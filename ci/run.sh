@@ -12,11 +12,9 @@ cd "$(dirname "$0")/.."
 stage_sanity() {
   echo "== sanity: byte-compile every python file"
   python -m compileall -q incubator_mxnet_tpu tests tools bench.py \
-      __graft_entry__.py
+      chip_smoke.py __graft_entry__.py
   echo "== sanity: import the package on the CPU backend"
   JAX_PLATFORMS=cpu python -c "
-import os; os.environ['JAX_PLATFORMS']='cpu'
-import jax; jax.config.update('jax_platforms','cpu')
 import incubator_mxnet_tpu as mx
 print('import ok:', mx.__version__)"
 }
@@ -246,13 +244,12 @@ stage_report() {
 }
 
 stage_entry() {
-  echo "== entry: driver entry points (single-chip compile is driver-side;"
-  echo "          here the 8-device multichip dryrun must pass)"
+  echo "== entry: the 8-device CPU multichip dryrun must pass. (The chip"
+  echo "          itself is checked by \`python chip_smoke.py\`, one process"
+  echo "          on a machine that holds a TPU — not a CI stage here;"
+  echo "          tests/test_chip_smoke.py keeps its CPU rehearsal green.)"
   JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
   python -c "
-import os
-os.environ['JAX_PLATFORMS']='cpu'
-import jax; jax.config.update('jax_platforms','cpu')
 import __graft_entry__ as ge
 ge.dryrun_multichip(8)"
 }

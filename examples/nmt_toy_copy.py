@@ -13,7 +13,7 @@ import argparse
 
 import numpy as np
 
-import _common  # noqa: F401  (accelerator-or-CPU bootstrap)
+import _common  # noqa: F401  (compile-cache bootstrap)
 
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import nd, autograd, gluon

@@ -29,27 +29,14 @@ __all__ = [
     "integer_types",
 ]
 
-# jax moved shard_map out of experimental around 0.4.35→0.6 (first as
-# ``jax.shard_map``, keeping the experimental alias for a while). Resolve
-# it ONCE here; everything in this package imports the symbol from base so
-# the framework runs on either side of the move.
-try:
-    from jax import shard_map as _jax_shard_map
-    shard_map = _jax_shard_map.shard_map if hasattr(
-        _jax_shard_map, "shard_map") else _jax_shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+from jax import shard_map  # noqa: E402,F401  (re-exported package-wide)
 
 
 def pcast_varying(x, axes):
-    """Compat for ``lax.pcast(x, axes, to="varying")`` (the VMA branding
-    newer jax requires on loop carries inside shard_map). Older jax has no
-    varying-manual-axes tracking, where the cast is semantically the
-    identity."""
+    """``lax.pcast(x, axes, to="varying")``: the VMA branding jax requires
+    on loop carries inside shard_map."""
     from jax import lax as _lax
-    if hasattr(_lax, "pcast"):
-        return _lax.pcast(x, axes, to="varying")
-    return x
+    return _lax.pcast(x, axes, to="varying")
 
 
 class MXNetError(RuntimeError):

@@ -86,10 +86,9 @@ class CausalSelfAttention(HybridBlock):
             if mesh is not None:
                 from ..parallel.ring_attention import (ring_self_attention,
                                                        ring_flash_attention)
-                from ..ops.pallas_attention import _pallas_available
-                on_tpu = any(d.platform == "tpu" for d in jax.devices())
+                from ..ops.pallas_attention import _on_tpu, pallas_path
                 engine = ring_flash_attention if (
-                    self._flash and on_tpu and _pallas_available()) \
+                    self._flash and _on_tpu() and pallas_path()) \
                     else ring_self_attention
                 out = NDArray(engine(
                     q._data, k._data, v._data, mesh=mesh, causal=True,

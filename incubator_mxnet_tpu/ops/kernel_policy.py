@@ -4,7 +4,7 @@ One function family maps STATIC shapes + hardware budgets to the
 training configuration, replacing the measurement ladder's env-knob
 folklore. The ladder's A/B rungs remain as audits of this policy.
 
-Measured anchors (v5e, TPU_RUNS_r04 / BENCH_MEASURED_r04.json):
+Measured anchors (v5e, 2026-07-31, TPU_RUNS_r04 / BENCH_MEASURED_r04.json):
   - bert-base  B=96  dense kernels, dots-remat: 85,771 tok/s/chip (25.6%)
   - bert-large B=32  dense kernels, dots-remat: 29,184 tok/s/chip (29.5%)
   - large-b24 on the STREAMING kernels measured slower than plain
@@ -22,10 +22,29 @@ static under jit, so the policy is closed-form + measured anchors.
 
 from __future__ import annotations
 
-# v5e budgets; the policy is deliberately conservative (fragmentation,
-# XLA workspaces and the fused optimizer all eat into the nominal 16 GB)
-HBM_BYTES = 16e9
-HBM_USABLE = 13.6e9
+# the chip the measured anchors below were taken on; a process with no
+# accelerator (the CPU tests, planning ahead of a run) plans for it
+_ANCHOR_DEVICE_KIND = "TPU v5 lite"
+# deliberately conservative: fragmentation, XLA workspaces and the fused
+# optimizer all eat into the nominal HBM
+_USABLE_SHARE = 0.85
+
+
+def usable_hbm_bytes():
+    """Plannable HBM per device: ``_USABLE_SHARE`` of what the local
+    accelerator reports (``memory_stats()["bytes_limit"]``), or of the
+    anchor chip's row in the one device table when the process holds no
+    accelerator."""
+    import jax
+
+    from ..utils.flops import DEVICE_PEAKS
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        limit = DEVICE_PEAKS[_ANCHOR_DEVICE_KIND]["hbm_bytes"]
+    else:
+        limit = dev.memory_stats()["bytes_limit"]
+    return _USABLE_SHARE * limit
+
 
 # (num_layers, units) -> largest batch validated on hardware. The
 # arithmetic below may admit a larger batch (e.g. base B=128 pencils
@@ -75,13 +94,16 @@ def _saved_activation_bytes(B, T, units, hidden, dtype_bytes, remat):
 
 
 def training_plan(num_layers, units, hidden, vocab, seq_len,
-                  dtype="bfloat16", hbm_bytes=HBM_USABLE):
-    """{batch, remat, dense, fwd/bwd heads_per_program} for one chip.
+                  dtype="bfloat16", hbm_bytes=None):
+    """{batch, remat, dense, fwd/bwd heads_per_program} for one chip
+    (``hbm_bytes`` defaults to ``usable_hbm_bytes()``).
 
     Largest candidate batch whose params (multi-precision LAMB: bf16
     weights + f32 master + 2 f32 moments = 14 B/param) plus saved
     activations fit the usable HBM, clamped to the hardware-validated
     anchor for known model shapes."""
+    if hbm_bytes is None:
+        hbm_bytes = usable_hbm_bytes()
     dtype_bytes = 2 if dtype in ("bfloat16", "float16") else 4
     params = _param_count(num_layers, units, hidden, vocab, seq_len)
     param_bytes = params * (14 if dtype_bytes == 2 else 12)

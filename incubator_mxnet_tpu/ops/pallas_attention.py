@@ -24,9 +24,14 @@ Design (per /opt/skills/guides/pallas_guide.md):
     padded-query contributions to dk/dv vanish because dO is zero there,
     padded keys never attend because valid_len caps at the real Tk.
 
-Falls back transparently (use_flash_attention() returns the best
-available implementation) when Pallas/TPU is absent — e.g. the CPU test
-mesh — via ``interpret=True`` or the pure-jnp blockwise path.
+Kernel-vs-reference is ONE static decision, ``pallas_path``: on platform
+``tpu`` the answer is the Mosaic kernel or an ``MXNetError`` (Pallas not
+importable, or interpret mode requested) — never the interpreter or the
+jnp path standing in for it. Off TPU (the CPU test mesh) the jnp
+blockwise path is the default and ``interpret=True`` /
+``MXTPU_FLASH_INTERPRET=1`` runs the same kernels in the interpreter.
+Selection from static shapes (D > 256, causal with Tq != Tk, a boolean
+mask) picks the jnp path on every platform.
 """
 
 from __future__ import annotations
@@ -37,6 +42,8 @@ import os
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..base import MXNetError
 
 _NEG_INF = -1e30
 
@@ -68,6 +75,36 @@ def _pallas_available():
         return True
     except Exception:  # pragma: no cover
         return False
+
+
+def _on_tpu():
+    return jax.default_backend() == "tpu"
+
+
+def _env_interpret():
+    return os.environ.get("MXTPU_FLASH_INTERPRET") == "1"
+
+
+def pallas_path(interpret=False):
+    """The one static kernel-vs-reference decision behind every attention
+    dispatcher (flash, block/ring, ragged). True = run the Pallas kernel
+    (compiled by Mosaic on TPU; in the interpreter off TPU when
+    ``interpret``); False = the jnp reference, which only an off-TPU
+    process may get. On TPU nothing stands in for the kernel."""
+    if _on_tpu():
+        if not _pallas_available():
+            raise MXNetError(
+                "platform is tpu but jax.experimental.pallas failed to "
+                "import: refusing to run the jnp reference in place of "
+                "the Mosaic attention kernel")
+        if interpret:
+            raise MXNetError(
+                "interpret mode (MXTPU_FLASH_INTERPRET=1 / interpret=True) "
+                "on platform tpu would run the Pallas interpreter in "
+                "place of the Mosaic attention kernel; unset it")
+        return True
+    # mxlint: allow-trace-host-leak(interpret is a host flag: env var or a static jit arg, never traced)
+    return bool(interpret) and _pallas_available()
 
 
 def _tile_mask(bq, bk, vl, causal, q_off=0, k_off=0):
@@ -217,6 +254,7 @@ def _flash_fwd_lse(q, k, v, valid_len, causal=False, scale=None,
 
     out, lse = pl.pallas_call(
         kernel,
+        name="mxtpu_flash_stream_fwd",
         grid=(B, H // shpp, Tq_p // block_q),
         in_specs=[
             pl.BlockSpec((B, 1), lambda b, g, i: (0, 0),
@@ -300,6 +338,28 @@ def _heads_per_program(H, cap_env, cap_default):
     buffered block set inside the ~16 MB/core VMEM."""
     cap = max(1, _env_block(cap_env, cap_default))
     return _largest_divisor(H, cap)
+
+
+def _dense_vmem_limit(hpp, Tq, Tk, D, itemsize, q_blocks, k_blocks,
+                      col_blocks, tiles_per_head):
+    """Scoped-VMEM request for one dense program, from its block set.
+    Per head: ``q_blocks``/``k_blocks`` (Tq, D)/(Tk, D) operand-dtype
+    blocks and ``col_blocks`` (Tq, 1) f32 columns, lane-padded to 128
+    and double-buffered by Pallas, plus ``tiles_per_head`` (Tq, Tk) f32
+    tiles — the unrolled head loop keeps scores/probabilities of
+    neighbouring heads live, so the tile count grows with the grouping.
+    Plus 4 MiB of slack. Mosaic's default scoped limit is 16 MiB; first
+    contact (2026-09) measured the forward's need inside a training step
+    at 26.34M for 12 heads (GPT-2-small) and 37.41M for 16 (BERT-large)
+    at T=512 — about 1.5 MiB of blocks and 0.75 MiB of tiles a head, and
+    more than the same kernel needs compiled alone (17.41M for 16). The
+    request is a cap, not an allocation, and stays inside v5e's 128 MiB
+    of VMEM."""
+    lanes = -(-D // 128) * 128
+    blocks = (q_blocks * Tq + k_blocks * Tk) * lanes * itemsize \
+        + col_blocks * Tq * 128 * 4
+    need = hpp * (2 * blocks + tiles_per_head * Tq * Tk * 4) + (4 << 20)
+    return min(max(need, 16 << 20), 100 << 20)
 
 
 def _dense_fwd_kernel(vl_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
@@ -386,6 +446,7 @@ def _dense_fwd_lse(q, k, v, valid_len, causal, scale, interpret,
                                causal=causal, hpp=hpp)
     out, lse = pl.pallas_call(
         kernel,
+        name="mxtpu_flash_dense_fwd",
         grid=(B, H // hpp),
         in_specs=[
             pl.BlockSpec((B, 1), lambda b, g: (0, 0),
@@ -402,6 +463,10 @@ def _dense_fwd_lse(q, k, v, valid_len, causal, scale, interpret,
             jax.ShapeDtypeStruct((B, H, Tq_p, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, Tq_p, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_dense_vmem_limit(
+                hpp, Tq_p, Tk_p, D, q.dtype.itemsize, q_blocks=2,
+                k_blocks=2, col_blocks=1, tiles_per_head=1)),
         interpret=interpret,
     )(vl, q, k, v)
     return out[:, :, :Tq, :], lse[:, :, :Tq, 0]
@@ -432,6 +497,7 @@ def _dense_backward(q, k, v, valid_len, lse, g, delta, causal, scale,
                                causal=causal, hpp=hpp)
     dq, dk, dv = pl.pallas_call(
         kernel,
+        name="mxtpu_flash_dense_bwd",
         grid=(B, H // hpp),
         in_specs=[
             pl.BlockSpec((B, 1), lambda b, g: (0, 0),
@@ -453,6 +519,10 @@ def _dense_backward(q, k, v, valid_len, lse, g, delta, causal, scale,
             jax.ShapeDtypeStruct((B, H, Tk_p, D), k.dtype),
             jax.ShapeDtypeStruct((B, H, Tk_p, D), v.dtype),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_dense_vmem_limit(
+                hpp, Tq_p, Tk_p, D, q.dtype.itemsize, q_blocks=3,
+                k_blocks=4, col_blocks=2, tiles_per_head=2)),
         interpret=interpret,
     )(vl, qp, kp, vp, dop, lsep, deltap)
     return dq[:, :, :Tq, :], dk[:, :, :Tk, :], dv[:, :, :Tk, :]
@@ -589,6 +659,7 @@ def _flash_backward(q, k, v, valid_len, out, lse, g, causal=False,
         block_k=block_k, n_k_blocks=n_k_blocks, hpp=qhpp)
     dq = pl.pallas_call(
         dq_kernel,
+        name="mxtpu_flash_stream_dq",
         grid=(B, H // qhpp, n_q_blocks),
         in_specs=[
             pl.BlockSpec((B, 1), lambda b, g, i: (0, 0),
@@ -618,6 +689,7 @@ def _flash_backward(q, k, v, valid_len, out, lse, g, causal=False,
         block_k=block_k, n_q_blocks=n_q_blocks, hpp=khpp)
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="mxtpu_flash_stream_dkv",
         grid=(B, H // khpp, n_k_blocks),
         in_specs=[
             pl.BlockSpec((B, 1), lambda b, g, j: (0, 0),
@@ -666,22 +738,6 @@ class _Static:
 jax.tree_util.register_pytree_node(
     _Static, lambda s: ((), s.value), lambda aux, _: _Static(aux))
 
-def _reference_blockwise(q, k, v, valid_len, causal, scale):
-    """jnp online-softmax reference in (B,H,T,D) layout — the fallback
-    backward recomputes through this (scan-structured, so autodiff keeps
-    memory at O(T * block))."""
-    from .attention import _sdpa_blockwise
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
-    key_mask = lax.broadcasted_iota(jnp.int32, (B, Tk), 1) < \
-        valid_len.astype(jnp.int32)[:, None]
-    sc = D ** -0.5 if scale is None else scale
-    # _sdpa_blockwise wants (B, T, H, D)
-    out = _sdpa_blockwise(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                          v.transpose(0, 2, 1, 3), key_mask, causal, sc)
-    return out.transpose(0, 2, 1, 3)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def flash_attention_bhtd(q, k, v, valid_len, causal=False, scale=None,
                          interpret=False):
@@ -707,21 +763,15 @@ def _fwd(q, k, v, valid_len, causal, scale, interpret):
 
 def _bwd(causal, scale, interpret, res, g):
     q, k, v, valid_len, out, lse, static = res
-    if _pallas_available():
-        dense = static.value            # the forward's decision, verbatim
-        block_q, block_k = (None, None) if dense else \
-            _resolve_blocks(None, None)
-        dq, dk, dv = _flash_backward(q, k, v, valid_len, out, lse, g,
-                                     causal=causal, scale=scale,
-                                     block_q=block_q, block_k=block_k,
-                                     interpret=interpret, dense=dense,
-                                     hpp=_dense_hpp(q.shape[1], bwd=True)
-                                     if dense else None)
-        return dq, dk, dv, None
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: _reference_blockwise(q_, k_, v_, valid_len,
-                                                causal, scale), q, k, v)
-    dq, dk, dv = vjp(g)
+    dense = static.value                # the forward's decision, verbatim
+    block_q, block_k = (None, None) if dense else \
+        _resolve_blocks(None, None)
+    dq, dk, dv = _flash_backward(q, k, v, valid_len, out, lse, g,
+                                 causal=causal, scale=scale,
+                                 block_q=block_q, block_k=block_k,
+                                 interpret=interpret, dense=dense,
+                                 hpp=_dense_hpp(q.shape[1], bwd=True)
+                                 if dense else None)
     return dq, dk, dv, None
 
 
@@ -730,15 +780,11 @@ flash_attention_bhtd.defvjp(_fwd, _bwd)
 
 def tpu_kernel_eligible(D, causal=False, Tq=None, Tk=None):
     """True when use_flash_attention will hand (length-maskable) inputs
-    to the Pallas TPU kernel rather than the jnp fallback. Shared with
+    to the Pallas kernel rather than the jnp blockwise path. Shared with
     the models' packed-qkv fast path so the caller-side relayout is only
-    done when the kernel actually consumes the bhtd layout."""
-    on = any(d.platform == "tpu" for d in jax.devices()) \
-        and _pallas_available()
-    if os.environ.get("MXTPU_FLASH_INTERPRET") == "1":
-        # test lever: route the dispatcher to the real kernels in
-        # Pallas interpret mode on CPU (packed-layout parity coverage)
-        on = _pallas_available()
+    done when the kernel actually consumes the bhtd layout. Raises on
+    TPU when the kernel cannot run (see ``pallas_path``)."""
+    on = pallas_path(_env_interpret())
     if os.environ.get("MXTPU_FLASH_FORCE_FALLBACK") == "1":
         on = False  # A/B lever: measure jnp blockwise vs the kernel
     # the Pallas kernel's causal grid assumes square Tq == Tk; offset
@@ -759,8 +805,9 @@ def use_flash_attention(q, k, v, key_mask=None, causal=False, scale=None,
     The Pallas kernel runs on TPU when the mask is expressible as
     per-batch key LENGTHS (valid_length, or no mask at all) — the
     contiguous-prefix form every bucketing/padding pipeline produces.
-    Arbitrary boolean masks fall back to the pure-jnp blockwise path
-    (same math, XLA-fused). Dispatch is static: no data-dependent
+    Arbitrary boolean masks take the pure-jnp blockwise path (same
+    math, XLA-fused) on every platform — a selection from static
+    shapes, not a fallback. Dispatch is static: no data-dependent
     branching, safe under jit.
 
     PRECEDENCE when both key_mask and valid_length are given: the two
@@ -793,15 +840,46 @@ def use_flash_attention(q, k, v, key_mask=None, causal=False, scale=None,
                                   key_mask, causal, sc)
             return out.transpose(0, 2, 1, 3)
         return _sdpa_blockwise(q, k, v, key_mask, causal, sc)
-    interp = os.environ.get("MXTPU_FLASH_INTERPRET") == "1"
+    interp = _env_interpret()
     if layout == "bhtd":
+        return _flash_on_mesh(q, k, v, valid_length, causal, scale,
+                              interp)
+    out = _flash_on_mesh(q.transpose(0, 2, 1, 3),
+                         k.transpose(0, 2, 1, 3),
+                         v.transpose(0, 2, 1, 3),
+                         valid_length, causal, scale, interp)
+    return out.transpose(0, 2, 1, 3)
+
+
+def _flash_on_mesh(q, k, v, valid_length, causal, scale, interp):
+    """``flash_attention_bhtd`` inside SPMDTrainer's GSPMD step. A Mosaic
+    custom call cannot be partitioned by the compiler (first contact on
+    a four-chip mesh, 2026-09: "Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map"), so when the
+    step's mesh spans several devices the kernel is mapped by hand over
+    the layout the models already constrain q/k/v to: batch over
+    (fsdp, dp), heads over tp. Attention is independent per (batch,
+    head), so the body needs no collective. Outside a trainer's trace
+    (no active mesh) and on a one-device mesh this is the plain call."""
+    from ..parallel.spmd import _ACTIVE_MESH
+    mesh = _ACTIVE_MESH.get()
+    if mesh is None or mesh.size == 1:
         return flash_attention_bhtd(q, k, v, valid_length, causal, scale,
                                     interp)
-    out = flash_attention_bhtd(q.transpose(0, 2, 1, 3),
-                               k.transpose(0, 2, 1, 3),
-                               v.transpose(0, 2, 1, 3),
-                               valid_length, causal, scale, interp)
-    return out.transpose(0, 2, 1, 3)
+    from jax.sharding import PartitionSpec as P
+
+    from ..base import shard_map
+    # the trainer has checked that the batch divides over fsdp x dp;
+    # heads that do not divide over tp stay whole on every tp rank
+    batch = tuple(a for a in ("fsdp", "dp") if mesh.shape.get(a, 1) > 1)
+    tp = mesh.shape.get("tp", 1)
+    heads = "tp" if tp > 1 and q.shape[1] % tp == 0 else None
+    spec = P(batch or None, heads, None, None)
+    return shard_map(
+        lambda q_, k_, v_, vl_: flash_attention_bhtd(
+            q_, k_, v_, vl_, causal, scale, interp),
+        mesh=mesh, in_specs=(spec, spec, spec, P(batch or None)),
+        out_specs=spec, check_vma=False)(q, k, v, valid_length)
 
 
 # --------------------------------------------------------------------- #
@@ -846,22 +924,15 @@ def _dense_attn_lse(q, k, v, valid_len, causal, scale):
     return out.astype(q.dtype), lse
 
 
-def _pallas_runnable(interpret):
-    """Pallas kernels execute on TPU, or anywhere under interpret mode."""
-    if not _pallas_available():
-        return False
-    return interpret or any(d.platform == "tpu" for d in jax.devices())
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def block_attn_lse(q, k, v, valid_len, causal=False, scale=None,
                    interpret=False):
     """One attention block returning (out, lse) — Pallas forward AND
-    backward on TPU (or under interpret mode), jnp fallback otherwise.
-    The lse output is what makes partial results MERGEABLE across ring
-    steps (see parallel/ring_attention.py merge rule); it is
-    non-differentiable."""
-    if _pallas_runnable(interpret):
+    backward on TPU (or off TPU under interpret mode), the jnp reference
+    off TPU otherwise (``pallas_path``). The lse output is what makes
+    partial results MERGEABLE across ring steps (see
+    parallel/ring_attention.py merge rule); it is non-differentiable."""
+    if pallas_path(interpret):
         dense = _use_dense(q.shape[2], k.shape[2])
         return _flash_fwd_lse(q, k, v, valid_len, causal=causal,
                               scale=scale, interpret=interpret,
@@ -876,7 +947,7 @@ def _block_fwd(q, k, v, valid_len, causal, scale, interpret):
                               interpret)
     # None = jnp-fallback path taken; else the dense/streaming decision
     dense = (_use_dense(q.shape[2], k.shape[2])
-             if _pallas_runnable(interpret) else None)
+             if pallas_path(interpret) else None)
     return (out, lse), (q, k, v, valid_len, out, lse, _Static(dense))
 
 
@@ -903,7 +974,7 @@ def _dense_block_bwd(q, k, v, valid_len, out, lse, g, causal, scale):
 def _block_bwd(causal, scale, interpret, res, g):
     q, k, v, valid_len, out, lse, static = res
     g_out, _ = g                              # lse cotangent is dropped
-    if static.value is not None and _pallas_runnable(interpret):
+    if static.value is not None:
         dense = static.value            # the forward's decision, verbatim
         dq, dk, dv = _flash_backward(q, k, v, valid_len, out, lse, g_out,
                                      causal=causal, scale=scale,

@@ -37,11 +37,12 @@ Kernel design (per /opt/skills/guides/pallas_guide.md):
     kernels). Decode attention is a prefix mask — the query IS position
     ``length - 1`` — so no causal triangle is needed.
 
-Falls back to a pure-jnp gather-and-mask reference off-TPU (the CPU
-serving path and the test oracle); ``MXTPU_FLASH_INTERPRET=1`` routes
-the dispatcher to the real kernel in interpret mode, mirroring
-``ops.pallas_attention``. Same masked-row contract as the training
-kernels: a slot with length 0 produces EXACTLY zero output.
+Off TPU the dispatchers run a pure-jnp gather-and-mask reference (the
+CPU serving path and the test oracle), or the real kernel in interpret
+mode under ``MXTPU_FLASH_INTERPRET=1``. On TPU they run the Mosaic
+kernel or raise — one decision, ``ops.pallas_attention.pallas_path``,
+shared with the training kernels. Same masked-row contract as the
+training kernels: a slot with length 0 produces EXACTLY zero output.
 
 ``ragged_prefill_attention`` is the chunked-prefill sibling: a CHUNK of
 C consecutive prompt tokens of ONE slot (absolute positions
@@ -57,13 +58,12 @@ one; same jnp gather fallback as CPU path and oracle.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .pallas_attention import _pallas_available, _pallas_runnable
+from . import pallas_attention as _pa
 
 _NEG_INF = -1e30
 
@@ -144,8 +144,10 @@ def _ragged_kernel(pt_ref, ln_ref, q_ref, k_ref, v_ref, o_ref,
             # dead-row test and PROPAGATES instead of being silently
             # zeroed — the serving engine's non-finite guard depends on
             # corruption staying visible in the output.
-            row_ok = ~(m <= _NEG_INF / 2)
-            o_ref[0, h] = jnp.where(row_ok[:, None],
+            # (the compare runs on the already-expanded f32 column:
+            # Mosaic cannot reshape an i1 vector)
+            row_ok = ~(m[:, None] <= _NEG_INF / 2)
+            o_ref[0, h] = jnp.where(row_ok,
                                     acc_ref[h] / l_safe[:, None],
                                     0.0).astype(o_ref.dtype)
 
@@ -187,6 +189,7 @@ def _ragged_pallas(q, k_pool, v_pool, page_table, lengths, scale,
     )
     out = pl.pallas_call(
         kernel,
+        name="mxtpu_ragged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
         interpret=interpret,
@@ -239,6 +242,7 @@ def _ragged_pallas_q(q, k_pool, v_pool, page_table, lengths, k_scale,
     )
     out = pl.pallas_call(
         kernel,
+        name="mxtpu_ragged_decode_q",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
         interpret=interpret,
@@ -323,14 +327,15 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, lengths,
     None (the default) is the unquantized path, bit-identical to
     before.
 
-    Dispatch is static (mirrors ``ops.pallas_attention``): the Pallas
-    kernel on TPU, or anywhere under ``MXTPU_FLASH_INTERPRET=1`` /
-    ``interpret=True``; the jnp gather reference otherwise (the CPU
-    serving path). Both paths share the masked-row contract."""
+    Dispatch is static (``ops.pallas_attention.pallas_path``): the
+    Mosaic kernel on TPU (or an error — never the reference); off TPU
+    the jnp gather reference (the CPU serving path), or the kernel in
+    the interpreter under ``MXTPU_FLASH_INTERPRET=1`` /
+    ``interpret=True``. Both paths share the masked-row contract."""
     if interpret is None:
-        interpret = os.environ.get("MXTPU_FLASH_INTERPRET") == "1"
+        interpret = _pa._env_interpret()
     sc = q.shape[-1] ** -0.5 if scale is None else scale
-    if _pallas_available() and _pallas_runnable(interpret):
+    if _pa.pallas_path(interpret):
         if k_scale is not None:
             return _ragged_pallas_q(q, k_pool, v_pool, page_table,
                                     lengths, k_scale, v_scale, sc,
@@ -415,8 +420,10 @@ def _ragged_prefill_kernel(pr_ref, qi_ref, q_ref, k_ref, v_ref, o_ref,
             # past every accumulated page) stay at _NEG_INF — emit zero.
             # Negated compare: NaN (poisoned page) propagates, see the
             # decode kernel's finalize
-            row_ok = ~(m <= _NEG_INF / 2)
-            o_ref[0, h] = jnp.where(row_ok[:, None],
+            # (the compare runs on the already-expanded f32 column:
+            # Mosaic cannot reshape an i1 vector)
+            row_ok = ~(m[:, None] <= _NEG_INF / 2)
+            o_ref[0, h] = jnp.where(row_ok,
                                     acc_ref[h] / l_safe[:, None],
                                     0.0).astype(o_ref.dtype)
 
@@ -459,6 +466,7 @@ def _ragged_prefill_pallas(q, k_pool, v_pool, page_row, qinfo, scale,
     )
     out = pl.pallas_call(
         kernel,
+        name="mxtpu_ragged_prefill",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, H, C, D), q.dtype),
         interpret=interpret,
@@ -508,6 +516,7 @@ def _ragged_prefill_pallas_q(q, k_pool, v_pool, page_row, qinfo,
     )
     out = pl.pallas_call(
         kernel,
+        name="mxtpu_ragged_prefill_q",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, H, C, D), q.dtype),
         interpret=interpret,
@@ -674,8 +683,10 @@ def _ragged_verify_kernel(pt_ref, ln_ref, dl_ref, q_ref, k_ref, v_ref,
             # at _NEG_INF — emit exactly zero. Negated compare so a NaN
             # running max (poisoned page) PROPAGATES, see the decode
             # kernel's finalize
-            row_ok = ~(m <= _NEG_INF / 2)
-            o_ref[0, h] = jnp.where(row_ok[:, None],
+            # (the compare runs on the already-expanded f32 column:
+            # Mosaic cannot reshape an i1 vector)
+            row_ok = ~(m[:, None] <= _NEG_INF / 2)
+            o_ref[0, h] = jnp.where(row_ok,
                                     acc_ref[h] / l_safe[:, None],
                                     0.0).astype(o_ref.dtype)
 
@@ -722,6 +733,7 @@ def _ragged_verify_pallas(q, k_pool, v_pool, page_table, lengths,
     )
     out = pl.pallas_call(
         kernel,
+        name="mxtpu_ragged_verify",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, W, D), q.dtype),
         interpret=interpret,
@@ -776,6 +788,7 @@ def _ragged_verify_pallas_q(q, k_pool, v_pool, page_table, lengths,
     )
     out = pl.pallas_call(
         kernel,
+        name="mxtpu_ragged_verify_q",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, W, D), q.dtype),
         interpret=interpret,
@@ -846,15 +859,15 @@ def ragged_verify_attention(q, k_pool, v_pool, page_table, lengths,
     whole window).
 
     Dispatch is static (mirrors ``ragged_paged_attention``): the
-    Pallas kernel on TPU or under ``MXTPU_FLASH_INTERPRET=1`` /
-    ``interpret=True``; the per-position jnp reference loop otherwise
-    (the CPU serving path and oracle)."""
+    Mosaic kernel on TPU; off TPU the per-position jnp reference loop
+    (the CPU serving path and oracle) or the kernel in the interpreter
+    under ``MXTPU_FLASH_INTERPRET=1`` / ``interpret=True``."""
     if interpret is None:
-        interpret = os.environ.get("MXTPU_FLASH_INTERPRET") == "1"
+        interpret = _pa._env_interpret()
     sc = q.shape[-1] ** -0.5 if scale is None else scale
     if draft_len is None:
         draft_len = jnp.full((q.shape[0],), q.shape[1] - 1, jnp.int32)
-    if _pallas_available() and _pallas_runnable(interpret):
+    if _pa.pallas_path(interpret):
         if k_scale is not None:
             return _ragged_verify_pallas_q(
                 q, k_pool, v_pool, page_table, lengths,
@@ -881,15 +894,16 @@ def ragged_prefill_attention(q, k_pool, v_pool, page_row, q_start,
     PRECONDITION (the engine's contract): the chunk's own K/V rows are
     already scattered into the slot's pages, and every page covering
     positions [0, q_start + n_real) is live. Dispatch is static
-    (mirrors ``ragged_paged_attention``): the Pallas kernel on TPU or
-    under ``MXTPU_FLASH_INTERPRET=1`` / ``interpret=True``; the jnp
-    gather reference otherwise (the CPU serving path)."""
+    (mirrors ``ragged_paged_attention``): the Mosaic kernel on TPU;
+    off TPU the jnp gather reference (the CPU serving path) or the
+    kernel in the interpreter under ``MXTPU_FLASH_INTERPRET=1`` /
+    ``interpret=True``."""
     if interpret is None:
-        interpret = os.environ.get("MXTPU_FLASH_INTERPRET") == "1"
+        interpret = _pa._env_interpret()
     sc = q.shape[-1] ** -0.5 if scale is None else scale
     if n_real is None:
         n_real = q.shape[0]
-    if _pallas_available() and _pallas_runnable(interpret):
+    if _pa.pallas_path(interpret):
         qinfo = jnp.stack([jnp.asarray(q_start, jnp.int32),
                            jnp.asarray(n_real, jnp.int32)])
         if k_scale is not None:

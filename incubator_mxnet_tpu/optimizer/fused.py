@@ -177,13 +177,13 @@ class FusedApplier:
     steady state re-dispatches the cached executable.
     """
 
-    def __init__(self, optimizer, donate: Optional[bool] = None,
+    def __init__(self, optimizer, donate: bool = True,
                  guard: Optional[bool] = None):
         self.optimizer = optimizer
-        if donate is None:
-            # donation is a no-op (plus a warning) on the CPU backend
-            donate = jax.default_backend() != "cpu" or \
-                getenv_bool("MXTPU_FUSED_DONATE", False)
+        # donated on every backend (the CPU backend implements donation
+        # too), so the CPU tests run the arm the chip runs: a guard
+        # veto, checkpoint or warm start that re-read a donated input
+        # fails here first, not on first contact with a TPU
         self.donate = donate
         if guard is None:
             guard = getenv_bool("MXTPU_STEP_GUARD", True)

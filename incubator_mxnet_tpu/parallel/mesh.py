@@ -102,11 +102,8 @@ def default_mesh() -> Mesh:
 
 def current_mesh() -> Optional[Mesh]:
     """The innermost mesh activated via ``with mesh:`` or None."""
-    try:
-        env_mesh = jax.sharding.get_abstract_mesh()  # jax>=0.4.35
-    except Exception:
-        env_mesh = None
-    if env_mesh is not None and not getattr(env_mesh, "empty", True):
+    env_mesh = jax.sharding.get_abstract_mesh()
+    if not env_mesh.empty:
         return env_mesh
     return _DEFAULT_MESH
 

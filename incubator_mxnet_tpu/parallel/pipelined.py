@@ -58,11 +58,10 @@ import jax
 import jax.numpy as jnp
 import jax.tree_util as jtu
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from .. import autograd, random as _random
-from ..base import MXNetError, getenv_int
+from ..base import MXNetError, getenv_int, shard_map
 from ..ndarray import NDArray
 from .collectives import (BucketSchedule, int8_bucket_allreduce,
                           plan_grad_buckets, ring_allreduce_flat)
@@ -559,7 +558,7 @@ def build_pipelined_step(trainer, n_batch):
                       PartitionSpec()) + (sp["batch"],) * n_batch,
             out_specs=(sp["train"], sp["frozen"], sp["state"],
                        PartitionSpec(), PartitionSpec()),
-            check_rep=False)
+            check_vma=False)
         return mapped(train_vals, frozen_vals, opt_leaves, t, lr,
                       scale, key, *batch)
 
@@ -655,7 +654,7 @@ def build_pipelined_accum_step(trainer, n_batch):
                       P()) + (sp["batch"],) * n_batch,
             out_specs=(sp["train"], sp["frozen"], sp["state"],
                        sp["train"], P(), P(), P(), P()),
-            check_rep=False)
+            check_vma=False)
         return mapped(train_vals, frozen_vals, opt_leaves, acc_vals,
                       acc_ok, acc_loss, t, lr, scale, inv_k, is_last,
                       key, *batch)

@@ -237,10 +237,10 @@ def _block_bwd_any(q, k, v, vl, out, lse, g, causal, scale, interpret):
     once LSE is the full-row normalizer. Pallas kernels on TPU (or
     interpret mode), the shared residual-based dense math otherwise."""
     from ..ops.pallas_attention import (_dense_block_bwd, _dense_hpp,
-                                        _flash_backward, _pallas_runnable,
-                                        _use_dense)
+                                        _flash_backward, _use_dense,
+                                        pallas_path)
 
-    if _pallas_runnable(interpret):
+    if pallas_path(interpret):
         dense = _use_dense(q.shape[2], k.shape[2])
         return _flash_backward(q, k, v, vl, out, lse, g, causal=causal,
                                scale=scale, interpret=interpret,

@@ -139,10 +139,29 @@ def _fsdp_spec(shape, mesh: Mesh,
     return PartitionSpec(*entries)
 
 
+def _fit_spec(shape, spec: PartitionSpec, mesh: Mesh) -> PartitionSpec:
+    """Drop, last first, the mesh axes a dimension cannot be split over
+    evenly. A hint is written against a family of shapes; GPT-2's
+    50257-row embedding under ``P(("tp", "fsdp"), None)`` on fsdp=4 is
+    not divisible (first contact on four chips, 2026-09), and a freed
+    ``fsdp`` then lands on another dim in ``_fsdp_spec``."""
+    import math
+    entries = []
+    for dim, e in zip(shape, spec):
+        axes = list(e) if isinstance(e, (tuple, list)) else \
+            ([e] if e is not None else [])
+        while axes and dim % math.prod(mesh.shape.get(a, 1)
+                                       for a in axes):
+            axes.pop()
+        entries.append(tuple(axes) if len(axes) > 1 else
+                       (axes[0] if axes else None))
+    return PartitionSpec(*entries)
+
+
 def _param_sharding(p, mesh: Mesh, mode: str) -> NamedSharding:
     hint = getattr(p, "_sharding", None)
-    if hint is not None and not isinstance(hint, PartitionSpec):
-        hint = PartitionSpec(*hint)
+    if hint is not None:
+        hint = _fit_spec(p.shape, PartitionSpec(*hint), mesh)
     if mode == "fsdp":
         return NamedSharding(mesh, _fsdp_spec(p.shape, mesh, base=hint))
     if hint is not None:
@@ -381,6 +400,25 @@ class SPMDTrainer:
             return fn.lower(*args).as_text()
         finally:
             self._pipe_lowering = False
+
+    def compiled_step_text(self) -> str:
+        """Optimized HLO text of the fused step as the backend compiled
+        it — kernels included, so ``chip_smoke.py`` reads the Mosaic
+        custom calls and the collectives out of the program that ran
+        rather than out of a flag. Requires one prior ``step``. The
+        lowering re-traces the Python body; the trace counters are put
+        back, and with the persistent compile cache on the compile
+        itself is a cache hit."""
+        if self._step_fn is None or self._pipe_example_args is None:
+            raise MXNetError("compiled_step_text: run one step first")
+        count = self.step_trace_count
+        self._pipe_lowering = True      # the pipelined body's own guard
+        try:
+            return self._step_fn.lower(
+                *self._pipe_example_args).compile().as_text()
+        finally:
+            self._pipe_lowering = False
+            self.step_trace_count = count
 
     def pipelined_structure(self, accum: bool = False) -> dict:
         """`pipelined.structure_report` over the compiled program: grad
@@ -725,8 +763,12 @@ class SPMDTrainer:
                 jax.device_put(
                     jnp.zeros(self._params[i].shape, jnp.float32), sh)
                 for i, sh in zip(self._train_idx, train_sh)]
-            self._accum_ok = jnp.float32(1.0)
-            self._accum_loss = jnp.float32(0.0)
+            # carried scalars start COMMITTED to the mesh like the arrays
+            # the program hands back: an uncommitted jnp scalar has a
+            # different abstract value (no mesh) and the second
+            # microbatch would retrace the whole program
+            self._accum_ok = jax.device_put(jnp.float32(1.0), repl)
+            self._accum_loss = jax.device_put(jnp.float32(0.0), repl)
 
         import numpy as _host_np
         train_set = set(self._train_idx)
@@ -891,8 +933,9 @@ class SPMDTrainer:
         batch_vals = self._global_batch_vals([b._data for b in batch_nds])
         if jax.process_count() > 1:
             key = _host_np.asarray(key)
-        if self._pipeline is not None and self._pipe_example_args is None:
-            # abstract snapshot for on-demand .lower() (structure checks)
+        if self._pipe_example_args is None:
+            # abstract snapshot for on-demand .lower() (structure checks,
+            # compiled_step_text)
             self._pipe_example_args = self._abstract_args(
                 (train_vals, frozen_vals, tuple(opt_leaves), opt_tree,
                  t, lr, scale, key) + tuple(batch_vals), static={3})

@@ -23,15 +23,10 @@ import numpy as _np
 __all__ = ["seed", "new_key", "get_state", "set_state"]
 
 # Random bits must not depend on how the consuming array is sharded over
-# the mesh: with the legacy (non-partitionable) threefry lowering, the
-# same dropout mask computed on a dp2×sp4 vs a dp8 mesh comes out
-# DIFFERENT, so data-parallel and model-parallel runs of the same seed
-# silently diverge. Newer jax defaults this on; force it on the older
-# jax this container ships so RNG is layout-invariant everywhere.
-try:
-    jax.config.update("jax_threefry_partitionable", True)
-except Exception:  # very old jax without the flag: keep legacy behavior
-    pass
+# the mesh: with a non-partitionable threefry lowering the same dropout
+# mask computed on a dp2×sp4 vs a dp8 mesh comes out DIFFERENT, so
+# data-parallel and model-parallel runs of one seed silently diverge.
+jax.config.update("jax_threefry_partitionable", True)
 
 _state = threading.local()
 _DEFAULT_SEED = 0

@@ -653,9 +653,43 @@ class InferenceEngine:
                                     donate_argnums=(1, 2))
         self._prefill_jits = {}          # bucket_pages -> jitted dense fn
         self._chunk_jits = {}            # bucket_pages -> jitted chunk fn
+        # program name -> (jitted fn, abstract args of its first
+        # dispatch), for compiled_program_text
+        self._programs = {}
         self._copy_jit = None
         self._promote_jit = None
         self._gather_jit = None
+
+    def _dispatch(self, name, fn, *args):
+        """Run one compiled serving program, remembering the abstract
+        signature of each program's first dispatch."""
+        if name not in self._programs:
+            self._programs[name] = (fn, jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(np.shape(a),
+                                               jnp.result_type(a)), args))
+        return fn(*args)
+
+    def compiled_program_text(self, name) -> str:
+        """Optimized HLO text of a serving program this engine has
+        dispatched, as the backend compiled it: ``"decode"`` /
+        ``"verify"`` (the W=1 / W>1 step), ``("chunk", Cpad)``,
+        ``("dense", Tpad)`` — the keys of ``prefill_trace_counts``.
+        Kernels are in it, so ``chip_smoke.py`` reads the ragged Mosaic
+        custom calls out of the program that ran. The lowering
+        re-traces the Python body; the trace counters are put back."""
+        if name not in self._programs:
+            raise MXNetError(
+                f"no program {name!r} dispatched yet; have "
+                f"{sorted(map(str, self._programs))}")
+        fn, args = self._programs[name]
+        counts = (self.decode_trace_count, self.verify_trace_count,
+                  self.prefill_trace_count,
+                  dict(self.prefill_trace_counts))
+        try:
+            return fn.lower(*args).compile().as_text()
+        finally:
+            (self.decode_trace_count, self.verify_trace_count,
+             self.prefill_trace_count, self.prefill_trace_counts) = counts
 
     # ------------------------------------------------------------- #
     # traced programs
@@ -2393,7 +2427,8 @@ class InferenceEngine:
         if fn is None:
             fn = jax.jit(self._prefill_fn, donate_argnums=(1, 2))
             self._prefill_jits[bucket] = fn
-        self._kpools, self._vpools, ka, va, tok = fn(
+        self._kpools, self._vpools, ka, va, tok = self._dispatch(
+            ("dense", Tpad), fn,
             self._param_vals, self._kpools, self._vpools, self._kamax,
             self._vamax, ids, np.int32(t0), pages_arr,
             np.float32(req.temperature), slot.key,
@@ -2433,7 +2468,8 @@ class InferenceEngine:
         if fn is None:
             fn = jax.jit(self._chunk_prefill_fn, donate_argnums=(1, 2))
             self._chunk_jits[bucket] = fn
-        self._kpools, self._vpools, ka, va, tok = fn(
+        self._kpools, self._vpools, ka, va, tok = self._dispatch(
+            ("chunk", Cpad), fn,
             self._param_vals, self._kpools, self._vpools, self._kamax,
             self._vamax, ids, np.int32(start), np.int32(n),
             slot.row.copy(), np.float32(req.temperature), slot.key,
@@ -2773,12 +2809,12 @@ class InferenceEngine:
             samp_ops = self._neutral_step_ops(W)
         t_start = time.perf_counter()
         self._kpools, self._vpools, ka, va, emitted, n_emit, lengths = \
-            self._decode_step(self._param_vals, self._kpools,
-                              self._vpools, self._kamax, self._vamax,
-                              tokens, draft_len,
-                              table_dev, lengths_dev,
-                              self._temps.copy(),
-                              self._slot_keys.copy(), *samp_ops)
+            self._dispatch("decode" if W == 1 else "verify",
+                           self._decode_step, self._param_vals,
+                           self._kpools, self._vpools, self._kamax,
+                           self._vamax, tokens, draft_len, table_dev,
+                           lengths_dev, self._temps.copy(),
+                           self._slot_keys.copy(), *samp_ops)
         self._pull_amax(ka, va)
         # THE designed per-step host sync: the scheduler needs the
         # emitted tokens/acceptance counts to advance slots; everything

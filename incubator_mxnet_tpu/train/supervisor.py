@@ -161,7 +161,14 @@ class Supervisor:
         # own session/process group: a launcher-style command that
         # spawned workers must die as a TREE on a hang kill — a
         # SIGKILL'd wrapper alone leaks wedged grandchildren that keep
-        # holding devices (and ticking the progress signal)
+        # holding devices (and ticking the progress signal).
+        # ONE PROCESS PER CHIP: the child is the process that owns the
+        # accelerator, so this parent must never touch a JAX backend.
+        # Importing the package does not initialise one (checked:
+        # ``jax._src.xla_bridge._backends`` is empty after importing
+        # ``incubator_mxnet_tpu.serve``/``.parallel``/``.train``) —
+        # keep it so; a supervisor that called ``jax.devices()`` would
+        # hold the chip and its child would fail or hang.
         return subprocess.Popen(self.argv, env=env,
                                 stdout=self.stdout, stderr=self.stderr,
                                 start_new_session=True)

@@ -21,13 +21,15 @@ tied LM head re-enters as a full d×V matmul. Both model helpers below
 build the terms from the model's own dims, so step_bench computes MFU
 from the same run that banks tokens/s.
 
-Peak FLOPs honesty (the CPU caveat, docs/TRAINING_PERF.md): on TPU the
-per-chip peak is a datasheet constant and MFU is absolute. On the CPU
-backend there is no meaningful datasheet peak, so ``peak_flops_per_
-device`` measures a sustained large-matmul rate once per process and
-uses it as a PROXY ceiling — CPU MFU is a relative regression number
-(comparable across arms of one bench run on one box), never a
-hardware-utilization claim. ``MXTPU_PEAK_FLOPS`` overrides both paths.
+Peaks (the one table, ``DEVICE_PEAKS``): keyed by
+``jax.devices()[0].device_kind``, each row with its source. An
+accelerator that is not in the table RAISES — a peak assumed for a
+device nobody looked up turns every utilization into fiction. On the
+CPU backend there is no datasheet peak, so ``peak_flops_per_device``
+measures a sustained large-matmul rate once per process and labels it
+``cpu-proxy``: a relative regression number for the CPU tools
+(comparable across arms of one run on one box), never a
+hardware-utilization claim. ``MXTPU_PEAK_FLOPS`` overrides both.
 """
 
 from __future__ import annotations
@@ -35,29 +37,40 @@ from __future__ import annotations
 import os
 from typing import Optional
 
+from ..base import MXNetError
+
 __all__ = ["transformer_train_flops", "gpt_train_flops",
            "bert_train_flops", "model_train_flops", "count_params",
-           "peak_flops_per_device", "mfu"]
+           "DEVICE_PEAKS", "device_peaks", "peak_flops_per_device", "mfu"]
 
-# bf16 peak FLOPs per chip by TPU generation (datasheet numbers; the
-# device_kind strings match jax.devices()[0].device_kind). Runtimes have
-# reported the same chip under several spellings across libtpu releases
-# ("TPU v5 lite" vs "TPU v5e", "TPU v6 lite" vs "TPU v6e", "TPU v5" for
-# v5p pods), so each generation lists its known variants — matching is
-# longest-prefix so "TPU v5 lite" never falls into the bare "TPU v5" row.
-_TPU_PEAK_BF16 = {
-    "TPU v2": 45e12,
-    "TPU v3": 123e12,
-    "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v5litepod": 197e12,
-    "TPU v5p": 459e12,
-    "TPU v5": 459e12,
-    "TPU v6 lite": 918e12,
-    "TPU v6e": 918e12,
-    "TPU v6": 918e12,
+# The one peak table. Key = jax.devices()[0].device_kind, exactly as the
+# runtime spells it (no prefix matching: "TPU v5 lite" is v5e, "TPU v5"
+# would be v5p). Only kinds this repository has run on are listed; add a
+# row, with its source, when a new chip is first used.
+DEVICE_PEAKS = {
+    # Google Cloud documentation, "TPU v5e" system architecture: 197
+    # TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2e at 819 GB/s per chip.
+    # device_kind as reported by libtpu 0.0.34 on the v5e (first
+    # contact, 2026-09).
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes": 16e9, "hbm_bytes_per_s": 819e9},
 }
+
+
+def device_peaks(device) -> dict:
+    """The ``DEVICE_PEAKS`` row of a ``jax.Device``. Raises
+    ``MXNetError`` for a device that is not in the table — CPU
+    included; the CPU tools go through ``peak_flops_per_device``'s
+    ``cpu-proxy`` instead."""
+    kind = device.device_kind
+    if kind not in DEVICE_PEAKS:
+        raise MXNetError(
+            f"no peak-table row for device_kind={kind!r} "
+            f"(platform={device.platform!r}); known: "
+            f"{sorted(DEVICE_PEAKS)}. Add a row with its source to "
+            f"utils/flops.py:DEVICE_PEAKS — a peak is never assumed.")
+    return DEVICE_PEAKS[kind]
+
 
 _CPU_PEAK_CACHE: Optional[float] = None
 
@@ -170,7 +183,8 @@ def _measure_cpu_peak() -> float:
 def peak_flops_per_device() -> dict:
     """Per-device peak FLOPs and its provenance:
     ``{"flops": float, "source": "env"|"tpu-datasheet"|"cpu-proxy",
-    "device_kind": str}``. ``MXTPU_PEAK_FLOPS`` overrides."""
+    "device_kind": str}``. ``MXTPU_PEAK_FLOPS`` overrides; an
+    accelerator absent from ``DEVICE_PEAKS`` raises."""
     import jax
 
     dev = jax.devices()[0]
@@ -179,25 +193,9 @@ def peak_flops_per_device() -> dict:
     if env:
         return {"flops": float(env), "source": "env",
                 "device_kind": kind}
-    # longest-prefix match so variant spellings ("TPU v5 lite") never
-    # fall into a shorter generation row ("TPU v5")
-    for k in sorted(_TPU_PEAK_BF16, key=len, reverse=True):
-        if kind.lower().startswith(k.lower()):
-            return {"flops": _TPU_PEAK_BF16[k], "source": "tpu-datasheet",
-                    "device_kind": kind}
     if dev.platform != "cpu":
-        # an accelerator we have no datasheet row for: the cpu-proxy
-        # ceiling below would silently bank nonsense MFU, so say so
-        # loudly and name the escape hatch
-        import warnings
-        warnings.warn(
-            f"no peak-FLOPs datasheet entry for device_kind={kind!r} "
-            f"(platform={dev.platform!r}); falling back to a measured "
-            f"matmul-rate proxy ceiling, so MFU numbers are NOT a "
-            f"hardware-utilization claim. Set MXTPU_PEAK_FLOPS to the "
-            f"chip's bf16 peak (FLOPs/s) or add a row to "
-            f"utils/flops.py:_TPU_PEAK_BF16.",
-            RuntimeWarning, stacklevel=2)
+        return {"flops": device_peaks(dev)["bf16_flops"],
+                "source": "tpu-datasheet", "device_kind": kind}
     global _CPU_PEAK_CACHE
     if _CPU_PEAK_CACHE is None:
         _CPU_PEAK_CACHE = _measure_cpu_peak()

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from incubator_mxnet_tpu.ops.kernel_policy import (
-    HBM_USABLE, flash_kernel_plan, training_plan)
+    flash_kernel_plan, training_plan, usable_hbm_bytes)
 
 
 def test_bert_base_plan_matches_measured_best():
@@ -45,7 +45,7 @@ def test_long_context_switches_to_streaming_kernels():
 def test_hbm_budget_scales_batch_down():
     full = training_plan(12, 768, 3072, vocab=30522, seq_len=512)
     half = training_plan(12, 768, 3072, vocab=30522, seq_len=512,
-                         hbm_bytes=HBM_USABLE / 2)
+                         hbm_bytes=usable_hbm_bytes() / 2)
     assert half["batch"] < full["batch"]
 
 
@@ -58,22 +58,18 @@ def test_bench_defaults_follow_policy(monkeypatch):
 
     monkeypatch.delenv("MXTPU_BENCH_BATCH", raising=False)
     monkeypatch.delenv("MXTPU_BENCH_REMAT", raising=False)
-    monkeypatch.delenv("MXTPU_BENCH_TPU_CONFIG", raising=False)
     monkeypatch.delenv("MXTPU_BENCH_DROPOUT", raising=False)
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     bench = importlib.import_module("bench")
 
-    B, T, _, dtype, _, _, flash, remat, _ = \
-        bench._resolve_bert_config("base", on_tpu=True)
+    B, T, _, dtype, flash, remat, _ = bench._resolve_bert_config("base")
     assert (B, T, dtype, flash, remat) == (96, 512, "bfloat16", True,
                                            "dots")
-    B, _, _, _, _, _, _, remat, _ = \
-        bench._resolve_bert_config("large", on_tpu=True)
+    B, _, _, _, _, remat, _ = bench._resolve_bert_config("large")
     assert (B, remat) == (32, "dots")
-    # env knobs still override the policy (ladder A/B rungs)
+    # env knobs still override the policy (A/B runs)
     monkeypatch.setenv("MXTPU_BENCH_BATCH", "48")
     monkeypatch.setenv("MXTPU_BENCH_REMAT", "0")
-    B, _, _, _, _, _, _, remat, _ = \
-        bench._resolve_bert_config("base", on_tpu=True)
+    B, _, _, _, _, remat, _ = bench._resolve_bert_config("base")
     assert (B, remat) == (48, False)

@@ -14,6 +14,10 @@ Supported launchers:
            for testing dist kvstore without a cluster; SURVEY.md §4
            idiom 4). Sets JAX_COORDINATOR_ADDRESS / JAX_PROCESS_ID /
            JAX_NUM_PROCESSES plus the DMLC_* names scripts may read.
+           This is the CPU multi-process TEST launcher (run it with
+           JAX_PLATFORMS=cpu): on a host that holds chips every worker
+           would claim all of them, and a chip belongs to one process —
+           there, one process drives all local chips through a mesh.
   ssh    — print the per-host commands (zero-egress build: execution via
            ssh is left to the operator / real cluster tooling).
 
@@ -64,7 +68,8 @@ def _worker_env(rank, n, coord, extra=None):
 
 def launch_local(n: int, command, port=None) -> int:
     """Fork n workers on this host; returns the first nonzero exit code
-    (0 when all succeed)."""
+    (0 when all succeed). CPU test launcher — see the module docstring
+    for why not on a host with chips."""
     coord = f"127.0.0.1:{port or _free_port()}"
     procs = []
     for rank in range(n):
