@@ -1,0 +1,8 @@
+"""Median gap between consecutive output tokens of the requests due in the
+window, in milliseconds: the steadier statistic beside the tail."""
+from harness import stats
+
+
+def read(ctx):
+    xs = ctx.get("detail", {}).get("gaps_s")
+    return stats.percentile(xs, 50) * 1e3 if xs else None
