@@ -33,6 +33,7 @@ from ..gluon import nn
 from ._attention import packed_flash_self_attention, use_packed_fast_path
 from ..gluon.block import HybridBlock
 from .. import initializer as init
+from ..profiler import scope, scoped
 
 __all__ = ["BERTModel", "BERTForPretraining", "BERTClassifier",
            "bert_base", "bert_large", "bert_tiny",
@@ -155,12 +156,19 @@ class BERTEncoderLayer(HybridBlock):
     def hybrid_forward(self, F, x, mask=None, valid_length=None):
         from ..parallel.spmd import constrain
         seq_ax = "sp" if self._seq_parallel else None
-        x = self.ln1(x + self.attention(x, mask, valid_length))
-        x = constrain(x, ("dp", "fsdp"), seq_ax, None)
-        h = constrain(self.ffn_in(x), ("dp", "fsdp"), seq_ax, "tp")
-        h = F.gelu(h)
-        h = self.dropout(self.ffn_out(h))
-        return constrain(self.ln2(x + h), ("dp", "fsdp"), seq_ax, None)
+        # one scope a block part (docs/OBSERVABILITY.md "Named scopes"):
+        # metadata on the compiled ops, nothing at run time
+        with scope("mx.attn"):
+            a = self.attention(x, mask, valid_length)
+        with scope("mx.norm"):
+            x = self.ln1(x + a)
+            x = constrain(x, ("dp", "fsdp"), seq_ax, None)
+        with scope("mx.ffn"):
+            h = constrain(self.ffn_in(x), ("dp", "fsdp"), seq_ax, "tp")
+            h = F.gelu(h)
+            h = self.dropout(self.ffn_out(h))
+        with scope("mx.norm"):
+            return constrain(self.ln2(x + h), ("dp", "fsdp"), seq_ax, None)
 
 
 class BERTModel(HybridBlock):
@@ -215,17 +223,19 @@ class BERTModel(HybridBlock):
                        valid_length=None):
         from ..parallel.spmd import constrain
         B, T = input_ids.shape
-        pos = F.arange(0, T, dtype="int32").reshape((1, T)).broadcast_to((B, T))
-        emb = self.word_embed(input_ids) + self.position_embed(pos)
-        if token_types is not None:
-            emb = emb + self.token_type_embed(token_types)
-        emb = constrain(emb, ("dp", "fsdp"), None, None)
-        # enter the compute dtype BEFORE the embedding LN/dropout: both
-        # are (B, T, units) elementwise passes, and LN computes its
-        # statistics in f32 internally regardless of stream dtype
-        if self._dtype != "float32":
-            emb = emb.astype(self._dtype)
-        x = self.embed_dropout(self.embed_ln(emb))
+        with scope("mx.embed"):
+            pos = F.arange(0, T, dtype="int32").reshape((1, T)) \
+                .broadcast_to((B, T))
+            emb = self.word_embed(input_ids) + self.position_embed(pos)
+            if token_types is not None:
+                emb = emb + self.token_type_embed(token_types)
+            emb = constrain(emb, ("dp", "fsdp"), None, None)
+            # enter the compute dtype BEFORE the embedding LN/dropout:
+            # both are (B, T, units) elementwise passes, and LN computes
+            # its statistics in f32 internally regardless of stream dtype
+            if self._dtype != "float32":
+                emb = emb.astype(self._dtype)
+            x = self.embed_dropout(self.embed_ln(emb))
         mask = None
         if valid_length is not None:
             ar = F.arange(0, T, dtype="float32").reshape((1, T))
@@ -248,15 +258,15 @@ class BERTModel(HybridBlock):
         # consumer (the r3 trace shows the MLM gather/scatter running as
         # 42 ms of f32 sort fusions); only the pooled [CLS] path, which
         # is tiny, is promoted
-        cls = x._op("slice_axis", axis=1, begin=0, end=1).reshape(
-            (B, self._units)).astype("float32")
-        from ..parallel.spmd import constrain
         # batch-pin the pooled stream: the pooler Dense may be
         # fsdp-sharded on out-features, and without this the partitioner
         # propagates a units-over-fsdp layout into the tiny [CLS] path,
         # paying a full rematerialization to reconcile it with the
         # batch-sharded NSP head (the dp>=4 dryrun warning)
-        pooled = constrain(self.pooler(cls), ("dp", "fsdp"), None)
+        with scope("mx.head"):
+            cls = x._op("slice_axis", axis=1, begin=0, end=1).reshape(
+                (B, self._units)).astype("float32")
+            pooled = constrain(self.pooler(cls), ("dp", "fsdp"), None)
         return x, pooled
 
 
@@ -287,45 +297,48 @@ class BERTForPretraining(HybridBlock):
     def hybrid_forward(self, F, input_ids, token_types, valid_length,
                        masked_positions, mlm_bias=None):
         seq, pooled = self.bert(input_ids, token_types, valid_length)
-        # gather masked positions as a one-hot batched matmul: (B,M,T) @
-        # (B,T,units) -> (B,M,units). A take_along_axis gather lowers to
-        # sort-based scatter fusions on TPU (42 ms/step in the r3 trace,
-        # fwd+bwd); the one-hot contraction rides the MXU both directions
-        # and is numerically EXACT (each row of the one-hot has a single
-        # 1.0, so the "sum" copies one value untouched, any dtype)
-        T = seq.shape[1]
-        onehot = F.one_hot(masked_positions, depth=T,
-                           dtype=self.bert._dtype)
-        gathered = F.batch_dot(onehot, seq)
-        # head runs in f32 (it is M=76 tokens — cheap); astype's VJP casts
-        # the cotangent back to the compute dtype, so the f32 head cannot
-        # poison the encoder backward stream
-        from ..parallel.spmd import constrain
-        # keep the (B, M, units) head stream batch-sharded: mlm_transform's
-        # weight is fsdp-sharded (out-features), and unconstrained its
-        # output inherits a units-over-fsdp layout that the LN backward can
-        # only undo with a full rematerialization on dp>=4 meshes — the
-        # constraint makes the partitioner all-gather the small weight
-        # instead of resharding the activation
-        h = constrain(self.mlm_transform(gathered.astype("float32")),
-                      ("dp", "fsdp"), None, None)
-        h = F.gelu(h)
-        h = constrain(self.mlm_ln(h), ("dp", "fsdp"), None, None)
-        embed_w = self.bert.word_embed.weight.data()  # (vocab, units)
-        # decoder matmul runs in the model compute dtype: with bf16 this
-        # keeps the (B, M, vocab) logits half-width and the MXU at full
-        # rate; the loss (pretraining_loss) does its log-sum-exp reduction
-        # with f32 accumulation, so no f32 logits tensor is ever written
-        dt = self.bert._dtype
-        scores = F.dot(h.astype(dt), embed_w.astype(dt), transpose_b=True) \
-            + mlm_bias.astype(dt)
-        # vocab-sharded logits on tp meshes: the decoder matmul inherits
-        # the embedding table's vocab-dim sharding instead of allgathering
-        # a (B, M, vocab) replicated tensor; the loss's logsumexp then
-        # reduces across tp via an XLA psum
-        from ..parallel.spmd import constrain
-        scores = constrain(scores, ("dp", "fsdp"), None, "tp")
-        return scores, self.nsp(pooled)
+        with scope("mx.head"):
+            # gather masked positions as a one-hot batched matmul:
+            # (B,M,T) @ (B,T,units) -> (B,M,units). A take_along_axis
+            # gather lowers to sort-based scatter fusions on TPU (42
+            # ms/step in the r3 trace, fwd+bwd); the one-hot contraction
+            # rides the MXU both directions and is numerically EXACT (each
+            # row of the one-hot has a single 1.0, so the "sum" copies one
+            # value untouched, any dtype)
+            T = seq.shape[1]
+            onehot = F.one_hot(masked_positions, depth=T,
+                               dtype=self.bert._dtype)
+            gathered = F.batch_dot(onehot, seq)
+            # head runs in f32 (it is M=76 tokens — cheap); astype's VJP
+            # casts the cotangent back to the compute dtype, so the f32
+            # head cannot poison the encoder backward stream
+            from ..parallel.spmd import constrain
+            # keep the (B, M, units) head stream batch-sharded:
+            # mlm_transform's weight is fsdp-sharded (out-features), and
+            # unconstrained its output inherits a units-over-fsdp layout
+            # that the LN backward can only undo with a full
+            # rematerialization on dp>=4 meshes — the constraint makes the
+            # partitioner all-gather the small weight instead of
+            # resharding the activation
+            h = constrain(self.mlm_transform(gathered.astype("float32")),
+                          ("dp", "fsdp"), None, None)
+            h = F.gelu(h)
+            h = constrain(self.mlm_ln(h), ("dp", "fsdp"), None, None)
+            embed_w = self.bert.word_embed.weight.data()  # (vocab, units)
+            # decoder matmul runs in the model compute dtype: with bf16
+            # this keeps the (B, M, vocab) logits half-width and the MXU at
+            # full rate; the loss (pretraining_loss) does its log-sum-exp
+            # reduction with f32 accumulation, so no f32 logits tensor is
+            # ever written
+            dt = self.bert._dtype
+            scores = F.dot(h.astype(dt), embed_w.astype(dt),
+                           transpose_b=True) + mlm_bias.astype(dt)
+            # vocab-sharded logits on tp meshes: the decoder matmul
+            # inherits the embedding table's vocab-dim sharding instead of
+            # allgathering a (B, M, vocab) replicated tensor; the loss's
+            # logsumexp then reduces across tp via an XLA psum
+            scores = constrain(scores, ("dp", "fsdp"), None, "tp")
+            return scores, self.nsp(pooled)
 
 
 def pretraining_loss(model: BERTForPretraining, input_ids, token_types,
@@ -405,13 +418,15 @@ def pretraining_pipeline(model: BERTForPretraining):
                        transpose_b=True) + model.mlm_bias.data().astype(dt)
         scores = constrain(scores, ("dp", "fsdp"), None, "tp")
         nsp_scores = model.nsp(pooled)
-        label_scores = scores.pick(masked_labels, axis=-1)   # (B, M)
-        lse = scores._op("logsumexp", axis=-1)
-        mlm_ll = label_scores.astype("float32") - lse
-        nsp_logp = nsp_scores.log_softmax(axis=-1)
-        nsp_pick = nsp_logp.pick(nsp_labels, axis=-1)        # (B,)
-        return ((mlm_ll * masked_weights).sum(), masked_weights.sum(),
-                nsp_pick.sum(), NDArray(jnp.float32(nsp_pick._data.size)))
+        with scope("mx.loss"):
+            label_scores = scores.pick(masked_labels, axis=-1)   # (B, M)
+            lse = scores._op("logsumexp", axis=-1)
+            mlm_ll = label_scores.astype("float32") - lse
+            nsp_logp = nsp_scores.log_softmax(axis=-1)
+            nsp_pick = nsp_logp.pick(nsp_labels, axis=-1)        # (B,)
+            return ((mlm_ll * masked_weights).sum(),
+                    masked_weights.sum(), nsp_pick.sum(),
+                    NDArray(jnp.float32(nsp_pick._data.size)))
 
     def finalize(n_mlm, d_mlm, n_nsp, d_nsp):
         # mirrors pretraining_loss: mlm_loss + nsp_loss, with the MLM
@@ -420,8 +435,8 @@ def pretraining_pipeline(model: BERTForPretraining):
 
     blocks = [getattr(bert, f"layer{i}") for i in range(bert.num_layers)]
     return PipelineSpec(
-        blocks=blocks, head=head, finalize=finalize, stem=stem,
-        context=context,
+        blocks=blocks, head=scoped("mx.head", head), finalize=finalize,
+        stem=scoped("mx.embed", stem), context=context,
         stem_modules=[bert.word_embed, bert.token_type_embed,
                       bert.position_embed, bert.embed_ln],
         head_modules=[bert.pooler, model.mlm_transform, model.mlm_ln,

@@ -28,6 +28,7 @@ from ..gluon.block import HybridBlock
 from ..ndarray import NDArray
 from .. import initializer as init
 from .. import random as _rand
+from ..profiler import scope, scoped
 
 __all__ = ["GPTModel", "gpt_mini", "gpt_small", "lm_loss", "lm_pipeline",
            "greedy_generate", "cached_generate", "init_kv_cache",
@@ -133,12 +134,20 @@ class GPTBlock(HybridBlock):
     def hybrid_forward(self, F, x):
         from ..parallel.spmd import constrain
         seq_ax = "sp" if self._seq_parallel else None
-        x = x + self.attn(self.ln1(x))
-        x = constrain(x, ("dp", "fsdp"), seq_ax, None)
-        h = constrain(self.ffn_in(self.ln2(x)),
-                      ("dp", "fsdp"), seq_ax, "tp")
-        h = self.dropout(self.ffn_out(F.gelu(h)))
-        return constrain(x + h, ("dp", "fsdp"), seq_ax, None)
+        # one scope a block part, the names models/bert.py uses
+        # (docs/OBSERVABILITY.md "Named scopes"); a residual add goes
+        # with the part it closes
+        with scope("mx.norm"):
+            n = self.ln1(x)
+        with scope("mx.attn"):
+            x = x + self.attn(n)
+            x = constrain(x, ("dp", "fsdp"), seq_ax, None)
+        with scope("mx.norm"):
+            n = self.ln2(x)
+        with scope("mx.ffn"):
+            h = constrain(self.ffn_in(n), ("dp", "fsdp"), seq_ax, "tp")
+            h = self.dropout(self.ffn_out(F.gelu(h)))
+            return constrain(x + h, ("dp", "fsdp"), seq_ax, None)
 
 
 class GPTModel(HybridBlock):
@@ -178,13 +187,14 @@ class GPTModel(HybridBlock):
     def hybrid_forward(self, F, input_ids):
         from ..parallel.spmd import constrain
         B, T = input_ids.shape
-        pos = F.arange(0, T, dtype="int32").reshape((1, T)) \
-            .broadcast_to((B, T))
-        x = self.word_embed(input_ids) + self.position_embed(pos)
-        x = constrain(x, ("dp", "fsdp"), None, None)
-        x = self.embed_dropout(x)
-        if self._dtype != "float32":
-            x = x.astype(self._dtype)
+        with scope("mx.embed"):
+            pos = F.arange(0, T, dtype="int32").reshape((1, T)) \
+                .broadcast_to((B, T))
+            x = self.word_embed(input_ids) + self.position_embed(pos)
+            x = constrain(x, ("dp", "fsdp"), None, None)
+            x = self.embed_dropout(x)
+            if self._dtype != "float32":
+                x = x.astype(self._dtype)
         from ._remat import remat_call, resolve_policy
         pol = resolve_policy(self._remat)
         for i in range(self.num_layers):
@@ -194,13 +204,14 @@ class GPTModel(HybridBlock):
         # the (B, T, vocab) LM-head matmul runs at the compute dtype's MXU
         # rate (an f32 cast here poisoned the biggest matmul in the model);
         # losses do their log-sum-exp reduction with f32 accumulation
-        x = self.ln_f(x)
-        embed_w = self.word_embed.weight.data()
-        logits = F.dot(x, embed_w.astype(x.dtype), transpose_b=True)
-        # vocab-sharded logits on tp meshes (see BERTForPretraining)
-        from ..parallel.spmd import constrain
-        seq_ax = "sp" if self._seq_parallel else None
-        logits = constrain(logits, ("dp", "fsdp"), seq_ax, "tp")
+        with scope("mx.norm"):
+            x = self.ln_f(x)
+        with scope("mx.head"):
+            embed_w = self.word_embed.weight.data()
+            logits = F.dot(x, embed_w.astype(x.dtype), transpose_b=True)
+            # vocab-sharded logits on tp meshes (see BERTForPretraining)
+            seq_ax = "sp" if self._seq_parallel else None
+            logits = constrain(logits, ("dp", "fsdp"), seq_ax, "tp")
         return logits
 
 
@@ -248,21 +259,23 @@ def lm_pipeline(model: GPTModel, weighted: bool = False):
 
     def head(x, input_ids, labels, *rest):
         from ..parallel.spmd import constrain
-        x = model.ln_f(x)
+        with scope("mx.norm"):
+            x = model.ln_f(x)
         embed_w = model.word_embed.weight.data()
         logits = F.dot(x, embed_w.astype(x.dtype), transpose_b=True)
         logits = constrain(logits, ("dp", "fsdp"), None, "tp")
-        label_scores = logits.pick(labels, axis=-1)        # (B, T)
-        lse = logits._op("logsumexp", axis=-1)
-        ll = label_scores.astype("float32") - lse
-        if weighted:
-            if not rest:
-                raise MXNetError(
-                    "lm_pipeline(weighted=True) expects batch = "
-                    "(input_ids, labels, weights)")
-            w = rest[0]
-            return ((ll * w).sum(), w.sum())
-        return (ll.sum(), NDArray(jnp.float32(ll._data.size)))
+        with scope("mx.loss"):
+            label_scores = logits.pick(labels, axis=-1)        # (B, T)
+            lse = logits._op("logsumexp", axis=-1)
+            ll = label_scores.astype("float32") - lse
+            if weighted:
+                if not rest:
+                    raise MXNetError(
+                        "lm_pipeline(weighted=True) expects batch = "
+                        "(input_ids, labels, weights)")
+                w = rest[0]
+                return ((ll * w).sum(), w.sum())
+            return (ll.sum(), NDArray(jnp.float32(ll._data.size)))
 
     if weighted:
         def finalize(n, d):
@@ -273,7 +286,8 @@ def lm_pipeline(model: GPTModel, weighted: bool = False):
 
     blocks = [getattr(model, f"block{i}") for i in range(model.num_layers)]
     return PipelineSpec(
-        blocks=blocks, head=head, finalize=finalize, stem=stem,
+        blocks=blocks, head=scoped("mx.head", head), finalize=finalize,
+        stem=scoped("mx.embed", stem),
         stem_modules=[model.word_embed, model.position_embed],
         head_modules=[model.ln_f, model.word_embed],
         name="gpt_lm")

@@ -16,7 +16,7 @@ from . import collectives
 from .collectives import host_allreduce
 from . import spmd
 from .spmd import (SPMDTrainer, shard_params, replicate, constrain,
-                   activation_sharding_scope)
+                   activation_sharding_scope, live_trainers)
 from . import pipeline
 from .pipeline import pipeline_apply, stack_stage_params
 from . import moe
@@ -28,5 +28,5 @@ __all__ = [
     "MeshConfig", "build_mesh", "current_mesh", "default_mesh",
     "set_default_mesh", "initialize", "collectives", "host_allreduce",
     "SPMDTrainer", "shard_params", "replicate", "ring_self_attention",
-    "ring_flash_attention",
+    "ring_flash_attention", "live_trainers",
 ]

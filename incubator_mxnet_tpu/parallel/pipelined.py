@@ -60,7 +60,7 @@ import jax.tree_util as jtu
 from jax import lax
 from jax.sharding import PartitionSpec
 
-from .. import autograd, random as _random
+from .. import autograd, profiler, random as _random
 from ..base import MXNetError, getenv_int, shard_map
 from ..ndarray import NDArray
 from .collectives import (BucketSchedule, int8_bucket_allreduce,
@@ -424,9 +424,10 @@ def _pipelined_grads(trainer, spec, train_full, frozen_vals, key, batch,
         L = L._data if isinstance(L, NDArray) else L
         return L * scale  # loss scaling rides the backward seed
 
-    loss_scaled, pull_fin = jax.vjp(fin, *g_partials)
-    seeds = pull_fin(jnp.float32(1.0))
-    loss_val = loss_scaled / scale
+    with profiler.scope("mx.loss"):
+        loss_scaled, pull_fin = jax.vjp(fin, *g_partials)
+        seeds = pull_fin(jnp.float32(1.0))
+        loss_val = loss_scaled / scale
 
     # --- backward, deepest segment first, collectives interleaved ----- #
     g_head, g_tied_head, g_x = pull_head(seeds)
@@ -527,25 +528,28 @@ def build_pipelined_step(trainer, n_batch):
                                                  for e in ledger]
             opt_state = jtu.tree_unflatten(opt_tree, opt_leaves)
             from ..optimizer.fused import all_finite, apply_updates
-            new_train, new_states = apply_updates(
-                optimizer, train_idx, train_vals, grads, opt_state, t,
-                lr, rescale_grad=jnp.float32(base_rescale) / scale)
+            with profiler.scope("mx.optimizer"):
+                new_train, new_states = apply_updates(
+                    optimizer, train_idx, train_vals, grads, opt_state,
+                    t, lr,
+                    rescale_grad=jnp.float32(base_rescale) / scale)
             new_train = tuple(new_train)
             new_leaves = tuple(jtu.tree_leaves(tuple(new_states)))
             if guard:
                 # guard verdict on the POST-collective grads (for int8:
                 # the dequantized values), per-shard then pmin-combined
                 # so fsdp shards agree — the PR-8 where-select skip
-                ok_flag = all_finite(grads)
-                if raxes:
-                    ok_flag = lax.pmin(ok_flag, raxes)
-                apply_p = ok_flag > 0
-                new_train = tuple(jnp.where(apply_p, nw, w)
-                                  for nw, w in zip(new_train,
-                                                   train_vals))
-                new_leaves = tuple(jnp.where(apply_p, nl, ol)
-                                   for nl, ol in zip(new_leaves,
-                                                     opt_leaves))
+                with profiler.scope("mx.guard"):
+                    ok_flag = all_finite(grads)
+                    if raxes:
+                        ok_flag = lax.pmin(ok_flag, raxes)
+                    apply_p = ok_flag > 0
+                    new_train = tuple(
+                        jnp.where(apply_p, nw, w)
+                        for nw, w in zip(new_train, train_vals))
+                    new_leaves = tuple(
+                        jnp.where(apply_p, nl, ol)
+                        for nl, ol in zip(new_leaves, opt_leaves))
             else:
                 ok_flag = jnp.float32(1.0)
             return (new_train, tuple(frozen_vals), new_leaves,
@@ -612,33 +616,38 @@ def build_pipelined_accum_step(trainer, n_batch):
                 trainer.pipelined_issue_ledger = ledger
                 trainer.pipelined_bucket_order = [e["key"]
                                                  for e in ledger]
-            new_acc = tuple(a + g.astype(jnp.float32)
-                            for a, g in zip(acc_vals, grads))
+            with profiler.scope("mx.optimizer"):
+                new_acc = tuple(a + g.astype(jnp.float32)
+                                for a, g in zip(acc_vals, grads))
             from ..optimizer.fused import all_finite, apply_updates
             if guard:
-                ok_here = all_finite(grads)
-                if raxes:
-                    ok_here = lax.pmin(ok_here, raxes)
-                ok_round = acc_ok * ok_here
+                with profiler.scope("mx.guard"):
+                    ok_here = all_finite(grads)
+                    if raxes:
+                        ok_here = lax.pmin(ok_here, raxes)
+                    ok_round = acc_ok * ok_here
             else:
                 ok_round = jnp.float32(1.0)
             loss_round = acc_loss + loss_val
             opt_state = jtu.tree_unflatten(opt_tree, opt_leaves)
-            apply_grads = tuple(a * inv_k for a in new_acc)
-            new_train, new_states = apply_updates(
-                optimizer, train_idx, train_vals, apply_grads,
-                opt_state, t, lr,
-                rescale_grad=jnp.float32(base_rescale) / scale)
+            with profiler.scope("mx.optimizer"):
+                apply_grads = tuple(a * inv_k for a in new_acc)
+                new_train, new_states = apply_updates(
+                    optimizer, train_idx, train_vals, apply_grads,
+                    opt_state, t, lr,
+                    rescale_grad=jnp.float32(base_rescale) / scale)
             new_leaves = tuple(jtu.tree_leaves(tuple(new_states)))
             last_p = is_last > 0
-            apply_p = jnp.logical_and(last_p, ok_round > 0)
-            new_train = tuple(jnp.where(apply_p, nw, w)
-                              for nw, w in zip(new_train, train_vals))
-            new_leaves = tuple(jnp.where(apply_p, nl, ol)
-                               for nl, ol in zip(new_leaves,
-                                                 opt_leaves))
-            acc_out = tuple(jnp.where(last_p, jnp.zeros_like(na), na)
-                            for na in new_acc)
+            with profiler.scope("mx.guard"):
+                apply_p = jnp.logical_and(last_p, ok_round > 0)
+                new_train = tuple(jnp.where(apply_p, nw, w)
+                                  for nw, w in zip(new_train, train_vals))
+                new_leaves = tuple(
+                    jnp.where(apply_p, nl, ol)
+                    for nl, ol in zip(new_leaves, opt_leaves))
+            with profiler.scope("mx.optimizer"):
+                acc_out = tuple(jnp.where(last_p, jnp.zeros_like(na), na)
+                                for na in new_acc)
             acc_ok_out = jnp.where(last_p, jnp.float32(1.0), ok_round)
             acc_loss_out = jnp.where(last_p, jnp.float32(0.0),
                                      loss_round)

@@ -22,14 +22,17 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import time
+import weakref
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import jax.tree_util as jtu
+import numpy as _host_np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from .. import autograd, random as _random
+from .. import autograd, profiler, random as _random
 from ..base import MXNetError, getenv_bool
 from ..ndarray import NDArray
 from ..optimizer import create as opt_create
@@ -37,7 +40,29 @@ from ..train.outcomes import StepOutcome, StepRecorder
 from . import mesh as _mesh
 
 __all__ = ["SPMDTrainer", "shard_params", "replicate", "constrain",
-           "activation_sharding_scope"]
+           "activation_sharding_scope", "live_trainers"]
+
+_LIVE_TRAINERS: "weakref.WeakSet[SPMDTrainer]" = weakref.WeakSet()
+
+
+def live_trainers() -> List["SPMDTrainer"]:
+    """The ``SPMDTrainer``s alive in this process: what a debug hook or a
+    benchmark's reader walks to reach a trainer it was not handed (its
+    ``flight`` events, ``scope_table()``, ``health_snapshot()``). Held
+    weakly: a trainer that is dropped is forgotten."""
+    return list(_LIVE_TRAINERS)
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _host_spans():
+    """The context manager a step wraps each host phase in: a
+    ``jax.profiler.TraceAnnotation`` while a profiler session is live, so
+    the phases lie beside the device's ops in its trace; else nothing."""
+    if profiler.session_live():
+        return jax.profiler.TraceAnnotation
+    return lambda name: _NO_SPAN
 
 # Mesh active while SPMDTrainer traces the fused step — models call
 # ``constrain`` on activations against it (a no-op everywhere else).
@@ -329,6 +354,7 @@ class SPMDTrainer:
         self._step_fn = None
         self._opt_state = None  # list aligned with self._train_idx
         self.step_count = 0
+        _LIVE_TRAINERS.add(self)
 
     # ------------------------------------------------------------------ #
     @property
@@ -347,8 +373,15 @@ class SPMDTrainer:
     def last_outcome(self):
         return self._recorder.last_outcome
 
+    @property
+    def flight(self):
+        """The flight recorder that holds this trainer's ``TRAIN_STEP``
+        events (``flight.events("trainer")``)."""
+        return self._recorder.flight
+
     def health_snapshot(self) -> dict:
         snap = self._recorder.snapshot()
+        snap["compile_events"] = profiler.compile_counts()
         snap["loss_scale"] = (None if self.loss_scaler is None
                               else float(self.loss_scaler.loss_scale))
         snap["guard"] = self.guard
@@ -371,11 +404,18 @@ class SPMDTrainer:
     def _abstract_args(args, static=frozenset()):
         """Freeze a call's arguments as ShapeDtypeStructs (static
         positions kept verbatim) so `.lower()` can re-derive the HLO
-        later without holding donated buffers alive."""
+        later without holding donated buffers alive. Each keeps the
+        sharding of an array that is committed to one (parameters and
+        state; a batch fresh from the host is not): the abstract call
+        then has the signature of the call that ran, and `.lower()`
+        finds that call's jaxpr and module in JAX's caches and traces
+        nothing again."""
 
         def _abs(a):
-            return jax.ShapeDtypeStruct(jnp.shape(a),
-                                        jnp.result_type(a))
+            sharding = a.sharding if getattr(a, "committed", False) \
+                else None
+            return jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a),
+                                        sharding=sharding)
         return tuple(
             a if pos in static else jtu.tree_map(_abs, a)
             for pos, a in enumerate(args))
@@ -406,9 +446,10 @@ class SPMDTrainer:
         it — kernels included, so ``chip_smoke.py`` reads the Mosaic
         custom calls and the collectives out of the program that ran
         rather than out of a flag. Requires one prior ``step``. The
-        lowering re-traces the Python body; the trace counters are put
-        back, and with the persistent compile cache on the compile
-        itself is a cache hit."""
+        lowering is the one the step's own call left in JAX's caches
+        (should it trace again, the trace counters are put back), and
+        with the persistent compile cache on the compile is a cache
+        hit: the text is that of the executable that ran."""
         if self._step_fn is None or self._pipe_example_args is None:
             raise MXNetError("compiled_step_text: run one step first")
         count = self.step_trace_count
@@ -419,6 +460,18 @@ class SPMDTrainer:
         finally:
             self._pipe_lowering = False
             self.step_trace_count = count
+
+    def scope_table(self) -> Dict[str, Tuple[str, str]]:
+        """``{HLO instruction name: (scope, "fwd"|"bwd"|"")}`` of the
+        compiled step (``profiler.scope_table`` over
+        ``compiled_step_text()``): which model part, optimizer or guard
+        each instruction belongs to, by the ``mx.`` scopes the step was
+        traced under. A device trace names its events by instruction, so
+        joined with one this says where the step's device time goes.
+        An executable the persistent cache kept from before the scopes
+        existed comes back without them (JAX leaves metadata out of the
+        cache key): every scope then reads ``""``."""
+        return profiler.scope_table(self.compiled_step_text())
 
     def pipelined_structure(self, accum: bool = False) -> dict:
         """`pipelined.structure_report` over the compiled program: grad
@@ -515,24 +568,26 @@ class SPMDTrainer:
                  scale, key, *batch):
             trainer.step_trace_count += 1   # python body = trace time only
             (loss_val, aux), grads = jax.value_and_grad(
-                lambda tv, fv, k, *b: (
+                profiler.scoped("mx.loss", lambda tv, fv, k, *b: (
                     # dynamic loss scaling as a traced scalar: scale the
                     # loss INSIDE the program, divide back through the
                     # (traced) rescale_grad below — growth/decay never
                     # retraces
                     (lambda L, a: (L * scale, a))(*pure_loss(tv, fv, k, *b))
-                ), argnums=0, has_aux=True)(
+                )), argnums=0, has_aux=True)(
                     train_vals, frozen_vals, key, *batch)
-            loss_val = loss_val / scale
+            with profiler.scope("mx.loss"):
+                loss_val = loss_val / scale
             opt_state = jtu.tree_unflatten(opt_tree, opt_leaves)
             # whole-tree fused apply (optimizer/fused.py — shared with the
             # eager Trainer's jitted group path); the step counter and lr
             # arrive as traced scalars so schedules and Adam/LAMB bias
             # correction advance without recompiling
             from ..optimizer.fused import apply_updates
-            new_train, new_states = apply_updates(
-                optimizer, train_idx, train_vals, grads, opt_state, t, lr,
-                rescale_grad=jnp.float32(base_rescale) / scale)
+            with profiler.scope("mx.optimizer"):
+                new_train, new_states = apply_updates(
+                    optimizer, train_idx, train_vals, grads, opt_state, t,
+                    lr, rescale_grad=jnp.float32(base_rescale) / scale)
             new_train = tuple(new_train)
             aux = tuple(aux)
             new_leaves = tuple(jtu.tree_leaves(tuple(new_states)))
@@ -544,15 +599,20 @@ class SPMDTrainer:
                 # — and a skip-step is a where-select of the old params,
                 # optimizer state AND mutated frozen params (BN stats)
                 from ..optimizer.fused import all_finite
-                ok_flag = all_finite(grads)
-                apply_p = ok_flag > 0
-                new_train = tuple(jnp.where(apply_p, nw, w)
-                                  for nw, w in zip(new_train, train_vals))
-                aux = tuple(jnp.where(apply_p, na, fv)
-                            for na, fv in zip(aux, frozen_vals))
-                new_leaves = tuple(jnp.where(apply_p, nl, ol)
-                                   for nl, ol in zip(new_leaves,
-                                                     opt_leaves))
+                # the selects sit inside the scope with the reduction: a
+                # fusion carries its root's name, and an update fused
+                # into a select outside any scope would read as unscoped
+                with profiler.scope("mx.guard"):
+                    ok_flag = all_finite(grads)
+                    apply_p = ok_flag > 0
+                    new_train = tuple(
+                        jnp.where(apply_p, nw, w)
+                        for nw, w in zip(new_train, train_vals))
+                    aux = tuple(jnp.where(apply_p, na, fv)
+                                for na, fv in zip(aux, frozen_vals))
+                    new_leaves = tuple(
+                        jnp.where(apply_p, nl, ol)
+                        for nl, ol in zip(new_leaves, opt_leaves))
             else:
                 ok_flag = jnp.float32(1.0)
             return new_train, aux, new_leaves, loss_val, ok_flag
@@ -659,20 +719,23 @@ class SPMDTrainer:
                   is_last, key, *batch):
             trainer.accum_step_trace_count += 1   # trace time only
             (loss_val, aux), grads = jax.value_and_grad(
-                lambda tv, fv, k, *b: (
+                profiler.scoped("mx.loss", lambda tv, fv, k, *b: (
                     (lambda L, a: (L * scale, a))(*pure_loss(tv, fv, k,
                                                              *b))
-                ), argnums=0, has_aux=True)(
+                )), argnums=0, has_aux=True)(
                     train_vals, frozen_vals, key, *batch)
-            loss_val = loss_val / scale
+            with profiler.scope("mx.loss"):
+                loss_val = loss_val / scale
             # fold this microbatch into the f32 accumulators; non-finite
             # values propagate through the sum AND the explicit verdict
             # product below, so the round's apply decision is combined
-            new_acc = tuple(a + g.astype(jnp.float32)
-                            for a, g in zip(acc_vals, grads))
+            with profiler.scope("mx.optimizer"):
+                new_acc = tuple(a + g.astype(jnp.float32)
+                                for a, g in zip(acc_vals, grads))
             from ..optimizer.fused import all_finite, apply_updates
             if guard:
-                ok_round = acc_ok * all_finite(grads)
+                with profiler.scope("mx.guard"):
+                    ok_round = acc_ok * all_finite(grads)
             else:
                 ok_round = jnp.float32(1.0)
             loss_round = acc_loss + loss_val
@@ -681,21 +744,26 @@ class SPMDTrainer:
             # round — the where-select skip idiom of the PR-8 guard,
             # extended with the is_last gate
             opt_state = jtu.tree_unflatten(opt_tree, opt_leaves)
-            apply_grads = tuple(a * inv_k for a in new_acc)
-            new_train, new_states = apply_updates(
-                optimizer, train_idx, train_vals, apply_grads, opt_state,
-                t, lr, rescale_grad=jnp.float32(base_rescale) / scale)
+            with profiler.scope("mx.optimizer"):
+                apply_grads = tuple(a * inv_k for a in new_acc)
+                new_train, new_states = apply_updates(
+                    optimizer, train_idx, train_vals, apply_grads,
+                    opt_state, t, lr,
+                    rescale_grad=jnp.float32(base_rescale) / scale)
             new_leaves = tuple(jtu.tree_leaves(tuple(new_states)))
             last_p = is_last > 0
-            apply_p = jnp.logical_and(last_p, ok_round > 0)
-            new_train = tuple(jnp.where(apply_p, nw, w)
-                              for nw, w in zip(new_train, train_vals))
-            new_leaves = tuple(jnp.where(apply_p, nl, ol)
-                               for nl, ol in zip(new_leaves, opt_leaves))
+            with profiler.scope("mx.guard"):
+                apply_p = jnp.logical_and(last_p, ok_round > 0)
+                new_train = tuple(jnp.where(apply_p, nw, w)
+                                  for nw, w in zip(new_train, train_vals))
+                new_leaves = tuple(
+                    jnp.where(apply_p, nl, ol)
+                    for nl, ol in zip(new_leaves, opt_leaves))
             # accumulators reset at round end regardless of verdict (a
             # vetoed round's batch is discarded, PR-8 skip semantics)
-            acc_out = tuple(jnp.where(last_p, jnp.zeros_like(na), na)
-                            for na in new_acc)
+            with profiler.scope("mx.optimizer"):
+                acc_out = tuple(jnp.where(last_p, jnp.zeros_like(na), na)
+                                for na in new_acc)
             acc_ok_out = jnp.where(last_p, jnp.float32(1.0), ok_round)
             acc_loss_out = jnp.where(last_p, jnp.float32(0.0),
                                      loss_round)
@@ -730,11 +798,36 @@ class SPMDTrainer:
         whole apply (params, optimizer state and BN aux bit-identical
         to the round start), records ONE ``SKIPPED_NONFINITE`` and
         halves the loss scale ONCE. Returns the round's mean loss."""
+        span = _host_spans()
+        t0 = time.perf_counter()
+        with span("mx.trainer.step"):
+            with span("mx.trainer.prepare"):
+                rounds = self._prepare_round(microbatches)
+            applied, loss_report, frozen_saved, host_span = \
+                self._run_round(rounds, span, t0)
+        k = len(rounds)
+        self.last_accum_count = k
+        if not applied:
+            # roll the per-microbatch BN/aux mutations back to the
+            # round start — a vetoed round rolls NOTHING forward
+            train_set = set(self._train_idx)
+            it_f = iter(frozen_saved)
+            for i, p in enumerate(self._params):
+                if i not in train_set:
+                    p._data._data = next(it_f)
+        self._record_outcome(
+            applied, host_span,
+            lambda: (f"non-finite gradient in accumulated SPMD round "
+                     f"(k={k}) at step_count={self.step_count}"))
+        return NDArray(loss_report)
+
+    def _prepare_round(self, microbatches):
+        """The microbatches as arrays, checked; the first call's
+        placement, build and accumulators."""
         batches = [b if isinstance(b, (tuple, list)) else (b,)
                    for b in microbatches]
         if not batches:
             raise MXNetError("step_microbatches needs >= 1 microbatch")
-        k = len(batches)
         dp = self.mesh.shape["dp"] * self.mesh.shape["fsdp"]
         rounds = []
         for batch in batches:
@@ -769,8 +862,15 @@ class SPMDTrainer:
             # microbatch would retrace the whole program
             self._accum_ok = jax.device_put(jnp.float32(1.0), repl)
             self._accum_loss = jax.device_put(jnp.float32(0.0), repl)
+        return rounds
 
-        import numpy as _host_np
+    def _run_round(self, rounds, span, t0):
+        """Dispatch every microbatch of a round, then read the combined
+        verdict. Returns ``(applied, loss, frozen_saved, host_span)``;
+        ``host_span`` is the round on the host's clock from ``t0`` as
+        ``StepRecorder.record`` takes it, each phase summed over the
+        microbatches."""
+        k = len(rounds)
         train_set = set(self._train_idx)
         self._optimizer.num_update = self.step_count
         t = _host_np.float32(self.step_count + 1)
@@ -781,58 +881,56 @@ class SPMDTrainer:
         inv_k = _host_np.float32(1.0 / k)
         # round-start frozen-param snapshot (array refs, not copies):
         # BN running stats advance per microbatch, and a vetoed round
-        # must roll NOTHING forward — restored below on veto
+        # must roll NOTHING forward — restored by the caller on veto
         frozen_saved = [p._data._data
                         for i, p in enumerate(self._params)
                         if i not in train_set]
 
         self._recorder.open_step()
         loss_report = ok_report = None
+        prepare_s = dispatch_s = bind_s = 0.0
+        mark = t0
         try:
             for m, batch_nds in enumerate(rounds):
-                is_last = _host_np.float32(1.0 if m == k - 1 else 0.0)
-                key = _random.new_key()
-                train_vals = tuple(self._params[i]._data._data
-                                   for i in self._train_idx)
-                frozen_vals = tuple(
-                    p._data._data for i, p in enumerate(self._params)
-                    if i not in train_set)
-                opt_leaves, opt_tree = jtu.tree_flatten(
-                    jtu.tree_map(
-                        lambda s: s._data if isinstance(s, NDArray)
-                        else s,
-                        tuple(self._opt_state),
-                        is_leaf=lambda s: isinstance(s, NDArray)))
-                batch_vals = self._global_batch_vals(
-                    [b._data for b in batch_nds])
-                if jax.process_count() > 1:
-                    key = _host_np.asarray(key)
-                if self._pipeline is not None and \
-                        self._pipe_example_accum_args is None:
-                    self._pipe_example_accum_args = self._abstract_args(
-                        (train_vals, frozen_vals, tuple(opt_leaves),
-                         opt_tree, tuple(self._accum_bufs),
-                         self._accum_ok, self._accum_loss, t, lr,
-                         scale, inv_k, is_last, key)
-                        + tuple(batch_vals), static={3})
-                (new_train, aux, new_leaves, acc_out, acc_ok_out,
-                 acc_loss_out, loss_report, ok_report) = \
-                    self._accum_step_fn(
-                        train_vals, frozen_vals, tuple(opt_leaves),
-                        opt_tree, tuple(self._accum_bufs),
-                        self._accum_ok, self._accum_loss, t, lr, scale,
-                        inv_k, is_last, key, *batch_vals)
-                it_t, it_a = iter(new_train), iter(aux)
-                for i, p in enumerate(self._params):
-                    p._data._data = next(it_t) if i in train_set \
-                        else next(it_a)
-                self._opt_state = [
-                    jtu.tree_map(NDArray, st)
-                    for st in jtu.tree_unflatten(opt_tree,
-                                                 list(new_leaves))]
-                self._accum_bufs = list(acc_out)
-                self._accum_ok = acc_ok_out
-                self._accum_loss = acc_loss_out
+                with span("mx.trainer.prepare"):
+                    is_last = _host_np.float32(
+                        1.0 if m == k - 1 else 0.0)
+                    key = _random.new_key()
+                    train_vals = tuple(self._params[i]._data._data
+                                       for i in self._train_idx)
+                    frozen_vals = tuple(
+                        p._data._data for i, p in enumerate(self._params)
+                        if i not in train_set)
+                    opt_leaves, opt_tree = self._flat_opt_state()
+                    batch_vals = self._global_batch_vals(
+                        [b._data for b in batch_nds])
+                    if jax.process_count() > 1:
+                        key = _host_np.asarray(key)
+                    args = (train_vals, frozen_vals, tuple(opt_leaves),
+                            opt_tree, tuple(self._accum_bufs),
+                            self._accum_ok, self._accum_loss, t, lr,
+                            scale, inv_k, is_last, key) + tuple(batch_vals)
+                    if self._pipeline is not None and \
+                            self._pipe_example_accum_args is None:
+                        self._pipe_example_accum_args = \
+                            self._abstract_args(args, static={3})
+                t1 = time.perf_counter()
+                with span("mx.trainer.dispatch"):
+                    (new_train, aux, new_leaves, acc_out, acc_ok_out,
+                     acc_loss_out, loss_report, ok_report) = \
+                        self._accum_step_fn(*args)
+                t2 = time.perf_counter()
+                with span("mx.trainer.bind"):
+                    self._bind_outputs(new_train, aux, new_leaves,
+                                       opt_tree)
+                    self._accum_bufs = list(acc_out)
+                    self._accum_ok = acc_ok_out
+                    self._accum_loss = acc_loss_out
+                t3 = time.perf_counter()
+                prepare_s += t1 - mark
+                dispatch_s += t2 - t1
+                bind_s += t3 - t2
+                mark = t3
         except BaseException:
             # dispatch died mid-round: close the step and drop the
             # half-accumulated state (re-zeroed on the next round)
@@ -840,36 +938,15 @@ class SPMDTrainer:
             self._accum_bufs = None
             raise
 
-        self.last_accum_count = k
         # the ONE designed readback per accumulated round: the combined
         # verdict steers host counters, the scaler and the outcome —
         # read after every microbatch is dispatched
-        applied = (not self.guard) or \
-            bool(_host_np.asarray(ok_report) > 0)
-        if applied:
-            self.step_count += 1
-            self._recorder.record(StepOutcome.APPLIED)
-            if self.loss_scaler is not None and self.guard:
-                self.loss_scaler.update_scale(overflow=False)
-        else:
-            # roll the per-microbatch BN/aux mutations back to the
-            # round start — a vetoed round rolls NOTHING forward
-            it_f = iter(frozen_saved)
-            for i, p in enumerate(self._params):
-                if i not in train_set:
-                    p._data._data = next(it_f)
-            if self.loss_scaler is not None:
-                self.loss_scaler.update_scale(overflow=True)
-            detail = (f"non-finite gradient in accumulated SPMD round "
-                      f"(k={k}) at step_count={self.step_count}")
-            outcome = self._recorder.record(
-                StepOutcome.SKIPPED_NONFINITE, detail)
-            if outcome is StepOutcome.HALTED_POISONED:
-                raise self._recorder.halt_error(
-                    detail,
-                    loss_scale=None if self.loss_scaler is None
-                    else self.loss_scaler.loss_scale)
-        return NDArray(loss_report)
+        with span("mx.trainer.flag_wait"):
+            applied = (not self.guard) or \
+                bool(_host_np.asarray(ok_report) > 0)
+        t4 = time.perf_counter()
+        return applied, loss_report, frozen_saved, (
+            t0, t4 - t0, prepare_s, dispatch_s, bind_s, t4 - mark)
 
     def _global_batch_vals(self, batch_vals):
         """Multi-host batch placement (every process holds the SAME full
@@ -877,7 +954,6 @@ class SPMDTrainer:
         identity in single-process runs."""
         if jax.process_count() <= 1:
             return batch_vals
-        import numpy as _host_np
         batch_sh = NamedSharding(self.mesh,
                                  PartitionSpec(("fsdp", "dp")))
 
@@ -894,7 +970,81 @@ class SPMDTrainer:
 
     # ------------------------------------------------------------------ #
     def step(self, *batch):
-        """Run one fused train step; returns the (device-resident) loss."""
+        """Run one fused train step; returns the (device-resident) loss.
+
+        The step stamps the host's clock at five points and hands the
+        phases to the recorder, which puts them on the step's one
+        ``TRAIN_STEP`` event: ``prepare_s`` (batch to arrays, the first
+        step's build, parameter and state gathering, flattening, key and
+        scalars), ``dispatch_s`` (the compiled step's call: the first one
+        traces and compiles), ``bind_s`` (outputs bound to parameters and
+        state), ``flag_wait_s`` (the guard's flag read, which waits for
+        the device). While a ``jax.profiler`` session is live the same
+        phases are ``mx.trainer.*`` annotations in its trace."""
+        span = _host_spans()
+        t0 = time.perf_counter()
+        with span("mx.trainer.step"):
+            with span("mx.trainer.prepare"):
+                args = self._prepare_step(batch)
+                self._recorder.open_step()
+            t1 = time.perf_counter()
+            with span("mx.trainer.dispatch"):
+                try:
+                    outs = self._step_fn(*args)
+                except BaseException:
+                    # dispatch died before any outcome existed — close
+                    # the step so the next one is not falsely accused of
+                    # a missing record
+                    self._recorder.abort_step()
+                    raise
+            t2 = time.perf_counter()
+            with span("mx.trainer.bind"):
+                new_train, aux, new_state_leaves, loss_val, ok_flag = outs
+                opt_tree = args[3]
+                self._bind_outputs(new_train, aux, new_state_leaves,
+                                   opt_tree)
+            t3 = time.perf_counter()
+            # the guard verdict is read AFTER the outputs are bound (the
+            # update was already selected on device); it only steers host
+            # counters, the scaler and the outcome record
+            with span("mx.trainer.flag_wait"):
+                applied = (not self.guard) or \
+                    bool(_host_np.asarray(ok_flag) > 0)
+            t4 = time.perf_counter()
+        self._record_outcome(
+            applied, (t0, t4 - t0, t1 - t0, t2 - t1, t3 - t2, t4 - t3),
+            lambda: (f"non-finite gradient in fused SPMD step at "
+                     f"step_count={self.step_count} "
+                     f"(loss={float(_host_np.asarray(loss_val)):g})"))
+        return NDArray(loss_val)
+
+    def _record_outcome(self, applied, host_span, detail):
+        """The one outcome of a step or accumulated round, with its span
+        on the host's clock; the loss scaler follows it. ``detail`` is
+        asked for only when the step was skipped."""
+        if applied:
+            self.step_count += 1
+            self._recorder.record(StepOutcome.APPLIED, span=host_span)
+            if self.loss_scaler is not None and self.guard:
+                # without the guard overflow can never be observed, so
+                # growing the scale would be a one-way ratchet to inf
+                self.loss_scaler.update_scale(overflow=False)
+            return
+        if self.loss_scaler is not None:
+            self.loss_scaler.update_scale(overflow=True)
+        detail = detail()
+        outcome = self._recorder.record(
+            StepOutcome.SKIPPED_NONFINITE, detail, span=host_span)
+        if outcome is StepOutcome.HALTED_POISONED:
+            raise self._recorder.halt_error(
+                detail,
+                loss_scale=None if self.loss_scaler is None
+                else self.loss_scaler.loss_scale)
+
+    def _prepare_step(self, batch):
+        """Everything ``step`` does on the host before the dispatch: the
+        batch as arrays, the first call's placement and build, and the
+        compiled step's arguments gathered from parameters and state."""
         batch_nds = [b if isinstance(b, NDArray) else NDArray(jnp.asarray(b))
                      for b in batch]
         dp = self.mesh.shape["dp"] * self.mesh.shape["fsdp"]
@@ -913,16 +1063,12 @@ class SPMDTrainer:
             else:
                 self._step_fn = self._build_step(len(batch_nds))
 
+        train_set = set(self._train_idx)
         train_vals = tuple(self._params[i]._data._data
                            for i in self._train_idx)
         frozen_vals = tuple(p._data._data for i, p in enumerate(self._params)
-                            if i not in set(self._train_idx))
-        state_nd = tuple(self._opt_state)
-        opt_leaves, opt_tree = jtu.tree_flatten(
-            jtu.tree_map(lambda s: s._data if isinstance(s, NDArray) else s,
-                         state_nd,
-                         is_leaf=lambda s: isinstance(s, NDArray)))
-        import numpy as _host_np
+                            if i not in train_set)
+        opt_leaves, opt_tree = self._flat_opt_state()
         key = _random.new_key()
         self._optimizer.num_update = self.step_count  # drive lr schedules
         t = _host_np.float32(self.step_count + 1)
@@ -933,25 +1079,22 @@ class SPMDTrainer:
         batch_vals = self._global_batch_vals([b._data for b in batch_nds])
         if jax.process_count() > 1:
             key = _host_np.asarray(key)
+        args = (train_vals, frozen_vals, tuple(opt_leaves), opt_tree,
+                t, lr, scale, key) + tuple(batch_vals)
         if self._pipe_example_args is None:
             # abstract snapshot for on-demand .lower() (structure checks,
             # compiled_step_text)
-            self._pipe_example_args = self._abstract_args(
-                (train_vals, frozen_vals, tuple(opt_leaves), opt_tree,
-                 t, lr, scale, key) + tuple(batch_vals), static={3})
+            self._pipe_example_args = self._abstract_args(args, static={3})
+        return args
 
-        self._recorder.open_step()
-        try:
-            new_train, aux, new_state_leaves, loss_val, ok_flag = \
-                self._step_fn(
-                    train_vals, frozen_vals, tuple(opt_leaves), opt_tree,
-                    t, lr, scale, key, *batch_vals)
-        except BaseException:
-            # dispatch died before any outcome existed — close the step
-            # so the next one is not falsely accused of a missing record
-            self._recorder.abort_step()
-            raise
+    def _flat_opt_state(self):
+        return jtu.tree_flatten(
+            jtu.tree_map(lambda s: s._data if isinstance(s, NDArray) else s,
+                         tuple(self._opt_state),
+                         is_leaf=lambda s: isinstance(s, NDArray)))
 
+    def _bind_outputs(self, new_train, aux, new_state_leaves, opt_tree):
+        """Point parameters and optimizer state at a dispatch's outputs."""
         train_set = set(self._train_idx)
         it_t = iter(new_train)
         it_a = iter(aux)
@@ -960,31 +1103,6 @@ class SPMDTrainer:
         new_states = jtu.tree_unflatten(opt_tree, list(new_state_leaves))
         self._opt_state = [
             jtu.tree_map(NDArray, st) for st in new_states]
-        # the guard verdict is read AFTER the outputs are bound (the
-        # update was already selected on device); it only steers host
-        # counters, the scaler and the outcome record
-        applied = (not self.guard) or bool(_host_np.asarray(ok_flag) > 0)
-        if applied:
-            self.step_count += 1
-            self._recorder.record(StepOutcome.APPLIED)
-            if self.loss_scaler is not None and self.guard:
-                # without the guard overflow can never be observed, so
-                # growing the scale would be a one-way ratchet to inf
-                self.loss_scaler.update_scale(overflow=False)
-        else:
-            if self.loss_scaler is not None:
-                self.loss_scaler.update_scale(overflow=True)
-            detail = (f"non-finite gradient in fused SPMD step at "
-                      f"step_count={self.step_count} "
-                      f"(loss={float(_host_np.asarray(loss_val)):g})")
-            outcome = self._recorder.record(
-                StepOutcome.SKIPPED_NONFINITE, detail)
-            if outcome is StepOutcome.HALTED_POISONED:
-                raise self._recorder.halt_error(
-                    detail,
-                    loss_scale=None if self.loss_scaler is None
-                    else self.loss_scaler.loss_scale)
-        return NDArray(loss_val)
 
     # ------------------------------------------------------------------ #
     # elastic checkpointing (checkpoint/ subsystem): each process

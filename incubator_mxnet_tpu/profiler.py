@@ -16,6 +16,10 @@ TPU-native split of responsibilities:
     reference emits, loadable in chrome://tracing or perfetto.
   - **aggregate table** → ``dumps()`` (parity: `MXAggregateProfileStatsPrint`
     / ``profiler.dumps()``), per-name count/total/min/max/avg.
+  - **compile events** → ``compile_events()``: what ``jax.monitoring``
+    reports of tracing, lowering, backend compiles and the persistent
+    cache, each with its ``perf_counter`` stamp, in a bounded list
+    (docs/OBSERVABILITY.md "Compile events").
   - ``mfu(...)`` — model-FLOPs-utilisation meter for the north-star metric
     (SURVEY.md §6); no reference analogue, TPU-specific addition.
 
@@ -25,11 +29,13 @@ Env autostart parity: ``MXTPU_PROFILER_AUTOSTART=1`` (reference:
 
 from __future__ import annotations
 
+import functools
 import json
 import os
+import re
 import threading
 import time
-from collections import defaultdict
+from collections import defaultdict, deque
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
@@ -38,8 +44,9 @@ from .base import MXNetError
 from .base import getenv_bool
 
 __all__ = ["set_config", "start", "stop", "dump", "dumps", "pause", "resume",
-           "scope", "ProfileEvent", "Counter", "Marker", "mfu",
-           "state_string"]
+           "scope", "scoped", "ProfileEvent", "Counter", "Marker", "mfu",
+           "state_string", "session_live", "scope_table",
+           "compile_events", "compile_counts"]
 
 _lock = threading.Lock()
 _config = {
@@ -67,6 +74,7 @@ def set_config(**kwargs) -> None:
 
 
 def _now_us() -> float:
+    # mxlint: allow-trace-host-leak(``scope`` times its region on the host; inside a traced function that is the trace itself, which is what a host event of a scope says)
     return (time.perf_counter() - _t0) * 1e6
 
 
@@ -110,8 +118,19 @@ def state_string() -> str:
     return "run" if _running else "stop"
 
 
+def session_live() -> bool:
+    """True while a ``jax.profiler`` trace session is open in this
+    process, whoever opened it (``start()`` here, ``jax.profiler.trace``,
+    a benchmark's tracer). One C++ flag read, some 80 ns."""
+    import jax
+
+    return jax.profiler.TraceAnnotation.is_enabled()
+
+
 def is_running() -> bool:
-    return _running and not _paused
+    """Host events are recorded: after ``start()``, or while a
+    ``jax.profiler`` session is live; never while paused."""
+    return (_running or session_live()) and not _paused
 
 
 def _record(name: str, cat: str, t_start_us: float, dur_us: float) -> None:
@@ -140,6 +159,47 @@ def scope(name: str, cat: str = "operator"):
             yield
         finally:
             _record(name, cat, t, _now_us() - t)
+
+
+def scoped(name: str, fn):
+    """``fn`` with every call under ``scope(name)``: for a function handed
+    to other code whole (a pipeline's stem or head)."""
+
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with scope(name):
+            return fn(*args, **kwargs)
+    return wrapped
+
+
+_HLO_NAME = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_MX_SCOPE = re.compile(r"mx\.[a-z_]+")
+
+
+def scope_table(hlo_text: str) -> Dict[str, tuple]:
+    """``{HLO instruction name: (scope, direction)}`` from a compiled
+    program's text. ``scope`` is the innermost ``mx.`` name of the
+    instruction's ``op_name`` metadata (what ``scope`` left on it at trace
+    time), ``""`` where it has none; ``direction`` is ``"bwd"`` where the
+    op_name passes through ``transpose(`` (the backward pass, its
+    rematerialized forward included), ``"fwd"`` where only through
+    ``jvp(``, else ``""`` (optimizer, guard). A fusion carries its root's
+    op_name. Instruction names are what a device trace calls its events,
+    so the table attributes trace time to model parts."""
+    table = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_NAME.match(line)
+        if m is None:
+            continue
+        meta = _HLO_OP_NAME.search(line)
+        op_name = meta.group(1) if meta else ""
+        scopes = _MX_SCOPE.findall(op_name)
+        direction = "bwd" if "transpose(" in op_name else \
+            "fwd" if "jvp(" in op_name else ""
+        table[m.group(1)] = (scopes[-1] if scopes else "",
+                             direction if scopes else "")
+    return table
 
 
 class ProfileEvent:
@@ -292,6 +352,73 @@ def mfu(model_flops_per_step: float, step_time_s: float,
         peak_flops_per_chip = peak_flops_per_device()["flops"]
     return model_flops_per_step / step_time_s / (n_chips * peak_flops_per_chip)
 
+
+# --------------------------------------------------------------------- #
+# compile events, from JAX's own instrumentation
+# --------------------------------------------------------------------- #
+
+# jax.monitoring's names (jax 0.9.0) -> the short ``kind`` kept here
+_COMPILE_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+_COMPILE_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hit",
+    "/jax/compilation_cache/cache_misses": "cache_miss",
+}
+_COMPILE_RING = 65536
+_compile_events: deque = deque(maxlen=_COMPILE_RING)
+_compile_counts: Dict[str, int] = defaultdict(int)
+
+
+def _keep_compile_event(kind: str, dur_s: float) -> None:
+    # deque.append and a dict increment under the interpreter lock: the
+    # listeners run on whichever thread compiled
+    _compile_events.append((time.perf_counter(), kind, dur_s))
+    _compile_counts[kind] += 1
+
+
+def _on_duration(event: str, duration_secs: float, **_kw) -> None:
+    kind = _COMPILE_DURATIONS.get(event)
+    if kind is not None:
+        _keep_compile_event(kind, float(duration_secs))
+
+
+def _on_event(event: str, **_kw) -> None:
+    kind = _COMPILE_COUNTS.get(event)
+    if kind is not None:
+        _keep_compile_event(kind, 0.0)
+
+
+def _register_compile_listeners() -> None:
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
+
+
+def compile_events() -> List[dict]:
+    """The trailing compile events of this process, oldest first:
+    ``{"ts", "kind", "dur_s"}`` with ``ts`` the ``time.perf_counter()``
+    reading when JAX reported the event (its END: a duration event spans
+    ``ts - dur_s`` to ``ts``) and ``kind`` one of ``trace`` (a function
+    traced to a jaxpr; inner jits report their own, nested in the outer
+    one's), ``lower`` (jaxpr to MLIR module), ``backend_compile`` (the
+    backend's compile OR its load from the persistent cache),
+    ``cache_retrieval``, ``cache_hit``, ``cache_miss`` (counts,
+    ``dur_s`` 0). Bounded: the last 65536 (a first step of 24 layers reports some thousands of nested traces)."""
+    return [{"ts": ts, "kind": kind, "dur_s": dur}
+            for ts, kind, dur in list(_compile_events)]
+
+
+def compile_counts() -> Dict[str, int]:
+    """Lifetime count of each kind of compile event (the list wraps)."""
+    return dict(_compile_counts)
+
+
+_register_compile_listeners()
 
 if getenv_bool("MXTPU_PROFILER_AUTOSTART"):
     set_config(profile_all=True)

@@ -33,11 +33,14 @@ every injected fault.
 from __future__ import annotations
 
 import enum
-from typing import Optional
+from typing import Optional, Sequence
 
 from ..base import MXNetError, getenv_int
 
 __all__ = ["StepOutcome", "StepRecorder"]
+
+# the TRAIN_STEP event's span fields, in the order ``record`` takes them
+_SPAN_FIELDS = ("dur_s", "prepare_s", "dispatch_s", "bind_s", "flag_wait_s")
 
 
 class StepOutcome(enum.Enum):
@@ -115,10 +118,15 @@ class StepRecorder:
                 "exactly-one-outcome-per-step is a trainer bug")
         self._open = True
 
-    def record(self, outcome: StepOutcome, detail: str = "") -> StepOutcome:
+    def record(self, outcome: StepOutcome, detail: str = "",
+               span: Optional[Sequence[float]] = None) -> StepOutcome:
         """Record this step's outcome (escalating to HALTED_POISONED at
         the consecutive-non-finite bound) and return the outcome
-        actually recorded."""
+        actually recorded. ``span`` is the step on the host's clock,
+        ``(start, dur_s, prepare_s, dispatch_s, bind_s, flag_wait_s)`` in
+        ``time.perf_counter()`` seconds: the ``TRAIN_STEP`` event then
+        starts at ``start`` and carries the rest as fields
+        (docs/OBSERVABILITY.md "Where a training step's time goes")."""
         if not self._open:
             raise MXNetError(
                 f"step outcome {outcome} recorded outside an open step "
@@ -137,9 +145,14 @@ class StepRecorder:
         self.last_detail = detail
         self._open = False
         from ..events import EventType
-        self.flight.emit(self.component, EventType.TRAIN_STEP,
+        fields = {}
+        start = None
+        if span is not None:
+            start = span[0]
+            fields = dict(zip(_SPAN_FIELDS, map(float, span[1:])))
+        self.flight.emit(self.component, EventType.TRAIN_STEP, ts=start,
                          step=self.step_count, outcome=outcome.value,
-                         detail=detail[:200])
+                         detail=detail[:200], **fields)
         if outcome is StepOutcome.HALTED_POISONED:
             self.flight.postmortem(
                 "HALTED_POISONED", self.component,

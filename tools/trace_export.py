@@ -15,9 +15,14 @@ with
     every prefill chunk as duration spans;
   - a ``steps`` lane of decode/verify steps (width + live occupancy
     in the args);
+  - a ``steps`` lane of training steps on the trainer's process, each
+    ``TRAIN_STEP`` a span from its start over ``dur_s`` with its host
+    phases (prepare, dispatch, bind, flag_wait) on a ``phases`` lane
+    beneath it; a ``TRAIN_STEP`` that carries no span (``gluon.Trainer``)
+    stays an instant;
   - instants for the control plane: SUBMIT, DISPATCH, REQUEUE,
-    BROWNOUT, REPLICA_HEALTH, CHECKPOINT_COMMIT, TRAIN_STEP,
-    SUPERVISOR_*, CHAOS injections.
+    BROWNOUT, REPLICA_HEALTH, CHECKPOINT_COMMIT, SUPERVISOR_*, CHAOS
+    injections.
 
 Usage:
   python tools/trace_export.py --events events.json --out trace.json
@@ -143,6 +148,23 @@ def to_perfetto(events) -> dict:
                 "pid": pid, "tid": "steps", "ts": us(e["ts"]),
                 "dur": max(data.get("dur_s", 0.0) * 1e6, 1.0),
                 "args": data})
+            continue
+        if et == "TRAIN_STEP" and "dur_s" in data:
+            trace.append({
+                "ph": "X", "cat": "train",
+                "name": f"step {data.get('step', '?')} "
+                        f"({data.get('outcome', '?')})",
+                "pid": pid, "tid": "steps", "ts": us(e["ts"]),
+                "dur": max(data["dur_s"] * 1e6, 1.0), "args": data})
+            at = e["ts"]
+            for phase in ("prepare_s", "dispatch_s", "bind_s",
+                          "flag_wait_s"):
+                trace.append({
+                    "ph": "X", "cat": "train", "name": phase[:-2],
+                    "pid": pid, "tid": "phases", "ts": us(at),
+                    "dur": max(data.get(phase, 0.0) * 1e6, 0.0),
+                    "args": {}})
+                at += data.get(phase, 0.0)
             continue
         if et in _INSTANT_TYPES:
             name = et
@@ -403,12 +425,38 @@ def _smoke(tmpdir: str) -> int:
         errors.append(f"obssmoke: fleet export lacks router/replica "
                       f"lanes: {sorted(procs)}")
 
+    # -- 4. the training step from inside: TRAIN_STEP spans -------- #
+    from incubator_mxnet_tpu import parallel
+    from incubator_mxnet_tpu.serve.events import validate_event_dict
+    tr = parallel.SPMDTrainer(
+        model, forward_loss=g.lm_loss, optimizer="adamw",
+        optimizer_params={"learning_rate": 1e-3})
+    n_dev = len(tr.mesh.devices.flat)
+    for _ in range(2):
+        ids = rng.randint(0, 64, size=(n_dev, 16)).astype(np.int32)
+        tr.step(ids, ids)
+    steps = [e.to_dict() for e in tr.flight.events("trainer")]
+    ttrace = to_perfetto(steps)
+    try:
+        for d in steps:
+            validate_event_dict(d)
+        validate_trace(ttrace)
+    except ValueError as e:
+        errors.append(f"obssmoke: trainer export invalid: {e}")
+    names = [ev["name"] for ev in ttrace["traceEvents"]
+             if ev["ph"] == "X"]
+    if sum(n.startswith("step ") for n in names) != 2 or \
+            not {"prepare", "dispatch", "bind", "flag_wait"} <= set(names):
+        errors.append(f"obssmoke: trainer export lacks the step spans "
+                      f"and their host phases: {names}")
+
     for e in errors:
         print(f"FAIL: {e}", file=sys.stderr)
     if not errors:
         print(f"obssmoke ok: postmortem + schema + Perfetto export "
               f"({len(trace['traceEvents'])} engine trace events, "
-              f"{len(fleet_trace['traceEvents'])} fleet trace events)")
+              f"{len(fleet_trace['traceEvents'])} fleet trace events, "
+              f"{len(ttrace['traceEvents'])} trainer trace events)")
     return 1 if errors else 0
 
 
