@@ -183,7 +183,12 @@ def _report(ctx, results, name, kernels, want, errs):
     results[name] = {"impl": impl, "max_err": round(worst, 5)}
 
 
-def _flash_case(ctx, results, name, B, H, T, D, causal, want):
+def _flash_case(ctx, results, name, B, H, T, D, causal, want,
+                packed=False):
+    """One flash case against the jnp reference. ``packed``: through the
+    dense pair that takes one (B, T, 3*H*D) projection and writes one
+    gradient (the relayout from and to (B, H, T, D) is this test's,
+    outside the program whose Mosaic calls are read)."""
     import jax
     import jax.numpy as jnp
     from incubator_mxnet_tpu.ops import pallas_attention as pa
@@ -195,11 +200,19 @@ def _flash_case(ctx, results, name, B, H, T, D, causal, want):
     vl = jnp.asarray([T] + [T - 37] * (B - 1), jnp.int32)
     interp = ctx["rehearsal"]
 
+    def flat(x):                           # (B, H, T, D) -> (B, T, H*D)
+        return x.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+
     def kernel(q, k, v, vl, g):
         out, vjp = jax.vjp(
             lambda q_, k_, v_: pa.flash_attention_bhtd(
                 q_, k_, v_, vl, causal, None, interp), q, k, v)
         return (out,) + vjp(g)
+
+    def kernel_packed(qkv, vl, g):
+        out, vjp = jax.vjp(lambda x: pa.flash_dense_packed(
+            x, vl, H, causal, None, interp), qkv)
+        return (out,) + tuple(jnp.split(vjp(g)[0], 3, axis=-1))
 
     def reference(q, k, v, vl, g):
         out, vjp = jax.vjp(
@@ -207,7 +220,14 @@ def _flash_case(ctx, results, name, B, H, T, D, causal, want):
                 q_, k_, v_, vl, causal, None)[0], q, k, v)
         return (out,) + vjp(g)
 
-    got, kernels = _compile_run(kernel, q, k, v, vl, g)
+    if packed:
+        got, kernels = _compile_run(
+            kernel_packed, jnp.concatenate([flat(q), flat(k), flat(v)], -1),
+            vl, flat(g))
+        got = tuple(x.reshape(B, T, H, D).transpose(0, 2, 1, 3)
+                    for x in got)
+    else:
+        got, kernels = _compile_run(kernel, q, k, v, vl, g)
     ref = _reference(reference, q, k, v, vl, g)
     errs = {n: _norm_err(a, b)
             for n, a, b in zip(("out", "dq", "dk", "dv"), got, ref)}
@@ -348,6 +368,13 @@ def leg_kernels(ctx):
                         f"flash_dense[H={H},T={z['dense_T']},"
                         f"{'causal' if causal else 'full'}]",
                         2, H, z["dense_T"], z["D"], causal, dense)
+    # the dense pair that reads the projection's own (B, T, 3*H*D)
+    # layout: what a one-device training step's layers call
+    for H, causal in z["packed_cases"]:
+        _flash_case(ctx, results,
+                    f"flash_dense_packed[H={H},T={z['dense_T']},"
+                    f"{'causal' if causal else 'full'}]",
+                    2, H, z["dense_T"], 64, causal, dense, packed=True)
     for causal in (True, False):
         _flash_case(ctx, results,
                     f"flash_stream[H={z['H']},T={z['stream_T']},"
@@ -366,12 +393,13 @@ def leg_train(ctx):
     import jax
     import numpy as np
     import incubator_mxnet_tpu as mx
-    from incubator_mxnet_tpu import nd, parallel
+    from incubator_mxnet_tpu import nd, parallel, profiler
     from incubator_mxnet_tpu.models import gpt as gpt_mod
     from incubator_mxnet_tpu.ndarray import NDArray
     from incubator_mxnet_tpu.parallel import mesh as pmesh
 
     z = ctx["size"]
+    profiler.attention_dispatch(reset=True)   # drop the kernel leg's sites
     n_dev = len(jax.devices())
     sharding = ctx["sharding"]
     axis = "fsdp" if sharding == "fsdp" else "dp"
@@ -396,6 +424,8 @@ def leg_train(ctx):
     first = trainer.step(*batch)
     jax.block_until_ready(first._data)
     t_first = time.perf_counter() - t0
+    # which implementation each attention call site got, at trace time
+    tally = profiler.attention_dispatch()
     losses = [float(first.asnumpy())]
     t0 = time.perf_counter()
     for _ in range(5):
@@ -420,8 +450,15 @@ def leg_train(ctx):
     kernels = mosaic_kernels(text)
     coll = collectives(text)
     log(f"train: compiled step mosaic_calls={kernels} collectives={coll}")
+    log(f"train: attention dispatch tally={tally}")
     if not ctx["rehearsal"]:
         L = model.num_layers
+        # one device: the pair that reads the projection; a mesh of
+        # several keeps the (B, H, T, D) pair (ops/pallas_attention.py,
+        # packed_dense_eligible)
+        site = "dense_packed" if n_dev == 1 else "dense_bhtd"
+        check(tally == {site: L},
+              f"attention call sites: want {L} x {site}, got {tally}")
         check(kernels.get("mxtpu_flash_dense_fwd", 0) >= L and
               kernels.get("mxtpu_flash_dense_bwd", 0) >= L,
               f"compiled step lacks the Mosaic attention forward/backward "
@@ -462,6 +499,7 @@ def leg_train(ctx):
             "first_loss": losses[0], "last_loss": losses[-1],
             "step_trace_count": trainer.step_trace_count,
             "mosaic_calls": kernels, "collectives": coll,
+            "attention_dispatch": tally,
             "first_step_s": round(t_first, 2),
             "five_steps_s": round(t_steps, 3)}
 
@@ -637,6 +675,7 @@ def _sizes(rehearsal):
         # gpt_mini: 2 layers, 128 units, 4 heads of 32, context 128
         return {"model": gpt_mod.gpt_mini, "H": 4, "D": 32,
                 "dense_heads": (4,), "dense_T": 128, "stream_T": 640,
+                "packed_cases": ((4, False), (2, True)),
                 "max_len": 128, "ragged_lengths": (0, 1, 16, 17, 100),
                 "prefill_cases": ((16, ((0, 16), (32, 5))),
                                   (32, ((64, 32),))),
@@ -646,6 +685,7 @@ def _sizes(rehearsal):
     # the dense flash pair also at BERT-large's 16 heads
     return {"model": gpt_mod.gpt_small, "H": 12, "D": 64,
             "dense_heads": (12, 16), "dense_T": 512, "stream_T": 1024,
+            "packed_cases": ((16, False), (12, True)),
             "max_len": 1024,
             "ragged_lengths": (0, 1, 16, 17, 1000, 255, 512, 33),
             "prefill_cases": ((16, ((0, 16), (32, 5))),

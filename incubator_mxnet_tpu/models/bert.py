@@ -85,7 +85,7 @@ class BERTSelfAttention(HybridBlock):
         B, T = x.shape[0], x.shape[1]
         H, D = self._heads, self._units // self._heads
         seq_ax = "sp" if self._seq_parallel else None
-        qkv = self.qkv(x).reshape((B, T, 3, H, D))
+        qkv = self.qkv(x)                     # (B, T, 3*H*D)
         mesh = None
         # ring dispatch requires EXPLICIT valid lengths (or no mask):
         # an arbitrary key mask is NOT converted — a non-prefix mask
@@ -107,7 +107,8 @@ class BERTSelfAttention(HybridBlock):
                 F, qkv, B, T, H, D, self._units, mask=mask,
                 valid_length=vl, seq_ax=seq_ax)
         else:
-            qkv = constrain(qkv, ("dp", "fsdp"), seq_ax, None, "tp", None)
+            qkv = constrain(qkv.reshape((B, T, 3, H, D)),
+                            ("dp", "fsdp"), seq_ax, None, "tp", None)
             q = qkv._op("slice_axis", axis=2, begin=0,
                         end=1).reshape((B, T, H, D))
             k = qkv._op("slice_axis", axis=2, begin=1,

@@ -65,7 +65,7 @@ class CausalSelfAttention(HybridBlock):
         from ..parallel.spmd import constrain
         B, T = x.shape[0], x.shape[1]
         H, D = self._heads, self._units // self._heads
-        qkv = self.qkv(x).reshape((B, T, 3, H, D))
+        qkv = self.qkv(x)                     # (B, T, 3*H*D)
         seq_ax = "sp" if self._seq_parallel else None
         mesh = None
         if self._seq_parallel:
@@ -77,7 +77,8 @@ class CausalSelfAttention(HybridBlock):
                 F, qkv, B, T, H, D, self._units, causal=True,
                 seq_ax=seq_ax)
         else:
-            qkv = constrain(qkv, ("dp", "fsdp"), seq_ax, None, "tp", None)
+            qkv = constrain(qkv.reshape((B, T, 3, H, D)),
+                            ("dp", "fsdp"), seq_ax, None, "tp", None)
             q = qkv._op("slice_axis", axis=2, begin=0,
                         end=1).reshape((B, T, H, D))
             k = qkv._op("slice_axis", axis=2, begin=1,

@@ -163,6 +163,23 @@ def scaled_dot_product_attention(q, k, v, mask=None, scale=None,
     return _sdpa_dense(q, k, v, m, scale)
 
 
+@register("flash_attention_packed")
+def flash_attention_packed(qkv, valid_length=None, heads=1, causal=False,
+                           scale=None):
+    """Flash self-attention straight off the fused projection: ``qkv``
+    (B, T, 3*H*D) with q | k | v side by side, as ``Dense(3*units)``
+    leaves it; returns (B, T, H*D). No transpose, no split: the packed
+    dense Pallas pair reads and writes this layout (the reference's
+    interleaved_matmul_selfatt_* pair has the same contract over its
+    interleaved buffer, src/operator/contrib/transformer.cc). Only for
+    call sites ``ops.pallas_attention.packed_dense_eligible`` admits
+    (it raises elsewhere); the transformer cells ask it first
+    (models/_attention.py)."""
+    from .pallas_attention import flash_packed_self_attention
+    return flash_packed_self_attention(qkv, heads, valid_length, causal,
+                                       scale)
+
+
 @register("masked_softmax")
 def masked_softmax(scores, mask=None, axis=-1):
     """Softmax with optional boolean mask (True = keep). Parity surface for

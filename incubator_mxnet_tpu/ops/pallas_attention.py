@@ -36,6 +36,7 @@ mask) picks the jnp path on every platform.
 
 from __future__ import annotations
 
+import collections
 import functools
 import os
 
@@ -107,17 +108,19 @@ def pallas_path(interpret=False):
     return bool(interpret) and _pallas_available()
 
 
-def _tile_mask(bq, bk, vl, causal, q_off=0, k_off=0):
-    """(bq, bk) boolean attend-mask for one score tile: keys < ``vl``,
-    optionally causal (top-left aligned — square Tq == Tk only, enforced
-    by use_flash_attention). ``q_off``/``k_off`` position the tile inside
-    the full (Tq, Tk) score matrix. Shared by ALL kernels (streaming
-    fwd/dq/dkv and dense fwd/bwd) so mask semantics cannot drift between
-    paths."""
-    k_pos = k_off + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+def _tile_mask(rows, cols, vl, causal, q_off=0, k_off=0, k_axis=1):
+    """(rows, cols) boolean attend-mask for one score tile, keys along
+    ``k_axis`` (1: a (bq, bk) tile; 0: the packed dense backward's
+    transposed (bk, bq) tile): keys < ``vl``, optionally causal (top-left
+    aligned — square Tq == Tk only, enforced by use_flash_attention).
+    ``q_off``/``k_off`` position the tile inside the full (Tq, Tk) score
+    matrix. Shared by ALL kernels (streaming fwd/dq/dkv, dense fwd/bwd in
+    both layouts) so mask semantics cannot drift between paths."""
+    k_pos = k_off + lax.broadcasted_iota(jnp.int32, (rows, cols), k_axis)
     mask = k_pos < vl
     if causal:
-        q_pos = q_off + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        q_pos = q_off + lax.broadcasted_iota(jnp.int32, (rows, cols),
+                                             1 - k_axis)
         mask = mask & (k_pos <= q_pos)
     return mask
 
@@ -720,8 +723,352 @@ def _flash_backward(q, k, v, valid_len, out, lse, g, causal=False,
 
 
 # --------------------------------------------------------------------- #
+# the dense pair in the projection's own layout, (B, T, 3*H*D)
+# --------------------------------------------------------------------- #
+#
+# A second dense pair beside the (B, H, T, D) one above, for
+# self-attention straight off the fused projection. Operands are column
+# blocks of the packed (B, T, 3*H*D) array, heads side by side on the
+# lanes: the same array is handed over three times with column offsets
+# 0, H*D, 2*H*D, the output is (B, T, H*D) and the backward writes ONE
+# (B, T, 3*H*D) gradient. Why (the v5e compiler's own layouts at
+# BERT-large's B=32, T=512, H=16, D=64): a (B, H, T, 64) operand is
+# `bf16[32,16,512,64]{3,2,1,0:T(8,128)(2,1)}`, D=64 minor under a
+# 128-lane tile, so every tile is half empty and the kernels and the
+# `copy`/`slice_bitcast_fusion`/`add_bitcast_fusion` relayouts round
+# them (five a layer forward, four backward) move twice the bytes;
+# lse/delta as `f32[32,16,512,1]` pad 128-fold. Here a lane group is 128
+# lanes = two D=64 heads (or one D>=128 head), every load and store is a
+# whole lane group, and the per-row statistics travel with T on the
+# lanes. Same mathematics and precision as the pair above; the two share
+# ``_tile_mask`` and nothing else (``packed_dense_eligible`` says which
+# call sites get this one, and why only those).
+
+_LANES = 128
+
+
+def _lane_group(D):
+    """(lanes, heads) of one lane group: the unit the packed dense
+    kernels load and store, so every access is lane-dense."""
+    return max(D, _LANES), max(1, _LANES // D)
+
+
+def packed_dense_shapes(T, H, D):
+    """The shapes the packed dense pair takes: T within the dense limit
+    and a multiple of the lane tile (the statistics ride the lanes),
+    heads filling whole lane groups (D=64 with H even, or D a multiple
+    of 128)."""
+    return (_use_dense(T, T) and T % _LANES == 0
+            and (D == 64 or D % _LANES == 0)
+            and H % _lane_group(D)[1] == 0)
+
+
+# one program's double-buffered blocks stay under this; v5e has 128 MiB
+# of VMEM and the f32 score tiles need their share
+_PACKED_BLOCK_BUDGET = 24 << 20
+
+
+def _packed_hpp(H, D, T, itemsize, bwd=False):
+    """Heads per program: the largest divisor of H, in whole lane
+    groups, whose blocks fit ``_PACKED_BLOCK_BUDGET`` — the whole row
+    (grid (B, 1)) at every size a cell runs. Derived from static shapes
+    only, so resolving it at trace time cannot go stale."""
+    heads = _lane_group(D)[1]
+    # forward: q, k, v, out; backward adds d(out) and the gradient's
+    # three parts
+    rows = 8 * T if bwd else 4 * T
+    return heads * _largest_divisor(
+        H // heads, H // heads, 2 * rows * heads * D * itemsize,
+        _PACKED_BLOCK_BUDGET)
+
+
+def _packed_vmem_limit(block_bytes, T, tiles):
+    """Scoped-VMEM request for one packed dense program: its blocks,
+    double-buffered by Pallas, ``tiles`` (T, T) f32 tiles for the heads
+    of a loop turn in flight, and 4 MiB of slack. Mosaic's default
+    scoped limit is 16 MiB; the request is a cap, not an allocation.
+    (AOT compile against v5e:2x2: inside the BERT-large step the forward
+    needs 11 MiB and the backward 19-20.)"""
+    need = 2 * block_bytes + tiles * T * T * 4 + (4 << 20)
+    return min(max(need, 16 << 20), 100 << 20)
+
+
+def _lanes_at(i, width):
+    """Lane slice ``[i*width, (i+1)*width)``; ``i`` static or traced,
+    ``width`` a multiple of the lane tile."""
+    from jax.experimental import pallas as pl
+    if isinstance(i, int):
+        return pl.ds(i * width, width)
+    return pl.ds(pl.multiple_of(i * width, _LANES), width)
+
+
+# lane groups a loop turn: two (four D=64 heads) let the scheduler run
+# one group's matmuls under the other's softmax, 6% off the pair at both
+# cells' shapes against one a turn; all of them at once gains 2% more
+# and needs every group's tiles live (PR 28's builder runs, v5e)
+_GROUP_UNROLL = 2
+
+
+def _for_groups(n, body):
+    """Run ``body(i)`` for the ``n`` lane groups of a block,
+    ``_GROUP_UNROLL`` a turn of a ``fori_loop`` (in place where that is
+    all of them) — a fully unrolled loop keeps every group's score
+    tiles live (the (B, H, T, D) forward: 37.41M of scoped VMEM for 16
+    heads)."""
+    u = _GROUP_UNROLL if n % _GROUP_UNROLL == 0 else 1
+    if n == u:
+        for i in range(n):
+            body(i)
+        return
+
+    def turn(j, carry):
+        for r in range(u):
+            body(j * u + r)
+        return carry
+
+    lax.fori_loop(0, n // u, turn, 0)
+
+
+def _packed_fwd_kernel(vl_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+                       scale, causal, D):
+    from jax.experimental import pallas as pl
+
+    vl = vl_ref[pl.program_id(0), 0]
+    T, W = q_ref.shape[1:]
+    L, heads = _lane_group(D)
+    mask = _tile_mask(T, T, vl, causal)
+    stat_lanes = _LANES // heads
+
+    def group(i):
+        lanes = _lanes_at(i, L)
+        qg, kg, vg = q_ref[0, :, lanes], k_ref[0, :, lanes], \
+            v_ref[0, :, lanes]
+        outs, stats = [], []
+        for h in range(heads):                 # the group's heads
+            q, k, v = (x[:, h * D:(h + 1) * D] for x in (qg, kg, vg))
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
+                        precision=lax.Precision.DEFAULT) * scale
+            s = jnp.where(mask, s, _NEG_INF)
+            m = jnp.max(s, axis=-1, keepdims=True)           # (T, 1)
+            p = jnp.exp(s - m)
+            l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+            o = jnp.dot(p.astype(v.dtype), v,
+                        preferred_element_type=jnp.float32,
+                        precision=lax.Precision.DEFAULT) / l
+            # zero fully-masked rows (vl==0) instead of the uniform mean
+            # of V, and pin their lse to _NEG_INF (see the streaming
+            # kernel for the rationale)
+            row_ok = m > _NEG_INF / 2
+            outs.append(jnp.where(row_ok, o, 0.0).astype(o_ref.dtype))
+            stats.append(jnp.broadcast_to(
+                jnp.where(row_ok, m + jnp.log(l), _NEG_INF),
+                (T, stat_lanes)))
+        o_ref[0, :, lanes] = outs[0] if heads == 1 else \
+            jnp.concatenate(outs, axis=1)
+        # the statistics leave with T on the lanes: one (T, 128)
+        # transpose a group, a head's row every ``stat_lanes`` sublanes
+        lse_t = (stats[0] if heads == 1 else
+                 jnp.concatenate(stats, axis=1)).T           # (128, T)
+        for h in range(heads):
+            lse_ref[0, i, h:h + 1, :] = \
+                lse_t[h * stat_lanes:h * stat_lanes + 1, :]
+
+    _for_groups(W // L, group)
+
+
+def _packed_bwd_kernel(vl_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                       grad_ref, *, scale, causal, D, part_cols):
+    """Scores are rebuilt TRANSPOSED, (Tk, Tq): the saved lse and delta
+    broadcast along sublanes from their lane-dense rows, and dv, dk are
+    plain products; only dq needs a transposed operand (the (Tq, Tk)
+    form needs two). ``grad_ref``: the whole-row (1, T, 3*H*D) block,
+    resident across the head axis, its dq | dk | dv parts ``part_cols``
+    apart."""
+    from jax.experimental import pallas as pl
+
+    vl = vl_ref[pl.program_id(0), 0]
+    T, W = q_ref.shape[1:]
+    L, heads = _lane_group(D)
+    mask_t = _tile_mask(T, T, vl, causal, k_axis=0)
+    groups = W // L
+    first = pl.program_id(1) * groups          # this block's first group
+
+    def group(i):
+        lanes = _lanes_at(i, L)
+        qg, kg, vg, dog = (r[0, :, lanes]
+                           for r in (q_ref, k_ref, v_ref, do_ref))
+        # delta = rowsum(dO * O), lane-dense: the product transposed
+        # once a group, then a sublane sum a head
+        prod_t = (dog.astype(jnp.float32)
+                  * o_ref[0, :, lanes].astype(jnp.float32)).T  # (L, T)
+        lse = lse_ref[0, i]                                # (heads, T)
+        grads = ([], [], [])
+        for h in range(heads):
+            q, k, v, do = (x[:, h * D:(h + 1) * D]
+                           for x in (qg, kg, vg, dog))
+            delta = jnp.sum(prod_t[h * D:(h + 1) * D], axis=0,
+                            keepdims=True)                   # (1, T)
+            s_t = jnp.dot(k, q.T, preferred_element_type=jnp.float32,
+                          precision=lax.Precision.DEFAULT) * scale
+            p_t = jnp.where(mask_t, jnp.exp(s_t - lse[h:h + 1]), 0.0)
+            dv = jnp.dot(p_t.astype(do.dtype), do,
+                         preferred_element_type=jnp.float32,
+                         precision=lax.Precision.DEFAULT)
+            dp_t = jnp.dot(v, do.T, preferred_element_type=jnp.float32,
+                           precision=lax.Precision.DEFAULT)
+            ds_t = (p_t * (dp_t - delta) * scale).astype(k.dtype)
+            dk = jnp.dot(ds_t, q, preferred_element_type=jnp.float32,
+                         precision=lax.Precision.DEFAULT)
+            dq = lax.dot_general(ds_t, k, (((0,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32,
+                                 precision=lax.Precision.DEFAULT)
+            for part, g in zip(grads, (dq, dk, dv)):
+                part.append(g.astype(grad_ref.dtype))
+        for c, part in enumerate(grads):
+            grad_ref[0, :, _lanes_at(first + i + c * (part_cols // L),
+                                     L)] = \
+                part[0] if heads == 1 else jnp.concatenate(part, axis=1)
+
+    _for_groups(groups, group)
+
+
+def _packed_specs(qkv, H, bwd=False):
+    """(B, T, D, heads of a lane group, heads a program, the q | k | v
+    column-block specs) for the packed (B, T, 3*H*D) projection: one
+    array, three specs with column offsets 0, H*D, 2*H*D."""
+    from jax.experimental import pallas as pl
+    B, T, W = qkv.shape
+    D = W // (3 * H)
+    hpp = _packed_hpp(H, D, T, qkv.dtype.itemsize, bwd=bwd)
+    G = H // hpp
+    specs = [pl.BlockSpec((1, T, hpp * D),
+                          lambda b, g, c=c: (b, 0, c * G + g))
+             for c in range(3)]
+    return B, T, D, _lane_group(D)[1], hpp, specs
+
+
+@functools.partial(jax.jit, static_argnames=("H", "causal", "scale",
+                                             "interpret"))
+def _packed_fwd_lse(qkv, valid_len, H, causal, scale, interpret):
+    """Single-tile forward over the packed projection: grid (B, H/hpp),
+    whole (T, T) tiles. Returns out (B, T, H*D) and lse
+    (B, H/heads, heads, T), heads = those of a lane group."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, D, heads, hpp, specs = _packed_specs(qkv, H)
+    scale = D ** -0.5 if scale is None else scale
+    vl = jnp.minimum(valid_len.astype(jnp.int32), T).reshape(B, 1)
+    kernel = functools.partial(_packed_fwd_kernel, scale=scale,
+                               causal=causal, D=D)
+    return pl.pallas_call(
+        kernel,
+        name="mxtpu_flash_dense_fwd",
+        grid=(B, H // hpp),
+        in_specs=[pl.BlockSpec((B, 1), lambda b, g: (0, 0),
+                               memory_space=pltpu.SMEM)] + specs,
+        out_specs=[
+            pl.BlockSpec((1, T, hpp * D), lambda b, g: (b, 0, g)),
+            pl.BlockSpec((1, hpp // heads, heads, T),
+                         lambda b, g: (b, g, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, T, H * D), qkv.dtype),
+            jax.ShapeDtypeStruct((B, H // heads, heads, T), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_packed_vmem_limit(
+                4 * T * hpp * D * qkv.dtype.itemsize, T,
+                tiles=4 * _GROUP_UNROLL)),
+        interpret=interpret,
+    )(vl, qkv, qkv, qkv)
+
+
+@functools.partial(jax.jit, static_argnames=("H", "causal", "scale",
+                                             "interpret"))
+def _packed_backward(qkv, valid_len, out, lse, g, H, causal, scale,
+                     interpret):
+    """Fused single-tile backward: ONE kernel for dq, dk and dv, delta
+    computed inside from ``out`` and ``g``. Returns the projection's
+    (B, T, 3*H*D) gradient, written in place: that output block is the
+    whole row and stays in VMEM across the head axis, each program
+    storing its columns of the three parts."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, T, D, heads, hpp, specs = _packed_specs(qkv, H, bwd=True)
+    scale = D ** -0.5 if scale is None else scale
+    vl = jnp.minimum(valid_len.astype(jnp.int32), T).reshape(B, 1)
+    kernel = functools.partial(_packed_bwd_kernel, scale=scale,
+                               causal=causal, D=D, part_cols=H * D)
+    row = pl.BlockSpec((1, T, hpp * D), lambda b, g: (b, 0, g))
+    return pl.pallas_call(
+        kernel,
+        name="mxtpu_flash_dense_bwd",
+        grid=(B, H // hpp),
+        in_specs=[pl.BlockSpec((B, 1), lambda b, g: (0, 0),
+                               memory_space=pltpu.SMEM)] + specs
+        + [row, row,
+           pl.BlockSpec((1, hpp // heads, heads, T),
+                        lambda b, g: (b, g, 0, 0))],
+        out_specs=pl.BlockSpec((1, T, 3 * H * D), lambda b, g: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, T, 3 * H * D), qkv.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_packed_vmem_limit(
+                T * (5 * hpp + 3 * H) * D * qkv.dtype.itemsize, T,
+                tiles=8 * _GROUP_UNROLL)),
+        interpret=interpret,
+    )(vl, qkv, qkv, qkv, out, g.astype(qkv.dtype), lse)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def flash_dense_packed(qkv, valid_len, heads, causal=False, scale=None,
+                       interpret=False):
+    """Dense flash self-attention straight off the projection: ``qkv``
+    (B, T, 3*H*D) with q | k | v side by side, the result (B, T, H*D),
+    the gradient one (B, T, 3*H*D) array. No transpose, no split. For
+    shapes ``packed_dense_shapes`` admits."""
+    return _packed_fwd_lse(qkv, valid_len, heads, causal, scale,
+                           interpret)[0]
+
+
+def _packed_fwd(qkv, valid_len, heads, causal, scale, interpret):
+    out, lse = _packed_fwd_lse(qkv, valid_len, heads, causal, scale,
+                               interpret)
+    return out, (qkv, valid_len, out, lse)
+
+
+def _packed_bwd(heads, causal, scale, interpret, res, g):
+    qkv, valid_len, out, lse = res
+    return _packed_backward(qkv, valid_len, out, lse, g, heads, causal,
+                            scale, interpret), None
+
+
+flash_dense_packed.defvjp(_packed_fwd, _packed_bwd)
+
+
+# --------------------------------------------------------------------- #
 # custom-vjp entry
 # --------------------------------------------------------------------- #
+
+# Trace-time count of which implementation each attention call site got
+# from the dispatchers below: ``dense_packed`` (the dense pair in the
+# projection's layout), ``dense_bhtd`` (the (B, H, T, D) dense pair),
+# ``stream_bhtd`` (the streaming kernels), ``blockwise_jnp`` (no
+# kernel). One count a call site and a trace — a layer traced once
+# counts once however often it runs.
+_DISPATCH = collections.Counter()
+
+
+def dispatch_tally(reset=False):
+    """{implementation: call sites traced so far}; see ``_DISPATCH``.
+    ``profiler.attention_dispatch`` is its public face."""
+    tally = dict(_DISPATCH)
+    if reset:
+        _DISPATCH.clear()
+    return tally
+
 
 class _Static:
     """Pytree-static residual carrier: the forward's trace-time kernel
@@ -795,6 +1142,42 @@ def tpu_kernel_eligible(D, causal=False, Tq=None, Tk=None):
     return on and D <= 256
 
 
+def _active_mesh():
+    from ..parallel.spmd import _ACTIVE_MESH
+    return _ACTIVE_MESH.get()
+
+
+def packed_dense_eligible(T, H, D):
+    """The packed dense pair's selection rule, from what the trace sees:
+    the Pallas kernel runs here, the shapes are the pair's
+    (``packed_dense_shapes``), and the active trainer mesh is absent or
+    has exactly ONE device. A mesh of several devices (dp, fsdp, tp, sp
+    alike) keeps the (B, H, T, D) route: a four-chip process of the
+    benchmark died with SIGSEGV once with this pair under ``shard_map``
+    (PERF.md, PR 28) and no cause is known, while that route has a clean
+    record there."""
+    mesh = _active_mesh()
+    return (tpu_kernel_eligible(D) and packed_dense_shapes(T, H, D)
+            and (mesh is None or mesh.size == 1))
+
+
+def flash_packed_self_attention(qkv, heads, valid_length=None,
+                                causal=False, scale=None):
+    """Self-attention over the packed projection, (B, T, 3*H*D) in and
+    (B, T, H*D) out, for call sites ``packed_dense_eligible`` admits."""
+    B, T, W = qkv.shape
+    if not packed_dense_eligible(T, heads, W // (3 * heads)):
+        raise MXNetError(
+            f"flash_packed_self_attention: T={T}, heads={heads}, "
+            f"D={W // (3 * heads)} under the active mesh is outside "
+            "packed_dense_eligible; use scaled_dot_product_attention")
+    if valid_length is None:
+        valid_length = jnp.full((B,), T, jnp.int32)
+    _DISPATCH["dense_packed"] += 1
+    return flash_dense_packed(qkv, valid_length, heads, causal, scale,
+                              _env_interpret())
+
+
 def use_flash_attention(q, k, v, key_mask=None, causal=False, scale=None,
                         valid_length=None, layout="bthd"):
     """Dispatch helper for ops.attention: (B, T, H, D) in/out by
@@ -827,6 +1210,7 @@ def use_flash_attention(q, k, v, key_mask=None, causal=False, scale=None,
     if not (tpu_kernel_eligible(D, causal, Tq, Tk)
             and valid_length is not None):
         from .attention import _sdpa_blockwise
+        _DISPATCH["blockwise_jnp"] += 1
         sc = D ** -0.5 if scale is None else scale
         if valid_length is not None:
             vlm = lax.broadcasted_iota(jnp.int32, (B, Tk), 1) < \
@@ -841,6 +1225,7 @@ def use_flash_attention(q, k, v, key_mask=None, causal=False, scale=None,
             return out.transpose(0, 2, 1, 3)
         return _sdpa_blockwise(q, k, v, key_mask, causal, sc)
     interp = _env_interpret()
+    _DISPATCH["dense_bhtd" if _use_dense(Tq, Tk) else "stream_bhtd"] += 1
     if layout == "bhtd":
         return _flash_on_mesh(q, k, v, valid_length, causal, scale,
                               interp)
@@ -861,8 +1246,7 @@ def _flash_on_mesh(q, k, v, valid_length, causal, scale, interp):
     (fsdp, dp), heads over tp. Attention is independent per (batch,
     head), so the body needs no collective. Outside a trainer's trace
     (no active mesh) and on a one-device mesh this is the plain call."""
-    from ..parallel.spmd import _ACTIVE_MESH
-    mesh = _ACTIVE_MESH.get()
+    mesh = _active_mesh()
     if mesh is None or mesh.size == 1:
         return flash_attention_bhtd(q, k, v, valid_length, causal, scale,
                                     interp)

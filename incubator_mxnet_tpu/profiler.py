@@ -19,7 +19,8 @@ TPU-native split of responsibilities:
   - **compile events** → ``compile_events()``: what ``jax.monitoring``
     reports of tracing, lowering, backend compiles and the persistent
     cache, each with its ``perf_counter`` stamp, in a bounded list
-    (docs/OBSERVABILITY.md "Compile events").
+    (docs/OBSERVABILITY.md "Compile events"); beside it
+    ``attention_dispatch()``: which kernels each attention call site got.
   - ``mfu(...)`` — model-FLOPs-utilisation meter for the north-star metric
     (SURVEY.md §6); no reference analogue, TPU-specific addition.
 
@@ -46,7 +47,7 @@ from .base import getenv_bool
 __all__ = ["set_config", "start", "stop", "dump", "dumps", "pause", "resume",
            "scope", "scoped", "ProfileEvent", "Counter", "Marker", "mfu",
            "state_string", "session_live", "scope_table",
-           "compile_events", "compile_counts"]
+           "compile_events", "compile_counts", "attention_dispatch"]
 
 _lock = threading.Lock()
 _config = {
@@ -416,6 +417,18 @@ def compile_events() -> List[dict]:
 def compile_counts() -> Dict[str, int]:
     """Lifetime count of each kind of compile event (the list wraps)."""
     return dict(_compile_counts)
+
+
+def attention_dispatch(reset: bool = False) -> Dict[str, int]:
+    """Which implementation each attention call site got, counted as the
+    sites were traced: ``dense_packed`` (the dense flash pair in the
+    projection's (B, T, 3*H*D) layout), ``dense_bhtd`` (the (B, H, T, D)
+    dense pair), ``stream_bhtd`` (the streaming kernels),
+    ``blockwise_jnp`` (no kernel). The tally is
+    ``ops.pallas_attention``'s; read it after a first step, beside
+    ``compile_events()``."""
+    from .ops.pallas_attention import dispatch_tally
+    return dispatch_tally(reset=reset)
 
 
 _register_compile_listeners()

@@ -59,6 +59,8 @@ def _dispatch_calls():
     one = jnp.ones((1,), jnp.int32)
     return {
         "flash": lambda **kw: pa.use_flash_attention(q4, q4, q4),
+        "flash_packed": lambda **kw: pa.flash_packed_self_attention(
+            jnp.zeros((1, 128, 3 * 2 * 64), jnp.float32), 2),
         "block": lambda **kw: pa.block_attn_lse(
             q4, q4, q4, jnp.full((1,), 2, jnp.int32), False, None,
             kw.get("interpret", False)),
@@ -89,7 +91,7 @@ def test_tpu_dispatch_raises_on_interpret_mode(monkeypatch, site):
     monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
     with pytest.raises(mx.MXNetError, match="interpret mode"):
         _dispatch_calls()[site]()
-    if site != "flash":                # the ragged ops' explicit argument
+    if not site.startswith("flash"):   # the ragged ops' explicit argument
         monkeypatch.delenv("MXTPU_FLASH_INTERPRET")
         with pytest.raises(mx.MXNetError, match="interpret mode"):
             _dispatch_calls()[site](interpret=True)

@@ -415,3 +415,188 @@ def test_packed_fast_path_matches_kernels_interpret(monkeypatch):
         dst[k_].set_data(v_.data())
     s2, _ = m2(ids, None, vl)
     np.testing.assert_allclose(s2.asnumpy(), base, rtol=2e-3, atol=2e-3)
+
+
+# --------------------------------------------------------------------- #
+# PR 29: on one device the dense flash pair reads the projection's own
+# layout; a mesh of several devices keeps the (3, B, H, T, D) route
+# --------------------------------------------------------------------- #
+
+def _attention_cell(kind, units, heads):
+    from incubator_mxnet_tpu.models.bert import BERTSelfAttention
+    from incubator_mxnet_tpu.models.gpt import CausalSelfAttention
+    cls = BERTSelfAttention if kind == "bert" else CausalSelfAttention
+    cell = cls(units, heads, dropout=0.0, flash=True)
+    cell.initialize()
+    return cell
+
+
+def _pure_cell(cell, call=None):
+    """(fn(param values, x) -> output arrays, param values): the cell as
+    a pure function, traced the way the trainer's step traces it.
+    ``call(cell, x)`` stands in for the cell's own forward."""
+    import jax
+    from incubator_mxnet_tpu import autograd
+    from incubator_mxnet_tpu.gluon.block import _hybrid_trace_scope
+    from incubator_mxnet_tpu.ndarray import NDArray
+    params = list(cell.collect_params().values())
+
+    def fn(vals, x):
+        saved = [p._data for p in params]
+        for p, v in zip(params, vals):
+            p._data = NDArray(v)
+        try:
+            with _hybrid_trace_scope(), \
+                    autograd._ModeScope(recording=False, training=True):
+                out = cell.hybrid_call(NDArray(x)) if call is None \
+                    else call(cell, NDArray(x))
+                return jax.tree_util.tree_map(
+                    lambda o: o._data, out,
+                    is_leaf=lambda o: isinstance(o, NDArray))
+        finally:
+            for p, s_ in zip(params, saved):
+                p._data = s_
+    return fn, [p.data()._data for p in params]
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it, a
+    Pallas kernel's own body left out (its in-register transposes are
+    not relayouts in HBM)."""
+    import jax
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _activation_transposes(jaxpr):
+    """Transposes of arrays of more than two dimensions (weights are
+    2-D: theirs are not relayouts of activations)."""
+    return [e for e in _eqns(jaxpr) if e.primitive.name == "transpose"
+            and e.invars[0].aval.ndim > 2]
+
+
+@pytest.mark.parametrize("kind", ["bert", "gpt"])
+def test_one_device_attention_has_no_relayout_between_projections(
+        kind, monkeypatch):
+    """On one device, at the packed pair's shapes, the cell's forward +
+    backward holds no transpose of an activation and no concatenation
+    between the qkv projection and the output projection: the kernels
+    read (B, T, 3*H*D) and write (B, T, H*D)."""
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu import profiler
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    cell = _attention_cell(kind, 128, 2)
+    fn, vals = _pure_cell(cell)
+    x = jnp.ones((2, 128, 128), jnp.float32)
+    profiler.attention_dispatch(reset=True)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda vals, x: fn(vals, x).sum(), argnums=(0, 1)))(vals, x)
+    assert profiler.attention_dispatch(reset=True) == {"dense_packed": 1}
+    eqns = list(_eqns(jaxpr.jaxpr))
+    assert sum(e.primitive.name == "pallas_call" for e in eqns) == 2
+    assert not _activation_transposes(jaxpr.jaxpr)
+    assert not [e for e in eqns if e.primitive.name == "concatenate"]
+
+
+@pytest.mark.parametrize("kind", ["bert", "gpt"])
+def test_four_device_mesh_keeps_the_relayout_route(kind, monkeypatch):
+    """Under the CPU's four-device mesh (dp=4) the same cells, at shapes
+    the packed pair would take on one device, take the route they took
+    before PR 29: the relayout to (3, B, H, T, D) and
+    ``flash_attention_bhtd`` under ``shard_map``. The tally reads
+    ``dense_bhtd``, the jaxpr holds that route's transposes, and loss
+    and gradients equal those of that route called directly, bit for
+    bit."""
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu import nd, profiler
+    from incubator_mxnet_tpu.models import _attention as att
+    from incubator_mxnet_tpu.ops import pallas_attention as pa
+    from incubator_mxnet_tpu.parallel import mesh as pmesh
+    from incubator_mxnet_tpu.parallel.spmd import (
+        activation_sharding_scope, constrain)
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    B, T, H, D = 4, 128, 2, 64
+    assert pa.packed_dense_eligible(T, H, D)          # one device: yes
+    mesh = pmesh.build_mesh(devices=jax.devices()[:4],
+                            axis_sizes={"dp": 4})
+    cell = _attention_cell(kind, H * D, H)
+    fn, vals = _pure_cell(cell)
+    x, w = (jax.random.normal(jax.random.PRNGKey(i), (B, T, H * D))
+            for i in (0, 1))
+
+    def relayout_route(cell, x):
+        """The cell's own projections round the route called directly."""
+        out = att.relayout_flash_self_attention(
+            nd, cell.qkv(x), B, T, H, D, H * D, kind == "gpt", None, None,
+            None)
+        return constrain(cell.dropout(cell.proj(out)), ("dp", "fsdp"),
+                         None, None)
+
+    direct, _ = _pure_cell(cell, relayout_route)
+
+    def loss_and_grads(f):
+        def body(vals, x):
+            with activation_sharding_scope(mesh):
+                return jnp.sum(f(vals, x) * w)
+        return jax.value_and_grad(body, argnums=(0, 1))
+
+    profiler.attention_dispatch(reset=True)
+    jaxpr = jax.make_jaxpr(loss_and_grads(fn))(vals, x)
+    assert profiler.attention_dispatch(reset=True) == {"dense_bhtd": 1}
+    eqns = list(_eqns(jaxpr.jaxpr))
+    assert sum(e.primitive.name == "shard_map" for e in eqns) == 2
+    assert sum(e.primitive.name == "pallas_call" for e in eqns) == 2
+    want_jaxpr = jax.make_jaxpr(loss_and_grads(direct))(vals, x)
+    perms = lambda j: sorted(e.params["permutation"]
+                             for e in _activation_transposes(j.jaxpr))
+    assert perms(jaxpr) == perms(want_jaxpr)
+    assert (2, 0, 3, 1, 4) in perms(jaxpr)    # to (3, B, H, T, D)
+
+    got = jax.jit(loss_and_grads(fn))(vals, x)
+    want = jax.jit(loss_and_grads(direct))(vals, x)
+    profiler.attention_dispatch(reset=True)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("kind,layers,heads,mesh,want", [
+    ("bert", 24, 16, None, "dense_packed"),     # bertl-train's stack
+    ("gpt", 12, 12, None, "dense_packed"),      # chip_smoke's train leg
+    ("gpt", 12, 12, 4, "dense_bhtd"),           # gpt2s-train-dp4's stack
+])
+def test_every_layer_of_the_cells_models_is_tallied(
+        kind, layers, heads, mesh, want, monkeypatch):
+    """A BERT-large-shaped and a GPT-2-small-shaped stack at the cells'
+    head sizes (16 and 12 heads of 64, T a multiple of 128): on one
+    device every layer's attention call site is tallied
+    ``dense_packed``, under a (dp=4) mesh ``dense_bhtd``, none anything
+    else."""
+    import contextlib
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu import profiler
+    from incubator_mxnet_tpu.models.bert import BERTModel
+    from incubator_mxnet_tpu.models.gpt import GPTModel
+    from incubator_mxnet_tpu.parallel import mesh as pmesh
+    from incubator_mxnet_tpu.parallel.spmd import activation_sharding_scope
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    cls = BERTModel if kind == "bert" else GPTModel
+    model = cls(vocab_size=64, units=heads * 64, hidden_size=64,
+                num_layers=layers, num_heads=heads, max_length=128,
+                dropout=0.0, flash=True)
+    model.initialize()
+    fn, vals = _pure_cell(model)
+    scope = contextlib.nullcontext() if mesh is None else \
+        activation_sharding_scope(pmesh.build_mesh(
+            devices=jax.devices()[:mesh], axis_sizes={"dp": mesh}))
+    profiler.attention_dispatch(reset=True)
+    with scope:
+        jax.eval_shape(fn, vals, jnp.zeros((4, 128), jnp.int32))
+    assert profiler.attention_dispatch(reset=True) == {want: layers}

@@ -689,6 +689,9 @@ DIST_CHECK = {
 # differentiable but excluded here, with reasons
 SKIP = {
     "Dropout": "stochastic mask; parity-tested in tests/test_nn_ops.py",
+    "flash_attention_packed": "Pallas-kernel-only op (raises where no "
+                              "kernel runs); gradient parity in "
+                              "tests/test_pallas_attention.py",
     "shuffle": "random permutation",
     "random_bernoulli": "sampler", "random_exponential": "sampler",
     "random_generalized_negative_binomial": "sampler",

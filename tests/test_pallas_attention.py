@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from incubator_mxnet_tpu.ops.attention import _sdpa_dense
+from incubator_mxnet_tpu.ops import pallas_attention as pa
 from incubator_mxnet_tpu.ops.pallas_attention import (
     _flash_forward, flash_attention_bhtd, use_flash_attention)
 
@@ -355,3 +356,278 @@ def test_fully_masked_rows_zero_output_and_safe_grads(kernel_path):
     np.testing.assert_array_equal(np.asarray(fb)[0], 0.0)
     np.testing.assert_allclose(np.asarray(fb).transpose(0, 2, 1, 3),
                                out_np, rtol=2e-5, atol=2e-5)
+
+
+# --------------------------------------------------------------------- #
+# PR 29: the dense pair in the projection's own layout, (B, T, 3*H*D)
+# --------------------------------------------------------------------- #
+
+_PACKED_CASES = {
+    # BERT-large's heads, a full row and a short one
+    "H16-D64-full": dict(B=2, H=16, D=64, T=256, causal=False,
+                         vl=(256, 101)),
+    # GPT-2-small's heads, causal
+    "H12-D64-causal": dict(B=2, H=12, D=64, T=256, causal=True,
+                           vl=(256, 256)),
+    # one head a lane group
+    "H2-D128-causal": dict(B=2, H=2, D=128, T=128, causal=True,
+                           vl=(128, 77)),
+}
+
+
+def _packed_reference(qkv, vl, H, causal):
+    """jnp oracle over the packed (B, T, 3*H*D) projection."""
+    B, T, W = qkv.shape
+    D = W // (3 * H)
+    q, k, v = (x.reshape(B, T, H, D) for x in jnp.split(qkv, 3, axis=-1))
+    m = (jnp.arange(T)[None, :] < vl[:, None])[:, None, None, :]
+    if causal:
+        m = m & jnp.tril(jnp.ones((T, T), bool))[None, None]
+    return _sdpa_dense(q, k, v, m, D ** -0.5).reshape(B, T, H * D)
+
+
+@pytest.fixture(scope="module")
+def packed_case_results():
+    """Forward and gradient of every packed case, computed once: the
+    kernels (interpreted), the operator over them, and the jnp oracle."""
+    cache = {}
+
+    def get(name):
+        if name in cache:
+            return cache[name]
+        c = _PACKED_CASES[name]
+        B, H, D, T, causal = c["B"], c["H"], c["D"], c["T"], c["causal"]
+        assert pa.packed_dense_shapes(T, H, D)
+        rng = np.random.RandomState(5)
+        qkv = jnp.asarray(rng.randn(B, T, 3 * H * D), jnp.float32)
+        g = jnp.asarray(rng.randn(B, T, H * D), jnp.float32)
+        vl = jnp.asarray(c["vl"], jnp.int32)
+
+        def run(fn):
+            out, vjp = jax.vjp(fn, qkv)
+            return out, vjp(g)[0]
+
+        kernel = run(lambda x: pa.flash_dense_packed(x, vl, H, causal,
+                                                     None, True))
+        oracle = run(lambda x: _packed_reference(x, vl, H, causal))
+        cache[name] = (kernel, oracle, (qkv, vl, g, H, causal))
+        return cache[name]
+    return get
+
+
+@pytest.mark.parametrize("what", ["out", "dq", "dk", "dv", "entry"])
+@pytest.mark.parametrize("case", sorted(_PACKED_CASES))
+def test_packed_dense_pair_matches_reference(case, what, packed_case_results,
+                                             monkeypatch):
+    """The packed dense pair against the jnp oracle at the cells' head
+    sizes: the output, the three parts of its ONE (B, T, 3*H*D)
+    gradient, and the operator's entry (``flash_attention_packed``, as
+    the transformer cells call it) equal to the kernels called directly,
+    output and single gradient, bit for bit."""
+    kernel, oracle, (qkv, vl, g, H, causal) = packed_case_results(case)
+    assert kernel[1].shape == qkv.shape
+    if what == "entry":
+        from incubator_mxnet_tpu.ops.attention import flash_attention_packed
+        monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+        out, vjp = jax.vjp(lambda x: flash_attention_packed(
+            x, valid_length=vl, heads=H, causal=causal), qkv)
+        np.testing.assert_array_equal(np.asarray(out),
+                                      np.asarray(kernel[0]))
+        np.testing.assert_array_equal(np.asarray(vjp(g)[0]),
+                                      np.asarray(kernel[1]))
+        return
+    if what == "out":
+        got, want = kernel[0], oracle[0]
+    else:
+        i = ("dq", "dk", "dv").index(what)
+        got, want = (jnp.split(x[1], 3, axis=-1)[i]
+                     for x in (kernel, oracle))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_packed_dense_pair_zeroes_fully_masked_rows():
+    """valid_len 0: zero output (not the mean of V) and zero, finite
+    gradients, as every other kernel family gives."""
+    B, H, D, T = 2, 2, 64, 128
+    rng = np.random.RandomState(3)
+    qkv = jnp.asarray(rng.randn(B, T, 3 * H * D), jnp.float32)
+    vl = jnp.asarray([0, T], jnp.int32)
+    out, vjp = jax.vjp(lambda x: pa.flash_dense_packed(
+        x, vl, H, False, None, True), qkv)
+    grad = np.asarray(vjp(jnp.ones_like(out))[0])
+    np.testing.assert_array_equal(np.asarray(out)[0], 0.0)
+    np.testing.assert_array_equal(grad[0], 0.0)
+    assert np.isfinite(grad).all() and np.abs(grad[1]).max() > 0
+
+
+def test_packed_dense_pair_in_column_blocks(monkeypatch):
+    """Rows too wide for ``_PACKED_BLOCK_BUDGET`` go in column blocks of
+    fewer heads a program (grid (B, H/hpp)); the gradient's whole-row
+    block then stays resident across the head axis. Same numbers as
+    whole rows, bit for bit."""
+    B, H, D, T = 2, 4, 64, 128
+    rng = np.random.RandomState(9)
+    qkv, g = (jnp.asarray(rng.randn(B, T, n * H * D), jnp.float32)
+              for n in (3, 1))
+    vl = jnp.asarray([T, 60], jnp.int32)
+
+    def run():
+        jax.clear_caches()
+        out, vjp = jax.vjp(lambda x: pa.flash_dense_packed(
+            x, vl, H, True, None, True), qkv)
+        return out, vjp(g)[0]
+
+    assert pa._packed_hpp(H, D, T, 4, bwd=True) == H
+    whole = run()
+    monkeypatch.setattr(pa, "_PACKED_BLOCK_BUDGET", 1 << 19)
+    assert pa._packed_hpp(H, D, T, 4, bwd=True) == 2
+    for a, b in zip(run(), whole):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    jax.clear_caches()
+
+
+def _mesh_of(n):
+    from incubator_mxnet_tpu.parallel import mesh as pmesh
+    if n is None:
+        return None
+    if isinstance(n, dict):
+        size = int(np.prod(list(n.values())))
+        return pmesh.build_mesh(devices=jax.devices()[:size], axis_sizes=n)
+    return pmesh.build_mesh(devices=jax.devices()[:n], axis_sizes={"dp": n})
+
+
+@pytest.mark.parametrize("T,H,D,mesh,kernel,want", [
+    (512, 16, 64, None, True, True),       # bertl-train outside a trainer
+    (512, 16, 64, 1, True, True),          # bertl-train: a mesh of one
+    (512, 12, 64, 1, True, True),          # GPT-2-small on one chip
+    (128, 2, 128, None, True, True),       # one head a lane group
+    (256, 4, 256, None, True, True),
+    (512, 12, 64, 4, True, False),         # gpt2s-train-dp4: four devices
+    (512, 16, 64, {"fsdp": 2}, True, False),
+    (512, 16, 64, {"dp": 2, "tp": 2}, True, False),
+    (512, 16, 64, {"sp": 2}, True, False),
+    (512, 16, 64, 1, False, False),        # no Pallas kernel runs here
+    (1024, 12, 64, None, True, False),     # over the dense limit
+    (64, 4, 64, None, True, False),        # T not a multiple of 128
+    (512, 3, 64, None, True, False),       # odd number of D=64 heads
+    (128, 8, 16, None, True, False),       # the tiny test models' D
+    (128, 4, 32, None, True, False),
+    (128, 2, 192, None, True, False),      # D neither 64 nor n*128
+])
+def test_packed_dense_selection_rule(T, H, D, mesh, kernel, want,
+                                     monkeypatch):
+    """``packed_dense_eligible`` from shapes, the kernel's presence and
+    the active trainer mesh: only an absent or one-device mesh gets the
+    packed pair."""
+    from incubator_mxnet_tpu.parallel.spmd import activation_sharding_scope
+    if kernel:
+        monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    with activation_sharding_scope(_mesh_of(mesh)):
+        assert pa.packed_dense_eligible(T, H, D) is want
+    if want:      # whole rows a program at every size a cell runs
+        assert pa._packed_hpp(H, D, T, 2) == H
+        assert pa._packed_hpp(H, D, T, 2, bwd=True) == H
+
+
+def test_packed_entry_refuses_call_sites_outside_the_rule(monkeypatch):
+    """The operator does not fall back: outside the rule it raises."""
+    from incubator_mxnet_tpu.base import MXNetError
+    from incubator_mxnet_tpu.parallel.spmd import activation_sharding_scope
+    z = jnp.zeros((4, 128, 3 * 2 * 64), jnp.float32)
+    with pytest.raises(MXNetError, match="packed_dense_eligible"):
+        pa.flash_packed_self_attention(z, 2)          # no kernel on CPU
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    jax.eval_shape(lambda x: pa.flash_packed_self_attention(x, 2), z)
+    with activation_sharding_scope(_mesh_of(4)):
+        with pytest.raises(MXNetError, match="packed_dense_eligible"):
+            pa.flash_packed_self_attention(z, 2)
+    pa.dispatch_tally(reset=True)
+
+
+def test_dispatch_tally_names_each_call_site(monkeypatch):
+    """``dispatch_tally`` (``profiler.attention_dispatch``) counts, at
+    trace time, which implementation each attention call site got."""
+    from incubator_mxnet_tpu import profiler
+    monkeypatch.setenv("MXTPU_FLASH_INTERPRET", "1")
+    profiler.attention_dispatch(reset=True)
+    z = lambda *s: jnp.zeros(s, jnp.float32)
+    jax.eval_shape(lambda q: use_flash_attention(q, q, q), z(1, 128, 2, 64))
+    jax.eval_shape(lambda q: use_flash_attention(q, q, q, layout="bhtd"),
+                   z(1, 2, 128, 64))
+    jax.eval_shape(lambda q: use_flash_attention(q, q, q),
+                   z(1, 640, 2, 8))                  # over the dense limit
+    jax.eval_shape(lambda x: pa.flash_packed_self_attention(x, 2),
+                   z(1, 128, 3 * 2 * 64))
+    monkeypatch.delenv("MXTPU_FLASH_INTERPRET")
+    jax.eval_shape(lambda q: use_flash_attention(q, q, q), z(1, 128, 2, 64))
+    assert profiler.attention_dispatch(reset=True) == {
+        "dense_packed": 1, "dense_bhtd": 2, "stream_bhtd": 1,
+        "blockwise_jnp": 1}
+    assert profiler.attention_dispatch() == {}
+
+
+# --------------------------------------------------------------------- #
+# the packed pair compiled for the chip, without one (Mosaic + XLA:TPU
+# against a described v5e: lowering faults and relayouts, no time)
+# --------------------------------------------------------------------- #
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """An AOT compile for a described chip is written to the persistent
+    cache but cannot be read back without one: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("B,H,D,T,causal", [
+    (32, 16, 64, 512, False),      # bertl-train, a layer
+    (16, 12, 64, 512, True),       # GPT-2-small, a layer on one chip
+])
+def test_packed_dense_pair_compiles_for_v5e_with_no_relayout(
+        v5e_chip, no_compile_cache, B, H, D, T, causal):
+    """At the cells' sizes the packed forward + backward compile for the
+    v5e to the two custom calls over the projection itself: no ``copy``,
+    ``transpose`` or fusion touches a bf16 array around them."""
+    import re
+
+    def fwd_bwd(qkv, vl, g):
+        out, vjp = jax.vjp(lambda x: pa.flash_dense_packed(
+            x, vl, H, causal, None, False), qkv)
+        return out, vjp(g)[0]
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    text = jax.jit(fwd_bwd).lower(
+        arg((B, T, 3 * H * D), jnp.bfloat16), arg((B,), jnp.int32),
+        arg((B, T, H * D), jnp.bfloat16)).compile().as_text()
+    calls = re.findall(r"%(mxtpu_flash_dense_\w+?)[.\d]* = (.*?) "
+                       r"custom-call\((.*?)\), "
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert sorted(c[0] for c in calls) == ["mxtpu_flash_dense_bwd",
+                                           "mxtpu_flash_dense_fwd"]
+    fwd, bwd = sorted(calls, key=lambda c: c[0], reverse=True)
+    assert f"bf16[{B},{T},{H * D}]" in fwd[1]
+    assert f"f32[{B},{H // 2},2,{T}]" in fwd[1]      # lse, T on the lanes
+    assert len(set(fwd[2].split(", ")[1:])) == 1     # the projection x3
+    assert bwd[1].startswith(f"bf16[{B},{T},{3 * H * D}]")
+    moved = re.findall(r"= bf16\[[^ ]* (copy|transpose|fusion)\(", text)
+    assert not moved, moved
