@@ -235,10 +235,10 @@ def _flash_case(ctx, results, name, B, H, T, D, causal, want,
 
 
 def _ragged_inputs(S, H, D, ps, max_pages, page_need, seed, quant):
-    """Random pools + page tables; ``page_need[s]`` live pages per slot
-    (distinct, never the null page). The null page is poisoned (NaN
-    payload, or a NaN scale for int8 pools): a masked read that leaked
-    would show."""
+    """A random fused pool (keys | values on the lanes) + page tables;
+    ``page_need[s]`` live pages per slot (distinct, never the null
+    page). The null page is poisoned (NaN payload, or a NaN scale for
+    int8 pools): a masked read that leaked would show."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -254,9 +254,8 @@ def _ragged_inputs(S, H, D, ps, max_pages, page_need, seed, quant):
         for j in range(n):
             table[s, j] = perm.pop()
     if not quant:
-        k_pool = k_pool.at[0].set(jnp.nan)
-        v_pool = v_pool.at[0].set(jnp.nan)
-        return k_pool, v_pool, jnp.asarray(table), None, None
+        pool = jnp.concatenate([k_pool, v_pool], -1).at[0].set(jnp.nan)
+        return pool, jnp.asarray(table), None, None
 
     def q8(pool):
         f = pool.astype(jnp.float32)
@@ -266,7 +265,7 @@ def _ragged_inputs(S, H, D, ps, max_pages, page_need, seed, quant):
         return codes, scale.at[0].set(jnp.nan)
 
     (k8, ksc), (v8, vsc) = q8(k_pool), q8(v_pool)
-    return k8, v8, jnp.asarray(table), ksc, vsc
+    return jnp.concatenate([k8, v8], -1), jnp.asarray(table), ksc, vsc
 
 
 def _ragged_cases(ctx, results, quant):
@@ -276,7 +275,7 @@ def _ragged_cases(ctx, results, quant):
     from incubator_mxnet_tpu.ops import ragged_attention as ra
 
     z = ctx["size"]
-    H, D, ps, max_pages = z["H"], z["D"], 16, z["max_len"] // 16
+    H, D, ps, max_pages = z["ragged_H"], z["D"], 16, z["max_len"] // 16
     lengths = z["ragged_lengths"]
     S = len(lengths)
     interp = True if ctx["rehearsal"] else None
@@ -285,18 +284,18 @@ def _ragged_cases(ctx, results, quant):
     pages = lambda n: -(-n // ps)
 
     # decode: one query per slot
-    kp, vp, table, ksc, vsc = _ragged_inputs(
+    pool, table, ksc, vsc = _ragged_inputs(
         S, H, D, ps, max_pages, [pages(n) for n in lengths], 11, quant)
     q = jax.random.normal(jax.random.PRNGKey(1), (S, H, D), jnp.bfloat16)
     ln = jnp.asarray(lengths, jnp.int32)
     got, kern = _compile_run(
-        lambda q, kp, vp, t, ln, ks, vs: ra.ragged_paged_attention(
-            q, kp, vp, t, ln, interpret=interp, k_scale=ks, v_scale=vs),
-        q, kp, vp, table, ln, ksc, vsc)
+        lambda q, pool, t, ln, ks, vs: ra.ragged_paged_attention(
+            q, pool, t, ln, interpret=interp, k_scale=ks, v_scale=vs),
+        q, pool, table, ln, ksc, vsc)
     ref = _reference(
-        lambda q, kp, vp, t, ln, ks, vs: ra.ragged_attention_reference(
-            q, kp, vp, t, ln, k_scale=ks, v_scale=vs),
-        q, kp, vp, table, ln, ksc, vsc)
+        lambda q, pool, t, ln, ks, vs: ra.ragged_attention_reference(
+            q, pool, t, ln, k_scale=ks, v_scale=vs),
+        q, pool, table, ln, ksc, vsc)
     for s, n in enumerate(lengths):
         if n == 0:
             check(not bool(jnp.any(got[s] != 0)),
@@ -307,20 +306,20 @@ def _ragged_cases(ctx, results, quant):
     # verify: W query rows per slot, ragged real draft counts
     W = 4
     dl = np.asarray([(s * 3) % W for s in range(S)], np.int32)
-    kp, vp, table, ksc, vsc = _ragged_inputs(
+    pool, table, ksc, vsc = _ragged_inputs(
         S, H, D, ps, max_pages,
         [pages(n + W - 1) if n else 0 for n in lengths], 13, quant)
     q = jax.random.normal(jax.random.PRNGKey(2), (S, W, H, D),
                           jnp.bfloat16)
     got, kern = _compile_run(
-        lambda q, kp, vp, t, ln, dl, ks, vs: ra.ragged_verify_attention(
-            q, kp, vp, t, ln, draft_len=dl, interpret=interp,
+        lambda q, pool, t, ln, dl, ks, vs: ra.ragged_verify_attention(
+            q, pool, t, ln, draft_len=dl, interpret=interp,
             k_scale=ks, v_scale=vs),
-        q, kp, vp, table, ln, jnp.asarray(dl), ksc, vsc)
+        q, pool, table, ln, jnp.asarray(dl), ksc, vsc)
     ref = _reference(
-        lambda q, kp, vp, t, ln, ks, vs: ra.ragged_verify_reference(
-            q, kp, vp, t, ln, k_scale=ks, v_scale=vs),
-        q, kp, vp, table, ln, ksc, vsc)
+        lambda q, pool, t, ln, ks, vs: ra.ragged_verify_reference(
+            q, pool, t, ln, k_scale=ks, v_scale=vs),
+        q, pool, table, ln, ksc, vsc)
     # rows past a slot's real draft count are discarded by the engine
     live = (np.arange(W)[None, :] <= dl[:, None])[:, :, None, None]
     _report(ctx, results, f"ragged_verify{tag}", kern,
@@ -332,24 +331,24 @@ def _ragged_cases(ctx, results, quant):
     for C, spans in z["prefill_cases"]:
         errs = {}
         for start, n_real in spans:
-            kp, vp, table, ksc, vsc = _ragged_inputs(
+            pool, table, ksc, vsc = _ragged_inputs(
                 1, H, D, ps, max_pages, [pages(start + n_real)],
                 17 + start, quant)
             q = jax.random.normal(jax.random.PRNGKey(3 + start),
                                   (C, H, D), jnp.bfloat16)
             qs, nr = jnp.int32(start), jnp.int32(n_real)
             got, kern = _compile_run(
-                lambda q, kp, vp, row, qs, nr, ks, vs:
+                lambda q, pool, row, qs, nr, ks, vs:
                 ra.ragged_prefill_attention(
-                    q, kp, vp, row, qs, n_real=nr, interpret=interp,
+                    q, pool, row, qs, n_real=nr, interpret=interp,
                     k_scale=ks, v_scale=vs),
-                q, kp, vp, table[0], qs, nr, ksc, vsc)
+                q, pool, table[0], qs, nr, ksc, vsc)
             ref = _reference(
-                lambda q, kp, vp, row, qs, nr, ks, vs:
+                lambda q, pool, row, qs, nr, ks, vs:
                 ra.ragged_prefill_reference(
-                    q, kp, vp, row, qs, n_real=nr, k_scale=ks,
+                    q, pool, row, qs, n_real=nr, k_scale=ks,
                     v_scale=vs),
-                q, kp, vp, table[0], qs, nr, ksc, vsc)
+                q, pool, table[0], qs, nr, ksc, vsc)
             errs[f"start{start}+{n_real}"] = _norm_err(got[:n_real],
                                                        ref[:n_real])
         _report(ctx, results, f"ragged_prefill{tag}[C={C}]", kern,
@@ -629,7 +628,7 @@ def leg_serve(ctx):
                 check(k.get("mxtpu_ragged_prefill", 0) >= L,
                       f"{name} program lacks the ragged Mosaic calls: "
                       f"{k}")
-        for arr, what in ((engine._kpools[0], "kv pool"),
+        for arr, what in ((engine._kvpools[0], "kv pool"),
                           (engine._param_vals[0], "weights")):
             plats = {d.platform for d in arr.devices()}
             check(plats == {"tpu"}, f"{what} lives on {plats}")
@@ -674,6 +673,7 @@ def _sizes(rehearsal):
     if rehearsal:
         # gpt_mini: 2 layers, 128 units, 4 heads of 32, context 128
         return {"model": gpt_mod.gpt_mini, "H": 4, "D": 32,
+                "ragged_H": 5,
                 "dense_heads": (4,), "dense_T": 128, "stream_T": 640,
                 "packed_cases": ((4, False), (2, True)),
                 "max_len": 128, "ragged_lengths": (0, 1, 16, 17, 100),
@@ -682,8 +682,10 @@ def _sizes(rehearsal):
                 "train_B": 4, "train_T": 64,
                 "chunk_pages": 2, "prefix_len": 32, "long_len": 70}
     # GPT-2-small: 12 layers, 768 units, 12 heads of 64, context 1024;
-    # the dense flash pair also at BERT-large's 16 heads
+    # the dense flash pair also at BERT-large's 16 heads, the ragged
+    # kernels over the fused page pool at GPT-2-XL's 25
     return {"model": gpt_mod.gpt_small, "H": 12, "D": 64,
+            "ragged_H": 25,
             "dense_heads": (12, 16), "dense_T": 512, "stream_T": 1024,
             "packed_cases": ((16, False), (12, True)),
             "max_len": 1024,

@@ -8,11 +8,16 @@ batch is a set of SLOTS at wildly different sequence lengths, and cache
 memory must scale with live tokens, not ``B × Tmax``. Following the
 ragged-paged-attention design (arxiv 2604.15464; the Gemma-on-TPU
 serving study 2605.25645 attributes most TPU serving wins to this
-batching + cache discipline), K/V live in a shared page pool
+batching + cache discipline), K/V live in ONE shared page pool a layer
 
-    k_pool / v_pool : (num_pages, H, page_size, D)
+    kv_pool : (num_pages, H, page_size, 2 * D)
 
-and each slot owns an ordered list of pages (its PAGE TABLE row). Page 0
+a head's keys in lanes [0, D), its values in lanes [D, 2 * D). At
+D = 64 a head's bf16 page is exactly one native (16, 128) tile, so the
+chip stores the pool row-major, which is what a Mosaic kernel reads: no
+program relays a pool out (a (.., 64)-wide pool is stored page-index
+minor and cost two whole-pool copies a pool a program; PERF.md, PR 33).
+Each slot owns an ordered list of pages (its PAGE TABLE row). Page 0
 is the NULL page: never allocated, dead page-table entries point at it,
 and its contents are garbage by construction — every read of it is
 masked by the slot's length.
@@ -36,6 +41,11 @@ Kernel design (per /opt/skills/guides/pallas_guide.md):
     ``preferred_element_type`` (same dtype discipline as the training
     kernels). Decode attention is a prefix mask — the query IS position
     ``length - 1`` — so no causal triangle is needed.
+  - NO LANE SLICE in the head loop: the query arrives zero-padded to
+    2 * D lanes, so ``q_pad · kvᵀ`` is exactly ``q · kᵀ``; ``p · kv``
+    yields ``[p·k | p·v]`` and the caller keeps lanes [D, 2 * D) of the
+    kernel's output. Contraction and output are one MXU tile wide
+    either way.
 
 Off TPU the dispatchers run a pure-jnp gather-and-mask reference (the
 CPU serving path and the test oracle), or the real kernel in interpret
@@ -72,199 +82,214 @@ __all__ = ["ragged_paged_attention", "ragged_attention_reference",
            "ragged_verify_attention", "ragged_verify_reference"]
 
 
-def _ragged_kernel(pt_ref, ln_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, scale, page_size, n_pages,
-                   heads, ks_ref=None, vs_ref=None):
-    """``ks_ref``/``vs_ref`` (None = unquantized pools, bit-identical
-    to the pre-quantization kernel) are (P,) f32 per-page scale arrays
-    riding the SAME scalar-prefetch path as the page table: the grid
-    step that DMAs page ``pt[s, j]`` reads that page's scale from SMEM
-    and dequantizes the int8/fp8 block inline at the DMA boundary —
-    the pool never materializes in float anywhere."""
+def _split(kv):
+    """Keys (lanes [0, D)) and values (lanes [D, 2 * D)) of a fused
+    array — the one place the jnp paths read the pool's lane layout."""
+    D = kv.shape[-1] // 2
+    return kv[..., :D], kv[..., D:]
+
+
+def _pad_lanes(q):
+    """Zero-pad queries to the pool's 2 * D lanes: against a fused
+    ``keys | values`` tile the padded product is exactly ``q · kᵀ``."""
+    return jnp.pad(q, [(0, 0)] * (q.ndim - 1) + [(0, q.shape[-1])])
+
+
+def _page_scale(scale_refs, page, lanes):
+    """(1, lanes) f32 inline-dequant scales of one quantized page: lanes
+    [0, D) by its key scale, lanes [D, 2 * D) by its value scale; None
+    for an unquantized pool (no scale refs). The (P,) per-page scale
+    arrays ride the SAME scalar-prefetch path as the page table: the
+    grid step that DMAs a page reads that page's scales from SMEM and
+    dequantizes the int8/fp8 block at the DMA boundary — the pool never
+    materializes in float anywhere."""
+    if not scale_refs:
+        return None
+    ks_ref, vs_ref = scale_refs
+    lane = lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    return jnp.where(lane < lanes // 2, ks_ref[page], vs_ref[page])
+
+
+def _init_state(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _accumulate_page(q_ref, kv_ref, m_ref, l_ref, acc_ref, *, heads,
+                     scale, valid, visible, page_scale):
+    """One page's online-softmax update for every head (unrolled).
+    ``valid`` (page_size, 1): positions some consumed query row may
+    read; ``visible`` (rows, page_size): the per-row prefix mask;
+    ``page_scale`` (1, 2 * D) f32 or None (unquantized pool)."""
+    for h in range(heads):
+        q = q_ref[0, h]                 # (rows, 2D), lanes [D, 2D) zero
+        kv = kv_ref[0, h]               # (page_size, 2D): keys | values
+        if page_scale is not None:      # inline dequant
+            q = q.astype(jnp.float32)
+            kv = kv.astype(jnp.float32) * page_scale
+        # SELECT masked rows out of the tile (not just zero-weight
+        # them): a freed page can be reused carrying non-finite garbage
+        # in positions past the new owner's length, and 0 * NaN = NaN
+        # would leak it through the weighted sum (and, keys and values
+        # sharing the tile, through the padded query's zero lanes) —
+        # masked reads must never matter, even poisoned ones (a
+        # quantized pool's NaN channel is the page SCALE — the select
+        # covers it the same way)
+        kv = jnp.where(valid, kv, 0.0)
+        sc = jnp.dot(q, kv.T, preferred_element_type=jnp.float32,
+                     precision=lax.Precision.DEFAULT) * scale
+        sc = jnp.where(visible, sc, _NEG_INF)
+        m_prev = m_ref[h]               # (rows,)
+        l_prev = l_ref[h]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
+        p = jnp.exp(sc - m_new[:, None])        # (rows, page_size) f32
+        alpha = jnp.exp(m_prev - m_new)
+        m_ref[h] = m_new
+        l_ref[h] = l_prev * alpha + jnp.sum(p, axis=-1)
+        # [p·k | p·v]: the caller keeps lanes [D, 2D)
+        acc_ref[h] = acc_ref[h] * alpha[:, None] + jnp.dot(
+            p.astype(kv.dtype), kv, preferred_element_type=jnp.float32,
+            precision=lax.Precision.DEFAULT)
+
+
+def _finalize(o_ref, m_ref, l_ref, acc_ref, heads):
+    for h in range(heads):
+        m = m_ref[h]
+        l_safe = jnp.maximum(l_ref[h], 1e-30)
+        # rows that never accumulated (a length-0 slot, a dead verify
+        # slot, padded chunk rows past every accumulated page): m never
+        # left _NEG_INF — emit exactly zero, the masked-row contract
+        # shared with the training kernels (ops.pallas_attention).
+        # Negated-compare form so a NaN running max (poisoned K/V page)
+        # fails the dead-row test and PROPAGATES instead of being
+        # silently zeroed — the serving engine's non-finite guard
+        # depends on corruption staying visible in the output.
+        # (the compare runs on the already-expanded f32 column:
+        # Mosaic cannot reshape an i1 vector)
+        row_ok = ~(m[:, None] <= _NEG_INF / 2)
+        o_ref[0, h] = jnp.where(row_ok, acc_ref[h] / l_safe[:, None],
+                                0.0).astype(o_ref.dtype)
+
+
+def _paged_call(kernel, name, grid, prefetch, q4, kv_pool, q_map, kv_map,
+                interpret):
+    """The one ``pallas_call`` shape of the three kernels: ``prefetch``
+    int32 page table / lengths (and f32 page scales) in SMEM, queries
+    ``q4`` (G, H, rows, D) blocked by ``q_map``, the pool blocked a
+    page at a time by ``kv_map`` (which dereferences the prefetched
+    page table, so the kernel never sees a gather), online-softmax
+    state in VMEM scratch. Returns (G, H, rows, D)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    D = q4.shape[-1]
+    q4 = _pad_lanes(q4)
+    _, H, rows, lanes = q4.shape
+    page_size = kv_pool.shape[2]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((1, H, rows, lanes), q_map),
+            pl.BlockSpec((1, H, page_size, lanes), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, H, rows, lanes), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((H, rows), jnp.float32),         # m
+            pltpu.VMEM((H, rows), jnp.float32),         # l
+            pltpu.VMEM((H, rows, lanes), jnp.float32),  # acc
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        name=name,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
+        interpret=interpret,
+    )(*prefetch, q4, kv_pool)
+    return out[..., D:]
+
+
+def _scale_prefetch(k_scale, v_scale):
+    """Per-page scales join the scalar-prefetch set (quantized pools)."""
+    if k_scale is None:
+        return ()
+    return (k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
+
+
+def _ragged_kernel(pt_ref, ln_ref, *refs, scale, page_size, n_pages,
+                   heads):
     from jax.experimental import pallas as pl
 
+    *scale_refs, q_ref, kv_ref, o_ref, m_ref, l_ref, acc_ref = refs
     s = pl.program_id(0)
     j = pl.program_id(1)
     length = ln_ref[s]                          # live tokens this slot
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     @pl.when(j * page_size < length)
     def _accumulate():
         valid = (j * page_size + lax.broadcasted_iota(
             jnp.int32, (page_size, 1), 0)) < length
-        if ks_ref is not None:                  # this page's scales
-            sk = ks_ref[pt_ref[s, j]]
-            sv = vs_ref[pt_ref[s, j]]
-        for h in range(heads):                  # unrolled head loop
-            q = q_ref[0, h]                     # (1, D), input dtype
-            k = k_ref[0, h]                     # (page_size, D)
-            if ks_ref is not None:              # inline dequant
-                q = q.astype(jnp.float32)
-                k = k.astype(jnp.float32) * sk
-            # SELECT masked rows out of V (not just zero-weight them):
-            # a freed page can be reused carrying non-finite garbage in
-            # positions past the new owner's length, and 0 * NaN = NaN
-            # would leak it through the weighted sum — masked reads
-            # must never matter, even poisoned ones (a quantized pool's
-            # NaN channel is the page SCALE — the select covers it the
-            # same way)
-            vv = v_ref[0, h] if vs_ref is None \
-                else v_ref[0, h].astype(jnp.float32) * sv
-            v = jnp.where(valid, vv, 0.0)
-            sc = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
-                         precision=lax.Precision.DEFAULT) * scale
-            pos = j * page_size + lax.broadcasted_iota(
-                jnp.int32, (1, page_size), 1)
-            sc = jnp.where(pos < length, sc, _NEG_INF)
-            m_prev = m_ref[h]                   # (1,)
-            l_prev = l_ref[h]
-            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
-            p = jnp.exp(sc - m_new[:, None])    # (1, page_size) f32
-            alpha = jnp.exp(m_prev - m_new)
-            m_ref[h] = m_new
-            l_ref[h] = l_prev * alpha + jnp.sum(p, axis=-1)
-            acc_ref[h] = acc_ref[h] * alpha[:, None] + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32,
-                precision=lax.Precision.DEFAULT)
+        pos = j * page_size + lax.broadcasted_iota(
+            jnp.int32, (1, page_size), 1)
+        _accumulate_page(
+            q_ref, kv_ref, m_ref, l_ref, acc_ref, heads=heads,
+            scale=scale, valid=valid, visible=pos < length,
+            page_scale=_page_scale(scale_refs, pt_ref[s, j],
+                                   kv_ref.shape[-1]))
 
     @pl.when(j == n_pages - 1)
-    def _finalize():
-        for h in range(heads):
-            m = m_ref[h]
-            l_safe = jnp.maximum(l_ref[h], 1e-30)
-            # fully-masked slot (length 0): m never left _NEG_INF — emit
-            # exactly zero, the masked-row contract shared with the
-            # training kernels (ops.pallas_attention). Negated-compare
-            # form so a NaN running max (poisoned K/V page) fails the
-            # dead-row test and PROPAGATES instead of being silently
-            # zeroed — the serving engine's non-finite guard depends on
-            # corruption staying visible in the output.
-            # (the compare runs on the already-expanded f32 column:
-            # Mosaic cannot reshape an i1 vector)
-            row_ok = ~(m[:, None] <= _NEG_INF / 2)
-            o_ref[0, h] = jnp.where(row_ok,
-                                    acc_ref[h] / l_safe[:, None],
-                                    0.0).astype(o_ref.dtype)
+    def _fin():
+        _finalize(o_ref, m_ref, l_ref, acc_ref, heads)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _ragged_pallas(q, k_pool, v_pool, page_table, lengths, scale,
-                   interpret):
-    """q: (S, H, D); pools: (P, H, page_size, D); page_table:
-    (S, max_pages) int32; lengths: (S,) int32. Returns (S, H, D)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    S, H, D = q.shape
-    page_size = k_pool.shape[2]
+def _ragged_pallas(q, kv_pool, page_table, lengths, scale, interpret,
+                   k_scale=None, v_scale=None):
+    """q: (S, H, D); kv_pool: (P, H, page_size, 2D); page_table:
+    (S, max_pages) int32; lengths: (S,) int32; k_scale/v_scale: (P,)
+    f32 per-page scales of a quantized pool, or None. Returns
+    (S, H, D)."""
+    S, H, _ = q.shape
     n_pages = page_table.shape[1]
-    q4 = q[:, :, None, :]                       # (S, H, 1, D)
-
+    quant = k_scale is not None
     kernel = functools.partial(
-        _ragged_kernel, scale=scale, page_size=page_size,
+        _ragged_kernel, scale=scale, page_size=kv_pool.shape[2],
         n_pages=n_pages, heads=H)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                  # page_table, lengths
-        grid=(S, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, H, 1, D), lambda s, j, pt, ln: (s, 0, 0, 0)),
-            pl.BlockSpec((1, H, page_size, D),
-                         lambda s, j, pt, ln: (pt[s, j], 0, 0, 0)),
-            pl.BlockSpec((1, H, page_size, D),
-                         lambda s, j, pt, ln: (pt[s, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H, 1, D),
-                               lambda s, j, pt, ln: (s, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, 1), jnp.float32),        # m
-            pltpu.VMEM((H, 1), jnp.float32),        # l
-            pltpu.VMEM((H, 1, D), jnp.float32),     # acc
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="mxtpu_ragged_decode",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q4, k_pool, v_pool)
+    out = _paged_call(
+        kernel, "mxtpu_ragged_decode" + ("_q" if quant else ""),
+        (S, n_pages),
+        (page_table.astype(jnp.int32), lengths.astype(jnp.int32),
+         *_scale_prefetch(k_scale, v_scale)),
+        q[:, :, None, :], kv_pool,
+        lambda s, j, *_: (s, 0, 0, 0),
+        lambda s, j, pt, *_: (pt[s, j], 0, 0, 0), interpret)
     return out[:, :, 0, :]
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _ragged_pallas_q(q, k_pool, v_pool, page_table, lengths, k_scale,
-                     v_scale, scale, interpret):
-    """Quantized-pool decode kernel: ``k_scale``/``v_scale`` (P,) f32
-    per-page scales join the page table and lengths in the
-    scalar-prefetch set; the kernel dequantizes each page inline at
-    the DMA boundary (see ``_ragged_kernel``)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    S, H, D = q.shape
-    page_size = k_pool.shape[2]
-    n_pages = page_table.shape[1]
-    q4 = q[:, :, None, :]                       # (S, H, 1, D)
-
-    def kernel(pt_ref, ln_ref, ks_ref, vs_ref, *rest):
-        _ragged_kernel(pt_ref, ln_ref, *rest, scale=scale,
-                       page_size=page_size, n_pages=n_pages, heads=H,
-                       ks_ref=ks_ref, vs_ref=vs_ref)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,      # page_table, lengths, k/v scales
-        grid=(S, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, H, 1, D),
-                         lambda s, j, pt, ln, ks, vs: (s, 0, 0, 0)),
-            pl.BlockSpec((1, H, page_size, D),
-                         lambda s, j, pt, ln, ks, vs:
-                         (pt[s, j], 0, 0, 0)),
-            pl.BlockSpec((1, H, page_size, D),
-                         lambda s, j, pt, ln, ks, vs:
-                         (pt[s, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H, 1, D),
-                               lambda s, j, pt, ln, ks, vs:
-                               (s, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, 1), jnp.float32),        # m
-            pltpu.VMEM((H, 1), jnp.float32),        # l
-            pltpu.VMEM((H, 1, D), jnp.float32),     # acc
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="mxtpu_ragged_decode_q",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      k_scale.astype(jnp.float32), v_scale.astype(jnp.float32),
-      q4, k_pool, v_pool)
-    return out[:, :, 0, :]
-
-
-def _gather_window(pool, page_table, scale=None):
-    """(S, H, K, D) dense window of a slot's pages — the expensive
-    gather over the pool's page axis, shared by the reference paths.
-    ``scale`` (P,) dequantizes a quantized pool inline with the gather
-    (per-page broadcast) — the f32 oracle's quantized arm."""
+def _gather_window(kv_pool, page_table, k_scale=None, v_scale=None):
+    """Dense (S, H, K, D) key and value windows of each slot's pages —
+    the expensive gather over the pool's page axis, shared by the
+    reference paths. ``k_scale``/``v_scale`` (P,) dequantize a
+    quantized pool inline with the gather (per-page broadcast) — the
+    f32 oracle's quantized arm."""
     S, n_pages = page_table.shape
-    _, H, page_size, D = pool.shape
-    g = pool[page_table]                        # (S, n_pages, H, ps, D)
-    if scale is not None:
-        g = g.astype(jnp.float32) * \
-            scale[page_table][:, :, None, None, None]
-    g = jnp.moveaxis(g, 2, 1)                   # (S, H, n_pages, ps, D)
-    return g.reshape(S, H, n_pages * page_size, D)
+    _, H, page_size, _ = kv_pool.shape
+
+    def window(g, pscale):              # (S, n_pages, H, ps, D)
+        if pscale is not None:
+            g = g.astype(jnp.float32) * \
+                pscale[page_table][:, :, None, None, None]
+        g = jnp.moveaxis(g, 2, 1)       # (S, H, n_pages, ps, D)
+        return g.reshape(S, H, n_pages * page_size, -1)
+
+    k, v = _split(kv_pool[page_table])
+    return window(k, k_scale), window(v, v_scale)
 
 
 def _reference_core(q, k, v, lengths, sc):
@@ -295,37 +320,37 @@ def _reference_core(q, k, v, lengths, sc):
     return jnp.where(row_ok[..., None], out, 0.0).astype(q.dtype)
 
 
-def ragged_attention_reference(q, k_pool, v_pool, page_table, lengths,
+def ragged_attention_reference(q, kv_pool, page_table, lengths,
                                scale=None, k_scale=None, v_scale=None):
     """Pure-jnp oracle and CPU serving path: gather each slot's pages to
-    a dense (S, H, K, D) window, mask positions >= length, softmax with
-    f32 accumulation. Jit-friendly (static shapes; the gather is an XLA
-    gather over the pool's page axis). ``k_scale``/``v_scale`` (P,)
-    dequantize quantized pools at the gather (per-page broadcast) —
-    past that point the math is BITWISE the unquantized reference, which
-    is what makes this the quantization accuracy oracle's denominator."""
+    dense (S, H, K, D) key and value windows (the pool's two lane
+    halves), mask positions >= length, softmax with f32 accumulation.
+    Jit-friendly (static shapes; the gather is an XLA gather over the
+    pool's page axis). ``k_scale``/``v_scale`` (P,) dequantize
+    quantized pools at the gather (per-page broadcast) — past that
+    point the math is BITWISE the unquantized reference, which is what
+    makes this the quantization accuracy oracle's denominator."""
     D = q.shape[-1]
     sc = D ** -0.5 if scale is None else scale
-    k = _gather_window(k_pool, page_table, k_scale)
-    v = _gather_window(v_pool, page_table, v_scale)
+    k, v = _gather_window(kv_pool, page_table, k_scale, v_scale)
     return _reference_core(q, k, v, lengths, sc)
 
 
-def ragged_paged_attention(q, k_pool, v_pool, page_table, lengths,
-                           scale=None, interpret=None, k_scale=None,
-                           v_scale=None):
+def ragged_paged_attention(q, kv_pool, page_table, lengths, scale=None,
+                           interpret=None, k_scale=None, v_scale=None):
     """Decode attention for one new token per slot against the paged KV
-    pool. q: (S, H, D); k_pool/v_pool: (num_pages, H, page_size, D);
-    page_table: (S, max_pages) int32 (dead entries 0 = null page);
-    lengths: (S,) int32 — number of live KV tokens INCLUDING the one
-    just written for this step. Returns (S, H, D).
+    pool. q: (S, H, D); kv_pool: (num_pages, H, page_size, 2 * D), keys
+    in lanes [0, D) and values in lanes [D, 2 * D); page_table:
+    (S, max_pages) int32 (dead entries 0 = null page); lengths: (S,)
+    int32 — number of live KV tokens INCLUDING the one just written for
+    this step. Returns (S, H, D).
 
-    ``k_scale``/``v_scale`` (P,) f32 mark the pools QUANTIZED (int8 /
-    fp8 codes with per-page symmetric scales — serve/paged_kv.py): the
-    Pallas path prefetches them next to the page table and dequantizes
-    inline at the DMA boundary; the jnp path dequantizes at the gather.
-    None (the default) is the unquantized path, bit-identical to
-    before.
+    ``k_scale``/``v_scale`` (P,) f32 mark the pool QUANTIZED (int8 /
+    fp8 codes with per-page symmetric scales, one for the key half and
+    one for the value half — serve/paged_kv.py): the Pallas path
+    prefetches them next to the page table and dequantizes inline at
+    the DMA boundary; the jnp path dequantizes at the gather. None (the
+    default) is the unquantized path.
 
     Dispatch is static (``ops.pallas_attention.pallas_path``): the
     Mosaic kernel on TPU (or an error — never the reference); off TPU
@@ -336,35 +361,28 @@ def ragged_paged_attention(q, k_pool, v_pool, page_table, lengths,
         interpret = _pa._env_interpret()
     sc = q.shape[-1] ** -0.5 if scale is None else scale
     if _pa.pallas_path(interpret):
-        if k_scale is not None:
-            return _ragged_pallas_q(q, k_pool, v_pool, page_table,
-                                    lengths, k_scale, v_scale, sc,
-                                    interpret)
-        return _ragged_pallas(q, k_pool, v_pool, page_table, lengths,
-                              sc, interpret)
-    return ragged_attention_reference(q, k_pool, v_pool, page_table,
-                                      lengths, sc, k_scale, v_scale)
+        return _ragged_pallas(q, kv_pool, page_table, lengths, sc,
+                              interpret, k_scale, v_scale)
+    return ragged_attention_reference(q, kv_pool, page_table, lengths,
+                                      sc, k_scale, v_scale)
 
 
 # --------------------------------------------------------------------- #
 # prefill over a paged prefix (the chunked-prefill attention variant)
 # --------------------------------------------------------------------- #
 
-def _ragged_prefill_kernel(pr_ref, qi_ref, q_ref, k_ref, v_ref, o_ref,
-                           m_ref, l_ref, acc_ref, *, scale, page_size,
-                           n_pages, heads, chunk, ks_ref=None,
-                           vs_ref=None):
+def _ragged_prefill_kernel(pr_ref, qi_ref, *refs, scale, page_size,
+                           n_pages, heads, chunk):
     from jax.experimental import pallas as pl
 
+    *scale_refs, q_ref, kv_ref, o_ref, m_ref, l_ref, acc_ref = refs
     j = pl.program_id(0)
     start = qi_ref[0]                # first query's absolute position
     n_real = qi_ref[1]               # live queries in the chunk
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     # pages whose first key position is past the last real query's
     # position contribute nothing to any live row — skip them, and
@@ -372,163 +390,58 @@ def _ragged_prefill_kernel(pr_ref, qi_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j * page_size < start + n_real)
     def _accumulate():
         # positions past the last real query's view are masked for
-        # EVERY row — select them out of V so reused-page garbage
-        # (possibly non-finite) cannot leak through 0-weight terms
+        # EVERY row — select them out of the tile so reused-page
+        # garbage (possibly non-finite) cannot leak through 0-weight
+        # terms
         valid = (j * page_size + lax.broadcasted_iota(
             jnp.int32, (page_size, 1), 0)) < start + n_real
-        if ks_ref is not None:                  # this page's scales
-            sk = ks_ref[pr_ref[j]]
-            sv = vs_ref[pr_ref[j]]
-        for h in range(heads):                  # unrolled head loop
-            q = q_ref[0, h]                     # (chunk, D), input dtype
-            k = k_ref[0, h]                     # (page_size, D)
-            if ks_ref is not None:              # inline dequant
-                q = q.astype(jnp.float32)
-                k = k.astype(jnp.float32) * sk
-            vv = v_ref[0, h] if vs_ref is None \
-                else v_ref[0, h].astype(jnp.float32) * sv
-            v = jnp.where(valid, vv, 0.0)
-            sc = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
-                         precision=lax.Precision.DEFAULT) * scale
-            pos_k = j * page_size + lax.broadcasted_iota(
-                jnp.int32, (chunk, page_size), 1)
-            pos_q = start + lax.broadcasted_iota(
-                jnp.int32, (chunk, page_size), 0)
-            # per-query prefix mask: query i (absolute pos start + i)
-            # sees keys [0, start + i] — the paged prefix AND the causal
-            # intra-chunk part in one predicate (the chunk's own K/V is
-            # already scattered into these pages)
-            sc = jnp.where(pos_k <= pos_q, sc, _NEG_INF)
-            m_prev = m_ref[h]                   # (chunk,)
-            l_prev = l_ref[h]
-            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
-            p = jnp.exp(sc - m_new[:, None])    # (chunk, page_size) f32
-            alpha = jnp.exp(m_prev - m_new)
-            m_ref[h] = m_new
-            l_ref[h] = l_prev * alpha + jnp.sum(p, axis=-1)
-            acc_ref[h] = acc_ref[h] * alpha[:, None] + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32,
-                precision=lax.Precision.DEFAULT)
+        pos_k = j * page_size + lax.broadcasted_iota(
+            jnp.int32, (chunk, page_size), 1)
+        pos_q = start + lax.broadcasted_iota(
+            jnp.int32, (chunk, page_size), 0)
+        # per-query prefix mask: query i (absolute pos start + i) sees
+        # keys [0, start + i] — the paged prefix AND the causal
+        # intra-chunk part in one predicate (the chunk's own K/V is
+        # already scattered into these pages)
+        _accumulate_page(
+            q_ref, kv_ref, m_ref, l_ref, acc_ref, heads=heads,
+            scale=scale, valid=valid, visible=pos_k <= pos_q,
+            page_scale=_page_scale(scale_refs, pr_ref[j],
+                                   kv_ref.shape[-1]))
 
+    # every live query attends at least position 0, so only rows that
+    # saw no page at all (possible when padded rows extend past every
+    # accumulated page) stay at _NEG_INF and emit zero
     @pl.when(j == n_pages - 1)
-    def _finalize():
-        for h in range(heads):
-            m = m_ref[h]
-            l_safe = jnp.maximum(l_ref[h], 1e-30)
-            # every live query attends at least position 0, so only rows
-            # that saw no page at all (possible when padded rows extend
-            # past every accumulated page) stay at _NEG_INF — emit zero.
-            # Negated compare: NaN (poisoned page) propagates, see the
-            # decode kernel's finalize
-            # (the compare runs on the already-expanded f32 column:
-            # Mosaic cannot reshape an i1 vector)
-            row_ok = ~(m[:, None] <= _NEG_INF / 2)
-            o_ref[0, h] = jnp.where(row_ok,
-                                    acc_ref[h] / l_safe[:, None],
-                                    0.0).astype(o_ref.dtype)
+    def _fin():
+        _finalize(o_ref, m_ref, l_ref, acc_ref, heads)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _ragged_prefill_pallas(q, k_pool, v_pool, page_row, qinfo, scale,
-                           interpret):
-    """q: (C, H, D) chunk queries of ONE slot; pools: (P, H, ps, D);
-    page_row: (max_pages,) int32; qinfo: (2,) int32 = [q_start, n_real].
-    Returns (C, H, D)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    C, H, D = q.shape
-    page_size = k_pool.shape[2]
+def _ragged_prefill_pallas(q, kv_pool, page_row, qinfo, scale, interpret,
+                           k_scale=None, v_scale=None):
+    """q: (C, H, D) chunk queries of ONE slot; kv_pool: (P, H, ps, 2D);
+    page_row: (max_pages,) int32; qinfo: (2,) int32 = [q_start,
+    n_real]; k_scale/v_scale: (P,) f32 or None. Returns (C, H, D)."""
+    C, H, _ = q.shape
     n_pages = page_row.shape[0]
-    q4 = q.transpose(1, 0, 2)[None]             # (1, H, C, D)
-
+    quant = k_scale is not None
     kernel = functools.partial(
-        _ragged_prefill_kernel, scale=scale, page_size=page_size,
+        _ragged_prefill_kernel, scale=scale, page_size=kv_pool.shape[2],
         n_pages=n_pages, heads=H, chunk=C)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                  # page_row, qinfo
-        grid=(n_pages,),
-        in_specs=[
-            pl.BlockSpec((1, H, C, D), lambda j, pr, qi: (0, 0, 0, 0)),
-            pl.BlockSpec((1, H, page_size, D),
-                         lambda j, pr, qi: (pr[j], 0, 0, 0)),
-            pl.BlockSpec((1, H, page_size, D),
-                         lambda j, pr, qi: (pr[j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H, C, D),
-                               lambda j, pr, qi: (0, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, C), jnp.float32),        # m
-            pltpu.VMEM((H, C), jnp.float32),        # l
-            pltpu.VMEM((H, C, D), jnp.float32),     # acc
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="mxtpu_ragged_prefill",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, H, C, D), q.dtype),
-        interpret=interpret,
-    )(page_row.astype(jnp.int32), qinfo.astype(jnp.int32),
-      q4, k_pool, v_pool)
+    out = _paged_call(
+        kernel, "mxtpu_ragged_prefill" + ("_q" if quant else ""),
+        (n_pages,),
+        (page_row.astype(jnp.int32), qinfo.astype(jnp.int32),
+         *_scale_prefetch(k_scale, v_scale)),
+        q.transpose(1, 0, 2)[None], kv_pool,            # (1, H, C, D)
+        lambda j, *_: (0, 0, 0, 0),
+        lambda j, pr, *_: (pr[j], 0, 0, 0), interpret)
     return out[0].transpose(1, 0, 2)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _ragged_prefill_pallas_q(q, k_pool, v_pool, page_row, qinfo,
-                             k_scale, v_scale, scale, interpret):
-    """Quantized-pool chunked-prefill kernel: per-page scales in the
-    scalar-prefetch set, dequant at the DMA boundary (see
-    ``_ragged_prefill_kernel``)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    C, H, D = q.shape
-    page_size = k_pool.shape[2]
-    n_pages = page_row.shape[0]
-    q4 = q.transpose(1, 0, 2)[None]             # (1, H, C, D)
-
-    def kernel(pr_ref, qi_ref, ks_ref, vs_ref, *rest):
-        _ragged_prefill_kernel(pr_ref, qi_ref, *rest, scale=scale,
-                               page_size=page_size, n_pages=n_pages,
-                               heads=H, chunk=C, ks_ref=ks_ref,
-                               vs_ref=vs_ref)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,      # page_row, qinfo, k/v scales
-        grid=(n_pages,),
-        in_specs=[
-            pl.BlockSpec((1, H, C, D),
-                         lambda j, pr, qi, ks, vs: (0, 0, 0, 0)),
-            pl.BlockSpec((1, H, page_size, D),
-                         lambda j, pr, qi, ks, vs: (pr[j], 0, 0, 0)),
-            pl.BlockSpec((1, H, page_size, D),
-                         lambda j, pr, qi, ks, vs: (pr[j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H, C, D),
-                               lambda j, pr, qi, ks, vs: (0, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, C), jnp.float32),        # m
-            pltpu.VMEM((H, C), jnp.float32),        # l
-            pltpu.VMEM((H, C, D), jnp.float32),     # acc
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="mxtpu_ragged_prefill_q",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, H, C, D), q.dtype),
-        interpret=interpret,
-    )(page_row.astype(jnp.int32), qinfo.astype(jnp.int32),
-      k_scale.astype(jnp.float32), v_scale.astype(jnp.float32),
-      q4, k_pool, v_pool)
-    return out[0].transpose(1, 0, 2)
-
-
-def ragged_prefill_reference(q, k_pool, v_pool, page_row, q_start,
-                             scale=None, n_real=None, k_scale=None,
-                             v_scale=None):
+def ragged_prefill_reference(q, kv_pool, page_row, q_start, scale=None,
+                             n_real=None, k_scale=None, v_scale=None):
     """Pure-jnp oracle and CPU serving path for chunked prefill: gather
     the slot's whole page window dense, apply the per-query prefix mask
     ``pos_k <= q_start + i``, softmax with f32 accumulation. Same
@@ -536,23 +449,23 @@ def ragged_prefill_reference(q, k_pool, v_pool, page_row, q_start,
     (``q_start`` is traced data). ``n_real`` is the count of live
     (non-padded) chunk rows, default C."""
     C, H, D = q.shape
-    page_size = k_pool.shape[2]
+    page_size = kv_pool.shape[2]
     n_pages = page_row.shape[0]
     K = n_pages * page_size
     sc = D ** -0.5 if scale is None else scale
     if n_real is None:
         n_real = C
 
-    def window(pool, pscale):
-        g = pool[page_row]                      # (n_pages, H, ps, D)
+    def window(g, pscale):                      # (n_pages, H, ps, D)
         if pscale is not None:                  # per-page dequant
             g = g.astype(jnp.float32) * \
                 pscale[page_row][:, None, None, None]
         g = jnp.moveaxis(g, 1, 0)               # (H, n_pages, ps, D)
         return g.reshape(H, K, D)
 
-    k = window(k_pool, k_scale)
-    v = window(v_pool, v_scale)
+    k, v = _split(kv_pool[page_row])
+    k = window(k, k_scale)
+    v = window(v, v_scale)
     s = jnp.einsum("chd,hkd->chk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * sc
     pos_k = lax.broadcasted_iota(jnp.int32, (C, K), 1)
@@ -592,10 +505,8 @@ def ragged_prefill_reference(q, k_pool, v_pool, page_row, q_start,
 # draft-then-verify attention variant)
 # --------------------------------------------------------------------- #
 
-def _ragged_verify_kernel(pt_ref, ln_ref, dl_ref, q_ref, k_ref, v_ref,
-                          o_ref, m_ref, l_ref, acc_ref, *, scale,
-                          page_size, n_pages, heads, window,
-                          ks_ref=None, vs_ref=None):
+def _ragged_verify_kernel(pt_ref, ln_ref, dl_ref, *refs, scale,
+                          page_size, n_pages, heads, window):
     """Decode kernel generalized to ``window`` queries per slot: query
     row r of slot s sits at absolute position ``lengths[s] - 1 + r``
     (row 0 IS the ordinary decode query) and attends keys
@@ -604,9 +515,10 @@ def _ragged_verify_kernel(pt_ref, ln_ref, dl_ref, q_ref, k_ref, v_ref,
     chunked-prefill masking with a per-SLOT dynamic start. Same
     online-softmax scratch carried across the page axis, same
     dead-page skip via the repeated-null-page index, same NaN
-    propagation / masked-V-select contract as the decode kernel."""
+    propagation / masked-tile-select contract as the decode kernel."""
     from jax.experimental import pallas as pl
 
+    *scale_refs, q_ref, kv_ref, o_ref, m_ref, l_ref, acc_ref = refs
     s = pl.program_id(0)
     j = pl.program_id(1)
     length = ln_ref[s]               # keys visible to query row 0
@@ -614,9 +526,7 @@ def _ragged_verify_kernel(pt_ref, ln_ref, dl_ref, q_ref, k_ref, v_ref,
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     # the last CONSUMED row (row dl — accepted drafts + the
     # bonus/correction) sees keys up to length + dl - 1, and that is
@@ -626,11 +536,11 @@ def _ragged_verify_kernel(pt_ref, ln_ref, dl_ref, q_ref, k_ref, v_ref,
     # re-DMA
     @pl.when((length > 0) & (j * page_size < length + dl))
     def _accumulate():
-        # positions no CONSUMED row may ever see are selected out of V
-        # so reused-page garbage (possibly non-finite) cannot leak
-        # through 0-weight terms. The bound must be the slot's real
-        # written extent length + dl, NOT length + window - 1: when a
-        # slot drafts fewer than window - 1 tokens, positions in
+        # positions no CONSUMED row may ever see are selected out of
+        # the tile so reused-page garbage (possibly non-finite) cannot
+        # leak through 0-weight terms. The bound must be the slot's
+        # real written extent length + dl, NOT length + window - 1:
+        # when a slot drafts fewer than window - 1 tokens, positions in
         # [length + dl, length + window - 1) are UNWRITTEN — a recycled
         # page can carry a quarantined slot's non-finite K/V there, and
         # 0 * NaN = NaN would poison every consumed row, falsely
@@ -641,165 +551,52 @@ def _ragged_verify_kernel(pt_ref, ln_ref, dl_ref, q_ref, k_ref, v_ref,
         # documented PRECONDITION).
         valid = (j * page_size + lax.broadcasted_iota(
             jnp.int32, (page_size, 1), 0)) < length + dl
-        if ks_ref is not None:                  # this page's scales
-            sk = ks_ref[pt_ref[s, j]]
-            sv = vs_ref[pt_ref[s, j]]
-        for h in range(heads):                  # unrolled head loop
-            q = q_ref[0, h]                     # (window, D), input dtype
-            k = k_ref[0, h]                     # (page_size, D)
-            if ks_ref is not None:              # inline dequant
-                q = q.astype(jnp.float32)
-                k = k.astype(jnp.float32) * sk
-            vv = v_ref[0, h] if vs_ref is None \
-                else v_ref[0, h].astype(jnp.float32) * sv
-            v = jnp.where(valid, vv, 0.0)
-            sc = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
-                         precision=lax.Precision.DEFAULT) * scale
-            pos_k = j * page_size + lax.broadcasted_iota(
-                jnp.int32, (window, page_size), 1)
-            row = lax.broadcasted_iota(
-                jnp.int32, (window, page_size), 0)
-            # row r (absolute position length - 1 + r) sees keys
-            # [0, length - 1 + r]: prefix + causal intra-window in one
-            # predicate
-            sc = jnp.where(pos_k < length + row, sc, _NEG_INF)
-            m_prev = m_ref[h]                   # (window,)
-            l_prev = l_ref[h]
-            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
-            p = jnp.exp(sc - m_new[:, None])    # (window, page_size) f32
-            alpha = jnp.exp(m_prev - m_new)
-            m_ref[h] = m_new
-            l_ref[h] = l_prev * alpha + jnp.sum(p, axis=-1)
-            acc_ref[h] = acc_ref[h] * alpha[:, None] + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32,
-                precision=lax.Precision.DEFAULT)
+        pos_k = j * page_size + lax.broadcasted_iota(
+            jnp.int32, (window, page_size), 1)
+        row = lax.broadcasted_iota(jnp.int32, (window, page_size), 0)
+        # row r (absolute position length - 1 + r) sees keys
+        # [0, length - 1 + r]: prefix + causal intra-window in one
+        # predicate
+        _accumulate_page(
+            q_ref, kv_ref, m_ref, l_ref, acc_ref, heads=heads,
+            scale=scale, valid=valid, visible=pos_k < length + row,
+            page_scale=_page_scale(scale_refs, pt_ref[s, j],
+                                   kv_ref.shape[-1]))
 
     @pl.when(j == n_pages - 1)
-    def _finalize():
-        for h in range(heads):
-            m = m_ref[h]
-            l_safe = jnp.maximum(l_ref[h], 1e-30)
-            # dead slots (length 0) never accumulate: every row stays
-            # at _NEG_INF — emit exactly zero. Negated compare so a NaN
-            # running max (poisoned page) PROPAGATES, see the decode
-            # kernel's finalize
-            # (the compare runs on the already-expanded f32 column:
-            # Mosaic cannot reshape an i1 vector)
-            row_ok = ~(m[:, None] <= _NEG_INF / 2)
-            o_ref[0, h] = jnp.where(row_ok,
-                                    acc_ref[h] / l_safe[:, None],
-                                    0.0).astype(o_ref.dtype)
+    def _fin():
+        _finalize(o_ref, m_ref, l_ref, acc_ref, heads)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _ragged_verify_pallas(q, k_pool, v_pool, page_table, lengths,
-                          draft_len, scale, interpret):
-    """q: (S, W, H, D) — W verify queries per slot; pools:
-    (P, H, page_size, D); page_table: (S, max_pages) int32; lengths:
+def _ragged_verify_pallas(q, kv_pool, page_table, lengths, draft_len,
+                          scale, interpret, k_scale=None, v_scale=None):
+    """q: (S, W, H, D) — W verify queries per slot; kv_pool:
+    (P, H, page_size, 2D); page_table: (S, max_pages) int32; lengths:
     (S,) int32 = keys visible to query row 0 (0 = dead slot);
     draft_len: (S,) int32 = the slot's real draft count (index of its
-    last consumed row, bounding the freshly-written extent).
-    Returns (S, W, H, D)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    S, W, H, D = q.shape
-    page_size = k_pool.shape[2]
+    last consumed row, bounding the freshly-written extent);
+    k_scale/v_scale: (P,) f32 or None. Returns (S, W, H, D)."""
+    S, W, H, _ = q.shape
     n_pages = page_table.shape[1]
-    q4 = q.transpose(0, 2, 1, 3)                # (S, H, W, D)
-
+    quant = k_scale is not None
     kernel = functools.partial(
-        _ragged_verify_kernel, scale=scale, page_size=page_size,
+        _ragged_verify_kernel, scale=scale, page_size=kv_pool.shape[2],
         n_pages=n_pages, heads=H, window=W)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,          # page_table, lengths, draft_len
-        grid=(S, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, H, W, D),
-                         lambda s, j, pt, ln, dl: (s, 0, 0, 0)),
-            pl.BlockSpec((1, H, page_size, D),
-                         lambda s, j, pt, ln, dl: (pt[s, j], 0, 0, 0)),
-            pl.BlockSpec((1, H, page_size, D),
-                         lambda s, j, pt, ln, dl: (pt[s, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H, W, D),
-                               lambda s, j, pt, ln, dl: (s, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, W), jnp.float32),        # m
-            pltpu.VMEM((H, W), jnp.float32),        # l
-            pltpu.VMEM((H, W, D), jnp.float32),     # acc
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="mxtpu_ragged_verify",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, W, D), q.dtype),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      draft_len.astype(jnp.int32), q4, k_pool, v_pool)
+    out = _paged_call(
+        kernel, "mxtpu_ragged_verify" + ("_q" if quant else ""),
+        (S, n_pages),
+        (page_table.astype(jnp.int32), lengths.astype(jnp.int32),
+         draft_len.astype(jnp.int32),
+         *_scale_prefetch(k_scale, v_scale)),
+        q.transpose(0, 2, 1, 3), kv_pool,               # (S, H, W, D)
+        lambda s, j, *_: (s, 0, 0, 0),
+        lambda s, j, pt, *_: (pt[s, j], 0, 0, 0), interpret)
     return out.transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _ragged_verify_pallas_q(q, k_pool, v_pool, page_table, lengths,
-                            draft_len, k_scale, v_scale, scale,
-                            interpret):
-    """Quantized-pool verify kernel: per-page scales in the
-    scalar-prefetch set, dequant at the DMA boundary (see
-    ``_ragged_verify_kernel``)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    S, W, H, D = q.shape
-    page_size = k_pool.shape[2]
-    n_pages = page_table.shape[1]
-    q4 = q.transpose(0, 2, 1, 3)                # (S, H, W, D)
-
-    def kernel(pt_ref, ln_ref, dl_ref, ks_ref, vs_ref, *rest):
-        _ragged_verify_kernel(pt_ref, ln_ref, dl_ref, *rest,
-                              scale=scale, page_size=page_size,
-                              n_pages=n_pages, heads=H, window=W,
-                              ks_ref=ks_ref, vs_ref=vs_ref)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,  # page_table, lengths, draft_len, scales
-        grid=(S, n_pages),
-        in_specs=[
-            pl.BlockSpec((1, H, W, D),
-                         lambda s, j, pt, ln, dl, ks, vs:
-                         (s, 0, 0, 0)),
-            pl.BlockSpec((1, H, page_size, D),
-                         lambda s, j, pt, ln, dl, ks, vs:
-                         (pt[s, j], 0, 0, 0)),
-            pl.BlockSpec((1, H, page_size, D),
-                         lambda s, j, pt, ln, dl, ks, vs:
-                         (pt[s, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, H, W, D),
-                               lambda s, j, pt, ln, dl, ks, vs:
-                               (s, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, W), jnp.float32),        # m
-            pltpu.VMEM((H, W), jnp.float32),        # l
-            pltpu.VMEM((H, W, D), jnp.float32),     # acc
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="mxtpu_ragged_verify_q",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, W, D), q.dtype),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      draft_len.astype(jnp.int32), k_scale.astype(jnp.float32),
-      v_scale.astype(jnp.float32), q4, k_pool, v_pool)
-    return out.transpose(0, 2, 1, 3)
-
-
-def ragged_verify_reference(q, k_pool, v_pool, page_table, lengths,
-                            scale=None, k_scale=None, v_scale=None):
+def ragged_verify_reference(q, kv_pool, page_table, lengths, scale=None,
+                            k_scale=None, v_scale=None):
     """Pure-jnp verify path: one ``ragged_attention_reference`` call
     per query offset — query row r of slot s attends
     ``lengths[s] + r`` keys (0 for dead slots). DELIBERATELY a loop of
@@ -818,8 +615,7 @@ def ragged_verify_reference(q, k_pool, v_pool, page_table, lengths,
     D = q.shape[-1]
     sc = D ** -0.5 if scale is None else scale
     lengths = lengths.astype(jnp.int32)
-    k = _gather_window(k_pool, page_table, k_scale)
-    v = _gather_window(v_pool, page_table, v_scale)
+    k, v = _gather_window(kv_pool, page_table, k_scale, v_scale)
     outs = []
     for r in range(W):
         lr = jnp.where(lengths > 0, lengths + r, 0)
@@ -827,7 +623,7 @@ def ragged_verify_reference(q, k_pool, v_pool, page_table, lengths,
     return jnp.stack(outs, axis=1)
 
 
-def ragged_verify_attention(q, k_pool, v_pool, page_table, lengths,
+def ragged_verify_attention(q, kv_pool, page_table, lengths,
                             draft_len=None, scale=None, interpret=None,
                             k_scale=None, v_scale=None):
     """Multi-query decode (speculative verify) attention: W queries per
@@ -835,9 +631,9 @@ def ragged_verify_attention(q, k_pool, v_pool, page_table, lengths,
     ``lengths[s] - 1``, row r sits at position ``lengths[s] - 1 + r``
     and attends the slot's paged prefix plus the causal intra-window
     part (keys ``[0, lengths[s] - 1 + r]``). q: (S, W, H, D);
-    k_pool/v_pool: (num_pages, H, page_size, D); page_table:
-    (S, max_pages) int32 (dead entries 0 = null page); lengths: (S,)
-    int32 = keys visible to row 0, i.e. the slot's pre-step KV length
+    kv_pool: (num_pages, H, page_size, 2 * D), keys | values;
+    page_table: (S, max_pages) int32 (dead entries 0 = null page);
+    lengths: (S,) int32 = keys visible to row 0, i.e. the slot's pre-step KV length
     PLUS ONE for the token written this step (0 = dead slot → exactly
     zero output, the masked-row contract). Returns (S, W, H, D).
 
@@ -850,7 +646,7 @@ def ragged_verify_attention(q, k_pool, v_pool, page_table, lengths,
 
     ``draft_len`` (S,) int32 gives each slot's real draft count — the
     index of its last consumed row. The Pallas kernel uses it to bound
-    the V-select at the slot's freshly-written extent
+    the tile select at the slot's freshly-written extent
     ``lengths[s] + draft_len[s]`` so stale non-finite garbage past it
     (a recycled page from a quarantined slot) cannot leak into
     consumed rows through 0-weight terms; the jnp reference is per-row
@@ -868,26 +664,21 @@ def ragged_verify_attention(q, k_pool, v_pool, page_table, lengths,
     if draft_len is None:
         draft_len = jnp.full((q.shape[0],), q.shape[1] - 1, jnp.int32)
     if _pa.pallas_path(interpret):
-        if k_scale is not None:
-            return _ragged_verify_pallas_q(
-                q, k_pool, v_pool, page_table, lengths,
-                jnp.asarray(draft_len), k_scale, v_scale, sc,
-                interpret)
-        return _ragged_verify_pallas(q, k_pool, v_pool, page_table,
-                                     lengths, jnp.asarray(draft_len),
-                                     sc, interpret)
-    return ragged_verify_reference(q, k_pool, v_pool, page_table,
-                                   lengths, sc, k_scale, v_scale)
+        return _ragged_verify_pallas(q, kv_pool, page_table, lengths,
+                                     jnp.asarray(draft_len), sc,
+                                     interpret, k_scale, v_scale)
+    return ragged_verify_reference(q, kv_pool, page_table, lengths, sc,
+                                   k_scale, v_scale)
 
 
-def ragged_prefill_attention(q, k_pool, v_pool, page_row, q_start,
-                             n_real=None, scale=None, interpret=None,
-                             k_scale=None, v_scale=None):
+def ragged_prefill_attention(q, kv_pool, page_row, q_start, n_real=None,
+                             scale=None, interpret=None, k_scale=None,
+                             v_scale=None):
     """Chunked-prefill attention for ONE slot: C chunk queries at
     absolute positions ``q_start + i`` attend the slot's paged prefix
-    plus the causal intra-chunk part. q: (C, H, D); k_pool/v_pool:
-    (num_pages, H, page_size, D); page_row: (max_pages,) int32 (dead
-    entries 0 = null page); q_start: scalar int32; n_real: live queries
+    plus the causal intra-chunk part. q: (C, H, D); kv_pool:
+    (num_pages, H, page_size, 2 * D), keys | values; page_row:
+    (max_pages,) int32 (dead entries 0 = null page); q_start: scalar int32; n_real: live queries
     (trailing padded rows emit garbage the caller discards — defaults
     to C). Returns (C, H, D).
 
@@ -906,12 +697,8 @@ def ragged_prefill_attention(q, k_pool, v_pool, page_row, q_start,
     if _pa.pallas_path(interpret):
         qinfo = jnp.stack([jnp.asarray(q_start, jnp.int32),
                            jnp.asarray(n_real, jnp.int32)])
-        if k_scale is not None:
-            return _ragged_prefill_pallas_q(q, k_pool, v_pool,
-                                            page_row, qinfo, k_scale,
-                                            v_scale, sc, interpret)
-        return _ragged_prefill_pallas(q, k_pool, v_pool, page_row,
-                                      qinfo, sc, interpret)
-    return ragged_prefill_reference(q, k_pool, v_pool, page_row,
-                                    q_start, sc, n_real=n_real,
-                                    k_scale=k_scale, v_scale=v_scale)
+        return _ragged_prefill_pallas(q, kv_pool, page_row, qinfo, sc,
+                                      interpret, k_scale, v_scale)
+    return ragged_prefill_reference(q, kv_pool, page_row, q_start, sc,
+                                    n_real=n_real, k_scale=k_scale,
+                                    v_scale=v_scale)

@@ -175,16 +175,12 @@ class CorruptPageWrite(ChaosInjector):
         self.fired = True
         s, page = cands[self.rng.randint(len(cands))]
         val = np.nan if self.mode == "nan" else 0.0
-        newk, newv = [], []
-        for kp, vp in zip(engine._kpools, engine._vpools):
-            k = np.asarray(kp).copy()
-            v = np.asarray(vp).copy()
-            k[page] = val
-            v[page] = val
-            newk.append(jnp.asarray(k))
-            newv.append(jnp.asarray(v))
-        engine._kpools = tuple(newk)
-        engine._vpools = tuple(newv)
+        pools = []
+        for pool in engine._kvpools:
+            kv = np.asarray(pool).copy()
+            kv[page] = val
+            pools.append(jnp.asarray(kv))
+        engine._kvpools = tuple(pools)
         self.page = page
         self._mark(engine._slots[s].request)
         self.log.append(f"step {step_idx}: {self.mode}-corrupted page "

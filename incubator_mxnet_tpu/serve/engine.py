@@ -455,11 +455,11 @@ class InferenceEngine:
         # kv_quant=None is byte-for-byte the unquantized engine.
         self._kv_spec = kv_quant_spec(kv_quant)
         self.kv_quant = self._kv_spec.name if self._kv_spec else None
-        pools = init_kv_pools(model.num_layers, self.num_pages, H,
-                              self.page_size, D, self._dtype,
-                              quant=self._kv_spec)
-        self._kpools = tuple(k for k, _ in pools)
-        self._vpools = tuple(v for _, v in pools)
+        # one (num_pages, H, page_size, 2 * D) pool a layer: a head's
+        # keys | values side by side on the lanes (serve/paged_kv.py)
+        self._kvpools = tuple(init_kv_pools(
+            model.num_layers, self.num_pages, H, self.page_size, D,
+            self._dtype, quant=self._kv_spec))
         if self._kv_spec is not None:
             self._kamax = tuple(np.zeros((self.num_pages,), np.float32)
                                 for _ in range(model.num_layers))
@@ -487,10 +487,8 @@ class InferenceEngine:
             from ..parallel.mesh import named_sharding
             self._mesh = mesh
             sh = named_sharding(mesh, None, "tp", None, None)
-            self._kpools = tuple(jax.device_put(k, sh)
-                                 for k in self._kpools)
-            self._vpools = tuple(jax.device_put(v, sh)
-                                 for v in self._vpools)
+            self._kvpools = tuple(jax.device_put(p, sh)
+                                  for p in self._kvpools)
         self._interpret = interpret
 
         self.spec_k = int(spec_k)
@@ -650,7 +648,7 @@ class InferenceEngine:
         self.preempt_handoff = None
 
         self._decode_step = jax.jit(self._decode_step_fn,
-                                    donate_argnums=(1, 2))
+                                    donate_argnums=(1,))
         self._prefill_jits = {}          # bucket_pages -> jitted dense fn
         self._chunk_jits = {}            # bucket_pages -> jitted chunk fn
         # program name -> (jitted fn, abstract args of its first
@@ -739,17 +737,17 @@ class InferenceEngine:
 
         return scope()
 
-    def _ragged_attn(self, q, kp, vp, page_table, lengths, ks=None,
+    def _ragged_attn(self, q, pool, page_table, lengths, ks=None,
                      vs=None):
         if self._mesh is not None:
-            return ragged_attention_reference(q, kp, vp, page_table,
+            return ragged_attention_reference(q, pool, page_table,
                                               lengths, k_scale=ks,
                                               v_scale=vs)
-        return ragged_paged_attention(q, kp, vp, page_table, lengths,
+        return ragged_paged_attention(q, pool, page_table, lengths,
                                       interpret=self._interpret,
                                       k_scale=ks, v_scale=vs)
 
-    def _verify_attn(self, q, kp, vp, page_table, lengths, draft_len,
+    def _verify_attn(self, q, pool, page_table, lengths, draft_len,
                      ks=None, vs=None):
         """Multi-query (speculative verify) decode attention: q is
         (S, W, H, D), ``lengths`` counts keys visible to query row 0
@@ -763,25 +761,25 @@ class InferenceEngine:
         jnp reference partitions cleanly, same as the single-query
         path."""
         if q.shape[1] == 1:
-            out = self._ragged_attn(q[:, 0], kp, vp, page_table,
+            out = self._ragged_attn(q[:, 0], pool, page_table,
                                     lengths, ks, vs)
             return out[:, None]
         if self._mesh is not None:
-            return ragged_verify_reference(q, kp, vp, page_table,
+            return ragged_verify_reference(q, pool, page_table,
                                            lengths, k_scale=ks,
                                            v_scale=vs)
-        return ragged_verify_attention(q, kp, vp, page_table, lengths,
+        return ragged_verify_attention(q, pool, page_table, lengths,
                                        draft_len=draft_len,
                                        interpret=self._interpret,
                                        k_scale=ks, v_scale=vs)
 
-    def _prefill_attn(self, q, kp, vp, page_row, start, n_real,
+    def _prefill_attn(self, q, pool, page_row, start, n_real,
                       ks=None, vs=None):
         if self._mesh is not None:
-            return ragged_prefill_reference(q, kp, vp, page_row, start,
+            return ragged_prefill_reference(q, pool, page_row, start,
                                             n_real=n_real, k_scale=ks,
                                             v_scale=vs)
-        return ragged_prefill_attention(q, kp, vp, page_row, start,
+        return ragged_prefill_attention(q, pool, page_row, start,
                                         n_real=n_real,
                                         interpret=self._interpret,
                                         k_scale=ks, v_scale=vs)
@@ -888,7 +886,7 @@ class InferenceEngine:
         n_emit = jnp.where(act, n_acc + 1, 0).astype(jnp.int32)
         return emitted, n_emit
 
-    def _decode_step_fn(self, param_vals, kpools, vpools, kamax, vamax,
+    def _decode_step_fn(self, param_vals, pools, kamax, vamax,
                         tokens, draft_len, page_table, lengths, temps,
                         slot_keys, top_k, top_p, rep_pen, pres_pen,
                         counts, bias, mask):
@@ -940,38 +938,33 @@ class InferenceEngine:
                 model.position_embed(NDArray(emb_pos))
             if model._dtype != "float32":
                 x = x.astype(model._dtype)
-            new_k, new_v = [], []
+            new_pools = []
             new_ka, new_va = [], []
             spec = self._kv_spec
             for i in range(model.num_layers):
                 blk = getattr(model, f"block{i}")
                 q, k, v = _qkv_heads(blk.attn, blk.ln1(x))  # (S,W,H,D)
+                kv = jnp.concatenate([k, v], axis=-1)       # (S,W,H,2D)
                 if spec is None:
-                    kp = write_block_kv(kpools[i], k, write_page,
-                                        write_off)
-                    vp = write_block_kv(vpools[i], v, write_page,
-                                        write_off)
+                    pool = write_block_kv(pools[i], kv, write_page,
+                                          write_off)
                     ks = vs = None
-                    adt = kp.dtype
+                    adt = pool.dtype
                 else:
-                    # quantize-at-write: the page's scale grows with
+                    # quantize-at-write: the page's scales grow with
                     # the window's amax and existing codes requantize
                     # in the same scatter — pure traced data, no new
                     # programs (trace counts stay asserted at 1)
-                    kp, ka = write_block_kv_q(kpools[i], kamax[i], k,
-                                              write_page, write_off,
-                                              spec)
-                    vp, va = write_block_kv_q(vpools[i], vamax[i], v,
-                                              write_page, write_off,
-                                              spec)
+                    pool, ka, va = write_block_kv_q(
+                        pools[i], kamax[i], vamax[i], kv, write_page,
+                        write_off, spec)
                     new_ka.append(ka)
                     new_va.append(va)
                     ks = page_scales(ka, spec)
                     vs = page_scales(va, spec)
                     adt = self._dtype
-                new_k.append(kp)
-                new_v.append(vp)
-                out = self._verify_attn(q.astype(adt), kp, vp,
+                new_pools.append(pool)
+                out = self._verify_attn(q.astype(adt), pool,
                                         page_table, eff_len, draft_len,
                                         ks, vs)
                 out = NDArray(out.astype(q.dtype).reshape(
@@ -1000,10 +993,10 @@ class InferenceEngine:
             bad = jnp.any(jnp.any(~jnp.isfinite(logits), axis=-1) &
                           used, axis=-1) & act
             emitted = jnp.where(bad[:, None], -emitted - 1, emitted)
-        return (tuple(new_k), tuple(new_v), tuple(new_ka),
-                tuple(new_va), emitted, n_emit, new_lengths)
+        return (tuple(new_pools), tuple(new_ka), tuple(new_va), emitted,
+                n_emit, new_lengths)
 
-    def _prefill_fn(self, param_vals, kpools, vpools, kamax, vamax,
+    def _prefill_fn(self, param_vals, pools, kamax, vamax,
                     ids, t0, pages, temp, key, top_k, top_p, rep_pen,
                     pres_pen, counts, bias, vocab_mask):
         """Prompt forward for ONE request (ids (1, Tpad) padded): dense
@@ -1032,24 +1025,24 @@ class InferenceEngine:
             pos_q = lax.broadcasted_iota(jnp.int32, (Tpad, Tpad), 0)
             pos_k = lax.broadcasted_iota(jnp.int32, (Tpad, Tpad), 1)
             mask = ((pos_k <= pos_q) & (pos_k < t0))[None, None]
-            new_k, new_v = list(kpools), list(vpools)
+            new_pools = list(pools)
             new_ka, new_va = list(kamax), list(vamax)
             spec = self._kv_spec
             for i in range(model.num_layers):
                 blk = getattr(model, f"block{i}")
                 q, k, v = _qkv_heads(blk.attn, blk.ln1(x))  # (1,Tpad,H,D)
+                kv = jnp.concatenate([k[0], v[0]], axis=-1)
                 if spec is None:
-                    new_k[i] = write_prompt_kv(new_k[i], k[0], pages)
-                    new_v[i] = write_prompt_kv(new_v[i], v[0], pages)
+                    new_pools[i] = write_prompt_kv(new_pools[i], kv,
+                                                   pages)
                 else:
-                    # quantize the prompt's pages at a FRESH per-page
-                    # scale; the prompt's own attention below runs on
+                    # quantize the prompt's pages at FRESH per-page
+                    # scales; the prompt's own attention below runs on
                     # the exact pre-quantization K/V (only future
                     # paged reads pay the quantization error)
-                    new_k[i], new_ka[i] = write_prompt_kv_q(
-                        new_k[i], new_ka[i], k[0], pages, spec)
-                    new_v[i], new_va[i] = write_prompt_kv_q(
-                        new_v[i], new_va[i], v[0], pages, spec)
+                    new_pools[i], new_ka[i], new_va[i] = \
+                        write_prompt_kv_q(new_pools[i], new_ka[i],
+                                          new_va[i], kv, pages, spec)
                 out = _sdpa(q, k, v, mask=mask)
                 x = x + blk.attn.proj(NDArray(out.reshape(
                     1, Tpad, model._units)))
@@ -1067,10 +1060,9 @@ class InferenceEngine:
         if self.guard_nonfinite:             # sign-encoded, see decode
             tok = jnp.where(jnp.any(~jnp.isfinite(logits)),
                             -tok - 1, tok)
-        return tuple(new_k), tuple(new_v), tuple(new_ka), \
-            tuple(new_va), tok
+        return tuple(new_pools), tuple(new_ka), tuple(new_va), tok
 
-    def _chunk_prefill_fn(self, param_vals, kpools, vpools, kamax,
+    def _chunk_prefill_fn(self, param_vals, pools, kamax,
                           vamax, ids, start, n_real, page_row, temp,
                           key, top_k, top_p, rep_pen, pres_pen, counts,
                           bias, vocab_mask):
@@ -1107,32 +1099,30 @@ class InferenceEngine:
             page_idx = jnp.clip(pos[0] // ps, 0, self.max_pages - 1)
             tok_pages = jnp.where(live, page_row[page_idx], NULL_PAGE)
             tok_off = pos[0] % ps
-            new_k, new_v = list(kpools), list(vpools)
+            new_pools = list(pools)
             new_ka, new_va = list(kamax), list(vamax)
             spec = self._kv_spec
             for i in range(model.num_layers):
                 blk = getattr(model, f"block{i}")
                 q, k, v = _qkv_heads(blk.attn, blk.ln1(x))  # (1,Cpad,H,D)
+                kv = jnp.concatenate([k[0], v[0]], axis=-1)
                 if spec is None:
-                    new_k[i] = write_token_kv(new_k[i], k[0], tok_pages,
-                                              tok_off)
-                    new_v[i] = write_token_kv(new_v[i], v[0], tok_pages,
-                                              tok_off)
+                    # through this module's global: the benchmark's
+                    # never-written-page control patches it here
+                    new_pools[i] = write_token_kv(new_pools[i], kv,
+                                                  tok_pages, tok_off)
                     ks = vs = None
-                    adt = new_k[i].dtype
+                    adt = new_pools[i].dtype
                 else:
-                    new_k[i], new_ka[i] = write_token_kv_q(
-                        new_k[i], new_ka[i], k[0], tok_pages, tok_off,
-                        spec)
-                    new_v[i], new_va[i] = write_token_kv_q(
-                        new_v[i], new_va[i], v[0], tok_pages, tok_off,
-                        spec)
+                    new_pools[i], new_ka[i], new_va[i] = \
+                        write_token_kv_q(new_pools[i], new_ka[i],
+                                         new_va[i], kv, tok_pages,
+                                         tok_off, spec)
                     ks = page_scales(new_ka[i], spec)
                     vs = page_scales(new_va[i], spec)
                     adt = self._dtype
-                out = self._prefill_attn(q[0].astype(adt),
-                                         new_k[i], new_v[i], page_row,
-                                         start, n_real, ks, vs)
+                out = self._prefill_attn(q[0].astype(adt), new_pools[i],
+                                         page_row, start, n_real, ks, vs)
                 x = x + blk.attn.proj(NDArray(out.astype(q.dtype).reshape(
                     1, Cpad, model._units)))
                 x = x + _mlp(blk, x)
@@ -1150,25 +1140,22 @@ class InferenceEngine:
         if self.guard_nonfinite:             # sign-encoded, see decode
             tok = jnp.where(jnp.any(~jnp.isfinite(logits)),
                             -tok - 1, tok)
-        return tuple(new_k), tuple(new_v), tuple(new_ka), \
-            tuple(new_va), tok
+        return tuple(new_pools), tuple(new_ka), tuple(new_va), tok
 
-    def _copy_page_fn(self, kpools, vpools, src, dst):
+    def _copy_page_fn(self, pools, src, dst):
         """COW boundary copy: duplicate one page's K/V across every
         layer, so the cached partial page becomes this slot's private
         page (the cached original stays read-only for its sharers).
         src/dst are traced scalars — one compile, ever."""
         self.copy_trace_count += 1           # trace-time only
-        new_k = tuple(p.at[dst].set(p[src]) for p in kpools)
-        new_v = tuple(p.at[dst].set(p[src]) for p in vpools)
-        return new_k, new_v
+        return tuple(p.at[dst].set(p[src]) for p in pools)
 
     def _copy_page(self, src: int, dst: int):
         if self._copy_jit is None:
             self._copy_jit = jax.jit(self._copy_page_fn,
-                                     donate_argnums=(0, 1))
-        self._kpools, self._vpools = self._copy_jit(
-            self._kpools, self._vpools, np.int32(src), np.int32(dst))
+                                     donate_argnums=(0,))
+        self._kvpools = self._copy_jit(self._kvpools, np.int32(src),
+                                       np.int32(dst))
         if self._kv_spec is not None:
             # the scale is page metadata: a COW copy carries its
             # source's scale (the codes were copied verbatim), and the
@@ -1178,28 +1165,28 @@ class InferenceEngine:
             for a in self._vamax:
                 a[dst] = a[src]
 
-    def _promote_page_fn(self, kpools, vpools, kpage, vpage, dst):
-        """Write one demoted page's payload (per-layer (H, ps, D)
-        host arrays, traced as data) into page ``dst`` of every pool —
+    def _promote_page_fn(self, pools, kpage, vpage, dst):
+        """Write one demoted page's payload (per-layer (H, ps, D) key
+        and value host arrays, traced as data, joined on the lanes
+        here: the host format keeps them apart) into page ``dst`` of
+        every pool —
         the tier PROMOTION program. Like the COW copy it is jitted
         once with donated pools and traced operands: re-admitting a
         page from DRAM or disk is data movement, never a new program
         and never a prefill recompute."""
         self.promote_trace_count += 1        # trace-time only
-        new_k = tuple(p.at[dst].set(pg.astype(p.dtype))
-                      for p, pg in zip(kpools, kpage))
-        new_v = tuple(p.at[dst].set(pg.astype(p.dtype))
-                      for p, pg in zip(vpools, vpage))
-        return new_k, new_v
+        return tuple(
+            p.at[dst].set(jnp.concatenate([kpg, vpg], -1).astype(p.dtype))
+            for p, kpg, vpg in zip(pools, kpage, vpage))
 
     def _promote_page(self, k_payload, v_payload, kamax, vamax,
                       dst: int):
         if self._promote_jit is None:
             self._promote_jit = jax.jit(self._promote_page_fn,
-                                        donate_argnums=(0, 1))
-        self._kpools, self._vpools = self._promote_jit(
-            self._kpools, self._vpools, tuple(k_payload),
-            tuple(v_payload), np.int32(dst))
+                                        donate_argnums=(0,))
+        self._kvpools = self._promote_jit(
+            self._kvpools, tuple(k_payload), tuple(v_payload),
+            np.int32(dst))
         if self._kv_spec is not None:
             # scale metadata rides back with the codes: the payload
             # was captured at demotion with exactly these amaxes
@@ -1208,16 +1195,19 @@ class InferenceEngine:
             for l, a in enumerate(self._vamax):
                 a[dst] = vamax[l]
 
-    def _gather_page_fn(self, kpools, vpools, page):
+    def _gather_page_fn(self, pools, page):
         """Demotion capture: slice one page out of EVERY pool in one
-        program call. Naively ``np.asarray(pool[page])`` per layer
+        program call, its key lanes and its value lanes apart (the
+        host format of tiers and capsules, whatever the pool's). Naively ``np.asarray(pool[page])`` per layer
         costs 2L separate dispatches per demoted page — on a small
         host that overhead alone made re-admission-by-copy slower
         than the recompute it replaces. One program, traced once
         (``page`` is a traced scalar), then a single device_get."""
         self.demote_trace_count += 1         # trace-time only
-        return (tuple(p[page] for p in kpools),
-                tuple(p[page] for p in vpools))
+        D = self._D
+        pages = [p[page] for p in pools]
+        return (tuple(pg[..., :D] for pg in pages),
+                tuple(pg[..., D:] for pg in pages))
 
     def gather_page(self, page: int) -> tuple:
         """One page's wire/at-rest payload: per-layer (H, ps, D)
@@ -1229,8 +1219,7 @@ class InferenceEngine:
         if self._gather_jit is None:
             self._gather_jit = jax.jit(self._gather_page_fn)
         k_payload, v_payload = jax.device_get(
-            self._gather_jit(self._kpools, self._vpools,
-                             np.int32(page)))
+            self._gather_jit(self._kvpools, np.int32(page)))
         kamax = vamax = None
         if self._kv_spec is not None:
             kamax = np.asarray([a[page] for a in self._kamax],
@@ -1446,11 +1435,13 @@ class InferenceEngine:
             # dashboard can see the quantized working set. At a fixed
             # HBM budget slots × context ≤ pool bytes, so kv_pool_bytes
             # IS the serving-capacity denominator.
-            "kv_dtype": str(self._kpools[0].dtype),
+            "kv_dtype": str(self._kvpools[0].dtype),
             "kv_quant": self.kv_quant or "off",
+            # a page of one pool, (H, page_size, 2 * D): keys | values
+            # on the lanes, the layout the kernels read
+            "kv_page_shape": tuple(self._kvpools[0].shape[1:]),
             "kv_pool_bytes": int(
-                sum(k.nbytes + v.nbytes
-                    for k, v in zip(self._kpools, self._vpools)) +
+                sum(p.nbytes for p in self._kvpools) +
                 sum(a.nbytes for a in self._kamax) +
                 sum(a.nbytes for a in self._vamax)),
             "kv_quantized_pages": (
@@ -2208,8 +2199,8 @@ class InferenceEngine:
         installed under another — the transport refuses the transfer
         and the replay fallback recomputes instead."""
         return (self.kv_quant or "off", self.page_size,
-                len(self._kpools), tuple(self._kpools[0].shape[1:]),
-                str(self._kpools[0].dtype))
+                len(self._kvpools), (self._H, self.page_size, self._D),
+                str(self._kvpools[0].dtype))
 
     def decode_ready(self, request_id: int) -> bool:
         """True when ``request_id`` holds a slot past prefill — the
@@ -2425,11 +2416,11 @@ class InferenceEngine:
         pages_arr[:prompt_pages] = slot.row[:prompt_pages]
         fn = self._prefill_jits.get(bucket)
         if fn is None:
-            fn = jax.jit(self._prefill_fn, donate_argnums=(1, 2))
+            fn = jax.jit(self._prefill_fn, donate_argnums=(1,))
             self._prefill_jits[bucket] = fn
-        self._kpools, self._vpools, ka, va, tok = self._dispatch(
+        self._kvpools, ka, va, tok = self._dispatch(
             ("dense", Tpad), fn,
-            self._param_vals, self._kpools, self._vpools, self._kamax,
+            self._param_vals, self._kvpools, self._kamax,
             self._vamax, ids, np.int32(t0), pages_arr,
             np.float32(req.temperature), slot.key,
             *self._slot_sampling_args(slot_idx))
@@ -2466,11 +2457,11 @@ class InferenceEngine:
         ids[0, :n] = slot.attempt_ids[start:start + n]
         fn = self._chunk_jits.get(bucket)
         if fn is None:
-            fn = jax.jit(self._chunk_prefill_fn, donate_argnums=(1, 2))
+            fn = jax.jit(self._chunk_prefill_fn, donate_argnums=(1,))
             self._chunk_jits[bucket] = fn
-        self._kpools, self._vpools, ka, va, tok = self._dispatch(
+        self._kvpools, ka, va, tok = self._dispatch(
             ("chunk", Cpad), fn,
-            self._param_vals, self._kpools, self._vpools, self._kamax,
+            self._param_vals, self._kvpools, self._kamax,
             self._vamax, ids, np.int32(start), np.int32(n),
             slot.row.copy(), np.float32(req.temperature), slot.key,
             *self._slot_sampling_args(slot_idx))
@@ -2808,10 +2799,10 @@ class InferenceEngine:
         else:
             samp_ops = self._neutral_step_ops(W)
         t_start = time.perf_counter()
-        self._kpools, self._vpools, ka, va, emitted, n_emit, lengths = \
+        self._kvpools, ka, va, emitted, n_emit, lengths = \
             self._dispatch("decode" if W == 1 else "verify",
                            self._decode_step, self._param_vals,
-                           self._kpools, self._vpools, self._kamax,
+                           self._kvpools, self._kamax,
                            self._vamax, tokens, draft_len, table_dev,
                            lengths_dev, self._temps.copy(),
                            self._slot_keys.copy(), *samp_ops)

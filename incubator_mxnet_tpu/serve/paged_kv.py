@@ -1,13 +1,18 @@
 """Paged KV cache: a shared page pool + host-side page allocator.
 
-Layout (one pool pair per transformer layer):
+Layout (ONE pool per transformer layer):
 
-    k_pool / v_pool : (num_pages, H, page_size, D)
+    kv_pool : (num_pages, H, page_size, 2 * D)
 
-chosen so each (page, head) slice is a contiguous (page_size, D) tile —
-the ragged kernel's per-head dot operand (ops/ragged_attention.py) —
-and so a tp mesh can shard the H axis with the existing
-``parallel.mesh`` machinery without splitting any page.
+a head's keys in lanes [0, D), its values in lanes [D, 2 * D). Each
+(page, head) slice is one contiguous (page_size, 2 * D) tile — the
+ragged kernel's per-head dot operand (ops/ragged_attention.py) — and at
+D = 64 a bf16 page of 16 positions is exactly one native (16, 128) TPU
+tile, so the chip stores the pool row-major, the layout its kernels
+read, and no serving program relays a pool out. A tp mesh shards the H
+axis with the existing ``parallel.mesh`` machinery without splitting
+any page. Every writer below takes ``new`` with keys and values
+concatenated on the last axis: one scatter a layer.
 
 Invariants (enforced by the engine, asserted in tests):
   - **Page 0 is the NULL page.** The allocator never hands it out; every
@@ -60,12 +65,13 @@ __all__ = ["NULL_PAGE", "PageAllocator", "PrefixIndex", "KVTierStore",
 # --------------------------------------------------------------------- #
 # quantized pool layout (int8 / fp8 payload + per-page symmetric scale)
 #
-# A quantized pool keeps the SAME (num_pages, H, page_size, D) page
-# layout with a narrow payload dtype, plus ONE float32 absolute-max
-# statistic per page per pool (``amax``, shape (num_pages,)) from which
-# the page's symmetric dequantization scale derives
+# A quantized pool keeps the SAME (num_pages, H, page_size, 2 * D) page
+# layout with a narrow payload dtype (key codes | value codes), plus
+# TWO float32 absolute-max statistics per page (``kamax`` for the key
+# half, ``vamax`` for the value half, each of shape (num_pages,)) from
+# which each half's symmetric dequantization scale derives
 # (ops.quantization.symmetric_scale: amax / qmax, 1.0 on an untouched
-# page). The amax array is PAGE METADATA: it rides next to the page
+# page). The amax arrays are PAGE METADATA: they ride next to the page
 # table as data into every program that reads or writes pages (and on
 # TPU down the same scalar-prefetch path — ops/ragged_attention.py), a
 # shared prefix page's scale is shared exactly like the page itself,
@@ -119,71 +125,99 @@ def page_scales(amax, spec: KVQuantSpec):
     return symmetric_scale(amax, spec.qmax)
 
 
-def write_token_kv_q(pool, amax, new, pages, offsets, spec: KVQuantSpec):
-    """Quantized twin of ``write_token_kv``: scatter one K (or V) row
-    per entry into an int8/fp8 pool, growing the per-page scales.
+def _lane_halves(k, v, lanes):
+    """(N, lanes) from a key-half and a value-half statistic of (N,):
+    ``k`` on a fused page's lanes [0, D), ``v`` on [D, 2 * D)."""
+    return jnp.where(jnp.arange(lanes) < lanes // 2, k[:, None],
+                     v[:, None])
 
-    pool: (P, H, ps, D) codes; amax: (P,) f32; new: (N, H, D) float;
-    pages/offsets: (N,) int32. Returns ``(pool, amax)`` updated.
+
+def _half_amax(x, axes):
+    """|max| of the key lanes and of the value lanes of fused rows."""
+    D = x.shape[-1] // 2
+    a = jnp.abs(x.astype(jnp.float32))
+    return (jnp.max(a[..., :D], axis=axes), jnp.max(a[..., D:], axis=axes))
+
+
+def write_token_kv_q(pool, kamax, vamax, new, pages, offsets,
+                     spec: KVQuantSpec):
+    """Quantized twin of ``write_token_kv``: scatter one fused K | V row
+    per entry into an int8/fp8 pool, growing the per-page scales of
+    each half.
+
+    pool: (P, H, ps, 2D) codes; kamax/vamax: (P,) f32; new: (N, H, 2D)
+    float; pages/offsets: (N,) int32. Returns ``(pool, kamax, vamax)``
+    updated.
 
     Three phases, all safe under duplicate page indices (several rows
     of one call landing in the same page — the verify window's block
     write flattens into this):
-      1. scatter-max the new rows' |max| into ``amax`` (duplicates
-         combine correctly by construction);
-      2. requantize every TOUCHED page's existing codes by
-         ``old_scale / new_scale`` — duplicate entries compute
+      1. scatter-max the new rows' |max| into each half's ``amax``
+         (duplicates combine correctly by construction);
+      2. requantize every TOUCHED page's existing codes, each half by
+         its own ``old_scale / new_scale`` — duplicate entries compute
          IDENTICAL page contents (same gathered codes, same final
-         scale), so the unspecified scatter order cannot diverge;
-      3. quantize the new rows at the final scale and scatter them at
+         scales), so the unspecified scatter order cannot diverge;
+      3. quantize the new rows at the final scales and scatter them at
          their (page, offset) cells — distinct cells except dead
          entries, which all land in the null page (garbage by design,
          same contract as the unquantized write)."""
-    H = pool.shape[1]
-    a_n = jnp.max(jnp.abs(new.astype(jnp.float32)), axis=(1, 2))  # (N,)
-    new_amax = amax.at[pages].max(a_n)
-    old_s = symmetric_scale(amax, spec.qmax)
-    new_s = symmetric_scale(new_amax, spec.qmax)
-    ratio = (old_s / new_s)[pages]                       # (N,) <= 1
+    H, lanes = pool.shape[1], pool.shape[3]
+    ak, av = _half_amax(new, (1, 2))                     # (N,) each
+    new_kamax = kamax.at[pages].max(ak)
+    new_vamax = vamax.at[pages].max(av)
+
+    def scales(old, grown):
+        old_s = symmetric_scale(old, spec.qmax)
+        new_s = symmetric_scale(grown, spec.qmax)
+        return (old_s / new_s)[pages], new_s[pages]      # ratio <= 1
+
+    rk, sk = scales(kamax, new_kamax)
+    rv, sv = scales(vamax, new_vamax)
     touched = requantize_symmetric(
-        pool[pages], ratio[:, None, None, None], spec.dtype, spec.qmax)
+        pool[pages], _lane_halves(rk, rv, lanes)[:, None, None, :],
+        spec.dtype, spec.qmax)
     pool = pool.at[pages].set(touched)
-    q = quantize_symmetric(new, new_s[pages][:, None, None],
-                           spec.dtype, spec.qmax)        # (N, H, D)
+    q = quantize_symmetric(
+        new, _lane_halves(sk, sv, lanes)[:, None, :],
+        spec.dtype, spec.qmax)                           # (N, H, 2D)
     pool = pool.at[pages[:, None], jnp.arange(H)[None, :],
                    offsets[:, None], :].set(q)
-    return pool, new_amax
+    return pool, new_kamax, new_vamax
 
 
-def write_block_kv_q(pool, amax, new, pages, offsets, spec: KVQuantSpec):
+def write_block_kv_q(pool, kamax, vamax, new, pages, offsets,
+                     spec: KVQuantSpec):
     """Quantized twin of ``write_block_kv``: a (S, W) block of rows
     (the speculative verify window) flattened into the per-row
     quantized scatter — duplicate pages inside one slot's window are
     exactly the case ``write_token_kv_q``'s phases are built for."""
-    S, W, H, D = new.shape
-    return write_token_kv_q(pool, amax, new.reshape(S * W, H, D),
+    S, W, H, lanes = new.shape
+    return write_token_kv_q(pool, kamax, vamax,
+                            new.reshape(S * W, H, lanes),
                             pages.reshape(S * W),
                             offsets.reshape(S * W), spec)
 
 
-def write_prompt_kv_q(pool, amax, kv, pages, spec: KVQuantSpec):
+def write_prompt_kv_q(pool, kamax, vamax, kv, pages, spec: KVQuantSpec):
     """Quantized twin of ``write_prompt_kv``: scatter a whole prompt's
-    K (or V) into its pages with a FRESH per-page scale (each page's
-    amax is overwritten, not grown — prefill is the page's first write,
-    so a recycled page's stale range dies here). Dead entries all index
-    the null page; whichever dead page's amax wins the duplicate
-    scatter is garbage by design, like the payload."""
+    fused K | V into its pages with FRESH per-page scales (each page's
+    amax pair is overwritten, not grown — prefill is the page's first
+    write, so a recycled page's stale range dies here). Dead entries
+    all index the null page; whichever dead page's amax wins the
+    duplicate scatter is garbage by design, like the payload."""
     n_pages = pages.shape[0]
-    ps = pool.shape[2]
+    ps, lanes = pool.shape[2], pool.shape[3]
     paged = kv.astype(jnp.float32).reshape(n_pages, ps, kv.shape[1],
-                                           kv.shape[2])
-    a_p = jnp.max(jnp.abs(paged), axis=(1, 2, 3))        # (n_pages,)
-    amax = amax.at[pages].set(a_p)
-    s = symmetric_scale(a_p, spec.qmax)
-    q = quantize_symmetric(paged, s[:, None, None, None],
+                                           lanes)
+    ak, av = _half_amax(paged, (1, 2, 3))                # (n_pages,)
+    s = _lane_halves(symmetric_scale(ak, spec.qmax),
+                     symmetric_scale(av, spec.qmax), lanes)
+    q = quantize_symmetric(paged, s[:, None, None, :],
                            spec.dtype, spec.qmax)
-    q = q.transpose(0, 2, 1, 3)                 # (n_pages, H, ps, D)
-    return pool.at[pages].set(q), amax
+    q = q.transpose(0, 2, 1, 3)                 # (n_pages, H, ps, 2D)
+    return (pool.at[pages].set(q), kamax.at[pages].set(ak),
+            vamax.at[pages].set(av))
 
 
 class PageAllocator:
@@ -920,20 +954,22 @@ class KVTierStore:
 
 def init_kv_pools(num_layers, num_pages, num_heads, page_size, head_dim,
                   dtype="float32", quant: Optional[KVQuantSpec] = None):
-    """Fresh zeroed (k_pool, v_pool) pairs, one per layer. With a
+    """Fresh zeroed pools, one (num_pages, H, page_size, 2 * head_dim)
+    array per layer: keys | values side by side on the lanes. With a
     ``quant`` spec the payload dtype is the spec's narrow dtype (the
     per-page amax metadata is the ENGINE's to own — host-resettable
     page metadata, not pool state)."""
     dt = jnp.dtype(quant.dtype) if quant is not None else jnp.dtype(dtype)
-    mk = lambda: jnp.zeros((num_pages, num_heads, page_size, head_dim), dt)
-    return [(mk(), mk()) for _ in range(num_layers)]
+    return [jnp.zeros((num_pages, num_heads, page_size, 2 * head_dim), dt)
+            for _ in range(num_layers)]
 
 
 def write_token_kv(pool, new, pages, offsets):
-    """Scatter one K (or V) row per entry into the pool.
+    """Scatter one fused K | V row per entry into the pool.
 
-    pool: (P, H, ps, D); new: (N, H, D); pages/offsets: (N,) int32 —
-    entry n writes ``new[n]`` to ``pool[pages[n], :, offsets[n], :]``.
+    pool: (P, H, ps, 2D); new: (N, H, 2D), keys and values concatenated
+    on the last axis; pages/offsets: (N,) int32 — entry n writes
+    ``new[n]`` to ``pool[pages[n], :, offsets[n], :]``.
     Serves both the decode step (one token per SLOT, N = num_slots;
     inactive slots carry pages[n] == NULL_PAGE) and chunked prefill
     (one row per CHUNK TOKEN of a single slot, N = chunk length; padded
@@ -946,32 +982,32 @@ def write_token_kv(pool, new, pages, offsets):
 
 
 def write_block_kv(pool, new, pages, offsets):
-    """Scatter a (S, W) BLOCK of K (or V) rows into the pool — the
+    """Scatter a (S, W) BLOCK of fused K | V rows into the pool — the
     speculative verify step's write: W consecutive positions per slot
     (the last accepted token plus up to W-1 draft candidates).
 
-    pool: (P, H, ps, D); new: (S, W, H, D); pages/offsets: (S, W)
+    pool: (P, H, ps, 2D); new: (S, W, H, 2D); pages/offsets: (S, W)
     int32 — entry (s, w) writes ``new[s, w]`` to
     ``pool[pages[s, w], :, offsets[s, w], :]``. Dead entries (inactive
     slots, positions past a slot's real draft window) carry
     ``pages == NULL_PAGE`` and land harmlessly in the null page, same
     contract as ``write_token_kv`` (which this flattens into). Static
     shapes; safe under jit."""
-    S, W, H, D = new.shape
-    return write_token_kv(pool, new.reshape(S * W, H, D),
+    S, W, H, lanes = new.shape
+    return write_token_kv(pool, new.reshape(S * W, H, lanes),
                           pages.reshape(S * W), offsets.reshape(S * W))
 
 
 def write_prompt_kv(pool, kv, pages):
-    """Scatter a whole prompt's K (or V) into its pages (prefill).
+    """Scatter a whole prompt's fused K | V into its pages (prefill).
 
-    pool: (P, H, ps, D); kv: (Tpad, H, D) with Tpad == len(pages) * ps;
-    pages: (n_pages,) int32 with dead (beyond the prompt) entries
+    pool: (P, H, ps, 2D); kv: (Tpad, H, 2D) with Tpad == len(pages) *
+    ps; pages: (n_pages,) int32 with dead (beyond the prompt) entries
     NULL_PAGE — those whole-page writes land in the null page. Duplicate
     null indices are fine: the store order is unspecified but the value
     is never read unmasked."""
     n_pages = pages.shape[0]
     ps = pool.shape[2]
     paged = kv.reshape(n_pages, ps, kv.shape[1], kv.shape[2]) \
-        .transpose(0, 2, 1, 3)                  # (n_pages, H, ps, D)
+        .transpose(0, 2, 1, 3)                  # (n_pages, H, ps, 2D)
     return pool.at[pages].set(paged.astype(pool.dtype))

@@ -54,7 +54,7 @@ def test_chip_smoke_cpu_rehearsal_passes_without_the_marker():
 
 def _dispatch_calls():
     q4 = jnp.zeros((1, 8, 2, 16), jnp.float32)        # (B, T, H, D)
-    pool = jnp.zeros((3, 2, 8, 16), jnp.float32)      # (P, H, ps, D)
+    pool = jnp.zeros((3, 2, 8, 32), jnp.float32)      # (P, H, ps, 2D)
     table = jnp.zeros((1, 2), jnp.int32)
     one = jnp.ones((1,), jnp.int32)
     return {
@@ -65,11 +65,11 @@ def _dispatch_calls():
             q4, q4, q4, jnp.full((1,), 2, jnp.int32), False, None,
             kw.get("interpret", False)),
         "ragged_decode": lambda **kw: ra.ragged_paged_attention(
-            jnp.zeros((1, 2, 16)), pool, pool, table, one, **kw),
+            jnp.zeros((1, 2, 16)), pool, table, one, **kw),
         "ragged_prefill": lambda **kw: ra.ragged_prefill_attention(
-            jnp.zeros((8, 2, 16)), pool, pool, table[0], 0, **kw),
+            jnp.zeros((8, 2, 16)), pool, table[0], 0, **kw),
         "ragged_verify": lambda **kw: ra.ragged_verify_attention(
-            jnp.zeros((1, 2, 2, 16)), pool, pool, table, one, **kw),
+            jnp.zeros((1, 2, 2, 16)), pool, table, one, **kw),
     }
 
 

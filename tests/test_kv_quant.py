@@ -131,42 +131,55 @@ def test_cow_partial_page_copy_requantizes_correctly():
     spec = kv_quant_spec("int8")
     rng = np.random.RandomState(4)
     H, ps, D, P = 2, 8, 4, 6
-    pool = jnp.zeros((P, H, ps, D), spec.dtype)
-    amax = jnp.zeros((P,))
+    # the fused pool: key codes | value codes on the lanes, each half
+    # of a page under its own scale; the values run 10x the keys, so a
+    # shared scale would cost the keys three bits
+    half = np.concatenate([np.ones(D), 10.0 * np.ones(D)]).astype(
+        np.float32)
+    pool = jnp.zeros((P, H, ps, 2 * D), spec.dtype)
+    kamax = vamax = jnp.zeros((P,))
     # page 1: the cached boundary page, 5 of 8 rows meaningful
-    rows = rng.randn(ps, H, D).astype(np.float32)
-    pool, amax = write_prompt_kv_q(pool, amax,
-                                   jnp.asarray(rows)[None].reshape(
-                                       ps, H, D),
-                                   jnp.asarray([1], jnp.int32), spec)
-    # COW: codes copied verbatim, scale copied (engine._copy_page)
+    rows = rng.randn(ps, H, 2 * D).astype(np.float32) * half
+    pool, kamax, vamax = write_prompt_kv_q(
+        pool, kamax, vamax, jnp.asarray(rows),
+        jnp.asarray([1], jnp.int32), spec)
+    assert float(vamax[1]) > 3 * float(kamax[1])
+
+    def lane_scales(ka, va, page):
+        sk = float(page_scales(jnp.asarray(ka), spec)[page])
+        sv = float(page_scales(jnp.asarray(va), spec)[page])
+        return np.concatenate([sk * np.ones(D), sv * np.ones(D)])
+
+    # COW: codes copied verbatim, scales copied (engine._copy_page)
     pool = pool.at[2].set(pool[1])
-    amax = np.array(amax)
-    amax[2] = amax[1]
-    s_before = float(page_scales(jnp.asarray(amax), spec)[2])
+    kamax, vamax = np.array(kamax), np.array(vamax)
+    kamax[2], vamax[2] = kamax[1], vamax[1]
+    s_before = lane_scales(kamax, vamax, 2)
     deq_before = np.asarray(pool[2], np.float32) * s_before
     np.testing.assert_array_equal(
         deq_before, np.asarray(pool[1], np.float32) * s_before)
     # suffix writes (rows 5..7) 4x hotter than the cached prefix
-    suffix = (4.0 * rng.randn(3, H, D)).astype(np.float32)
-    pool, amax2 = write_token_kv_q(
-        pool, jnp.asarray(amax), jnp.asarray(suffix),
+    suffix = (4.0 * rng.randn(3, H, 2 * D)).astype(np.float32) * half
+    page1 = np.asarray(pool[1])
+    pool, kamax2, vamax2 = write_token_kv_q(
+        pool, jnp.asarray(kamax), jnp.asarray(vamax), jnp.asarray(suffix),
         jnp.asarray([2, 2, 2], jnp.int32),
         jnp.asarray([5, 6, 7], jnp.int32), spec)
-    s_after = float(page_scales(amax2, spec)[2])
-    assert s_after >= s_before
+    s_after = lane_scales(kamax2, vamax2, 2)
+    assert (s_after >= s_before).all()
     deq_after = np.asarray(pool[2], np.float32) * s_after
-    # prefix rows: original value ± (old quantum/2 + new quantum/2)
-    prefix_vals = np.moveaxis(rows[:5], 0, 1)     # (H, 5, D)
-    assert np.abs(deq_after[:, :5] - prefix_vals).max() <= \
-        s_before / 2 + s_after / 2 + 1e-6
-    # suffix rows: fresh quantization at the grown scale
-    suffix_vals = np.moveaxis(suffix, 0, 1)       # (H, 3, D)
-    assert np.abs(deq_after[:, 5:] - suffix_vals).max() <= \
-        s_after / 2 + 1e-6
+    # prefix rows: original value ± (old quantum/2 + new quantum/2),
+    # each half by its own quantum
+    prefix_vals = np.moveaxis(rows[:5], 0, 1)     # (H, 5, 2D)
+    assert (np.abs(deq_after[:, :5] - prefix_vals) <=
+            s_before / 2 + s_after / 2 + 1e-6).all()
+    # suffix rows: fresh quantization at the grown scales
+    suffix_vals = np.moveaxis(suffix, 0, 1)       # (H, 3, 2D)
+    assert (np.abs(deq_after[:, 5:] - suffix_vals) <=
+            s_after / 2 + 1e-6).all()
     # the cached original is untouched
-    np.testing.assert_array_equal(np.asarray(pool[1], np.float32),
-                                  np.asarray(pool[1], np.float32))
+    np.testing.assert_array_equal(np.asarray(pool[1]), page1)
+    assert kamax2[1] == kamax[1] and vamax2[1] == vamax[1]
 
 
 def test_quantized_cow_boundary_page_end_to_end(model):

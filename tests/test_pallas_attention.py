@@ -631,3 +631,57 @@ def test_packed_dense_pair_compiles_for_v5e_with_no_relayout(
     assert bwd[1].startswith(f"bf16[{B},{T},{3 * H * D}]")
     moved = re.findall(r"= bf16\[[^ ]* (copy|transpose|fusion)\(", text)
     assert not moved, moved
+
+
+def test_serving_programs_compile_for_v5e_with_no_pool_relayout(
+        v5e_chip, no_compile_cache, monkeypatch):
+    """The engine's decode step and a chunk-prefill program of a
+    two-layer model at GPT-2-XL's widths (25 heads of 64, 64 slots, 695
+    pages of 16, bf16) compile for the v5e to one Mosaic call a layer
+    and no ``copy`` or ``transpose`` of a pool-sized array: the pool
+    (P, H, 16, 128) is stored in the layout its kernels read, and the
+    scatter writes into it in place (a (.., 64)-wide pool cost two
+    whole-pool copies a pool a program; PERF.md, PR 33)."""
+    import math
+    import re
+    import numpy as np
+    from incubator_mxnet_tpu.models.gpt import GPTModel
+    from incubator_mxnet_tpu.serve import InferenceEngine, Request
+
+    L, P = 2, 695
+    model = GPTModel(vocab_size=256, units=1600, hidden_size=6400,
+                     num_layers=L, num_heads=25, max_length=1024,
+                     dropout=0.0, dtype="bfloat16", flash=False)
+    model.initialize()
+    eng = InferenceEngine(model, num_slots=64, page_size=16, max_len=1024,
+                          num_pages=P, chunk_pages=16, token_budget=512)
+    assert eng.health_snapshot()["kv_page_shape"] == (25, 16, 128)
+    # one request through a chunk and a decode step on the CPU records
+    # each program's abstract arguments
+    eng.run([Request(np.arange(1, 41, dtype=np.int32), max_new_tokens=2)])
+    bodies = {"decode": (eng._decode_step_fn, "mxtpu_ragged_decode"),
+              ("chunk", 64): (eng._chunk_prefill_fn,
+                              "mxtpu_ragged_prefill")}
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    pool_elems = P * 25 * 16 * 128
+    for name, (body, kernel) in bodies.items():
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=v5e_chip),
+            eng._programs[name][1])
+        # a jit of its own: the engine's was traced on the CPU, where
+        # the dispatchers took the jnp reference
+        compiled = jax.jit(lambda *a: body(*a), donate_argnums=(1,)) \
+            .lower(*args).compile()
+        text = compiled.as_text()
+        calls = re.findall(r"%(mxtpu_\w+?)[.\d]* = \S+ custom-call\(", text)
+        assert calls == [kernel] * L, (name, calls)
+        assert f"bf16[{P},25,16,128]{{3,2,1,0" in text     # row-major
+        moved = [(shape, op) for shape, op in re.findall(
+            r"= \w+\[([\d,]+)\]\S* (copy|transpose)\(", text)
+            if math.prod(map(int, shape.split(","))) >= pool_elems // 2]
+        assert not moved, (name, moved)
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= L * pool_elems * 2   # donated
+        assert mem.temp_size_in_bytes < pool_elems * 2 // 4, \
+            (name, mem.temp_size_in_bytes)

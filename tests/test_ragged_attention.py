@@ -19,6 +19,13 @@ from incubator_mxnet_tpu.ops.ragged_attention import (
     ragged_verify_attention, ragged_verify_reference)
 
 
+def _fuse(k_pool, v_pool):
+    """The pool as the program holds it: a head's keys | values side by
+    side on the last axis. The cases below build the two halves apart
+    (their oracles read them apart) and fuse at the call."""
+    return jnp.concatenate([jnp.asarray(k_pool), jnp.asarray(v_pool)], -1)
+
+
 def _make_case(rng, S, H, D, page_size, max_pages, lengths,
                num_pages=None, dtype=np.float32):
     """Random pools + a SHUFFLED page table (non-identity page order —
@@ -77,9 +84,9 @@ def test_ragged_matches_dense_sdpa(lengths, impl):
     max_pages = 4                                       # Tmax = 32
     q, kp, vp, pt, ln = _make_case(rng, S, H, D, ps, max_pages, lengths)
     if impl == "pallas_interpret":
-        got = _ragged_pallas(q, kp, vp, pt, ln, D ** -0.5, True)
+        got = _ragged_pallas(q, _fuse(kp, vp), pt, ln, D ** -0.5, True)
     else:
-        got = ragged_attention_reference(q, kp, vp, pt, ln)
+        got = ragged_attention_reference(q, _fuse(kp, vp), pt, ln)
     ref = _dense_sdpa_oracle(q, kp, vp, pt, ln)
     # fully-masked rows: exactly zero (kernel contract); _sdpa_dense
     # emits the uniform mean of V there, so compare only live rows
@@ -103,8 +110,8 @@ def test_pallas_interpret_matches_jnp_reference_exhaustive():
         q, kp, vp, pt, ln = _make_case(rng, len(lengths), 2, 16, ps,
                                        max_pages, lengths,
                                        num_pages=64)
-        a = _ragged_pallas(q, kp, vp, pt, ln, 16 ** -0.5, True)
-        b = ragged_attention_reference(q, kp, vp, pt, ln)
+        a = _ragged_pallas(q, _fuse(kp, vp), pt, ln, 16 ** -0.5, True)
+        b = ragged_attention_reference(q, _fuse(kp, vp), pt, ln)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-5, atol=2e-5)
 
@@ -116,12 +123,12 @@ def test_null_page_contents_never_leak():
     rng = np.random.RandomState(2)
     ps = 8
     q, kp, vp, pt, ln = _make_case(rng, 4, 2, 8, ps, 4, [0, 3, 8, 20])
-    base = ragged_attention_reference(q, kp, vp, pt, ln)
+    base = ragged_attention_reference(q, _fuse(kp, vp), pt, ln)
     kp2 = kp.at[0].set(1e9)
     vp2 = vp.at[0].set(-1e9)
-    poisoned = ragged_attention_reference(q, kp2, vp2, pt, ln)
+    poisoned = ragged_attention_reference(q, _fuse(kp2, vp2), pt, ln)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(poisoned))
-    a = _ragged_pallas(q, kp2, vp2, pt, ln, 8 ** -0.5, True)
+    a = _ragged_pallas(q, _fuse(kp2, vp2), pt, ln, 8 ** -0.5, True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(base),
                                rtol=2e-5, atol=2e-5)
 
@@ -132,12 +139,13 @@ def test_partial_tail_page_masked():
     rng = np.random.RandomState(3)
     ps = 8
     q, kp, vp, pt, ln = _make_case(rng, 2, 2, 8, ps, 2, [5, 11])
-    base = np.asarray(_ragged_pallas(q, kp, vp, pt, ln, 8 ** -0.5, True))
+    base = np.asarray(_ragged_pallas(q, _fuse(kp, vp), pt, ln, 8 ** -0.5,
+                                     True))
     # slot 0's only page is pt[0,0]; positions 5..7 are dead
     page = int(pt[0, 0])
     kp2 = kp.at[page, :, 5:, :].set(123.0)
     vp2 = vp.at[page, :, 5:, :].set(-321.0)
-    got = np.asarray(_ragged_pallas(q, kp2, vp2, pt, ln, 8 ** -0.5,
+    got = np.asarray(_ragged_pallas(q, _fuse(kp2, vp2), pt, ln, 8 ** -0.5,
                                     True))
     np.testing.assert_array_equal(base, got)
 
@@ -148,13 +156,12 @@ def test_dispatcher_and_dtype():
     bf16 inputs accumulate in f32 and track the f32 result."""
     rng = np.random.RandomState(4)
     q, kp, vp, pt, ln = _make_case(rng, 3, 2, 8, 8, 3, [1, 9, 24])
-    out = ragged_paged_attention(q, kp, vp, pt, ln)
-    ref = ragged_attention_reference(q, kp, vp, pt, ln)
+    out = ragged_paged_attention(q, _fuse(kp, vp), pt, ln)
+    ref = ragged_attention_reference(q, _fuse(kp, vp), pt, ln)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-6, atol=1e-6)
     b16 = ragged_paged_attention(q.astype(jnp.bfloat16),
-                                 kp.astype(jnp.bfloat16),
-                                 vp.astype(jnp.bfloat16), pt, ln)
+                                 _fuse(kp, vp).astype(jnp.bfloat16), pt, ln)
     assert b16.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(b16, np.float32),
                                np.asarray(ref), rtol=0.05, atol=0.05)
@@ -219,12 +226,12 @@ def test_prefill_matches_dense_causal_oracle(q_start, C, impl):
     q = rng.randn(C, H, D).astype(np.float32)
     if impl == "pallas_interpret":
         got = _ragged_prefill_pallas(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(q), _fuse(kp, vp),
             jnp.asarray(row), jnp.asarray([q_start, C], jnp.int32),
             D ** -0.5, True)
     else:
         got = ragged_prefill_reference(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(q), _fuse(kp, vp),
             jnp.asarray(row), np.int32(q_start))
     ref = _prefill_oracle(q, tok_k, tok_v, q_start, C)
     np.testing.assert_allclose(np.asarray(got), ref, rtol=2e-5,
@@ -245,15 +252,15 @@ def test_prefill_chunk_composition_matches_single_shot():
     kp, vp, tok_k, tok_v = _make_prefill_case(rng, H, D, ps, T, pages)
     q = rng.randn(T, H, D).astype(np.float32)
     full = np.asarray(ragged_prefill_reference(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), _fuse(kp, vp),
         jnp.asarray(row), np.int32(0)))
     for splits in ([8, 8, 5], [16, 5], [8, 13]):
         start = 0
         rows = []
         for n in splits:
             rows.append(np.asarray(ragged_prefill_reference(
-                jnp.asarray(q[start:start + n]), jnp.asarray(kp),
-                jnp.asarray(vp), jnp.asarray(row), np.int32(start))))
+                jnp.asarray(q[start:start + n]), _fuse(kp, vp),
+                jnp.asarray(row), np.int32(start))))
             start += n
         np.testing.assert_allclose(np.concatenate(rows), full,
                                    rtol=2e-5, atol=2e-5)
@@ -274,14 +281,14 @@ def test_prefill_padded_rows_do_not_affect_real_rows():
                                       num_pages=12)
     q = rng.randn(Cpad, H, D).astype(np.float32)
     exact_ref = np.asarray(ragged_prefill_reference(
-        jnp.asarray(q[:n_real]), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q[:n_real]), _fuse(kp, vp),
         jnp.asarray(row), np.int32(q_start)))
     padded_ref = np.asarray(ragged_prefill_reference(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), _fuse(kp, vp),
         jnp.asarray(row), np.int32(q_start)))
     np.testing.assert_array_equal(padded_ref[:n_real], exact_ref)
     padded_pal = np.asarray(_ragged_prefill_pallas(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), _fuse(kp, vp),
         jnp.asarray(row), jnp.asarray([q_start, n_real], jnp.int32),
         D ** -0.5, True))
     np.testing.assert_allclose(padded_pal[:n_real], exact_ref,
@@ -313,7 +320,7 @@ def test_partial_chunk_unwritten_tail_nan_does_not_poison_live_rows():
                                       num_pages=12)
     q = rng.randn(Cpad, H, D).astype(np.float32)
     clean = np.asarray(ragged_prefill_reference(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), _fuse(kp, vp),
         jnp.asarray(row), np.int32(q_start), n_real=np.int32(n_real)))
     # poison the unwritten tail of the chunk's own page AND the whole
     # reserved (recycled) next page — positions >= q_start + n_real = 19
@@ -322,13 +329,13 @@ def test_partial_chunk_unwritten_tail_nan_does_not_poison_live_rows():
     kp2[pg, :, off:], vp2[pg, :, off:] = np.nan, np.nan
     kp2[9], vp2[9] = np.nan, np.nan
     dirty = np.asarray(ragged_prefill_reference(
-        jnp.asarray(q), jnp.asarray(kp2), jnp.asarray(vp2),
+        jnp.asarray(q), _fuse(kp2, vp2),
         jnp.asarray(row), np.int32(q_start), n_real=np.int32(n_real)))
     assert np.isfinite(dirty[:n_real]).all(), \
         "unwritten-tail NaN leaked into live chunk rows (reference)"
     np.testing.assert_array_equal(dirty[:n_real], clean[:n_real])
     pal = np.asarray(_ragged_prefill_pallas(
-        jnp.asarray(q), jnp.asarray(kp2), jnp.asarray(vp2),
+        jnp.asarray(q), _fuse(kp2, vp2),
         jnp.asarray(row), jnp.asarray([q_start, n_real], jnp.int32),
         D ** -0.5, True))
     assert np.isfinite(pal[:n_real]).all(), \
@@ -349,16 +356,16 @@ def test_prefill_null_page_contents_never_leak():
     kp, vp, _, _ = _make_prefill_case(rng, H, D, ps, T, pages)
     q = rng.randn(T, H, D).astype(np.float32)
     base = np.asarray(ragged_prefill_reference(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), _fuse(kp, vp),
         jnp.asarray(row), np.int32(0)))
     kp2, vp2 = kp.copy(), vp.copy()
     kp2[0], vp2[0] = -3e8, 3e8               # different poison
     again = np.asarray(ragged_prefill_reference(
-        jnp.asarray(q), jnp.asarray(kp2), jnp.asarray(vp2),
+        jnp.asarray(q), _fuse(kp2, vp2),
         jnp.asarray(row), np.int32(0)))
     np.testing.assert_array_equal(base, again)
     pal = np.asarray(_ragged_prefill_pallas(
-        jnp.asarray(q), jnp.asarray(kp2), jnp.asarray(vp2),
+        jnp.asarray(q), _fuse(kp2, vp2),
         jnp.asarray(row), jnp.asarray([0, T], jnp.int32),
         D ** -0.5, True))
     np.testing.assert_allclose(pal, base, rtol=2e-5, atol=2e-5)
@@ -375,15 +382,14 @@ def test_prefill_dispatcher_and_dtype():
     row[:2] = pages
     kp, vp, tok_k, tok_v = _make_prefill_case(rng, H, D, ps, T, pages)
     q = rng.randn(T, H, D).astype(np.float32)
-    out = ragged_prefill_attention(jnp.asarray(q), jnp.asarray(kp),
-                                   jnp.asarray(vp), jnp.asarray(row),
-                                   np.int32(0))
+    out = ragged_prefill_attention(jnp.asarray(q), _fuse(kp, vp),
+                                   jnp.asarray(row), np.int32(0))
     ref = _prefill_oracle(q, tok_k, tok_v, 0, T)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-5,
                                atol=2e-5)
     b16 = ragged_prefill_attention(
-        jnp.asarray(q, jnp.bfloat16), jnp.asarray(kp, jnp.bfloat16),
-        jnp.asarray(vp, jnp.bfloat16), jnp.asarray(row), np.int32(0))
+        jnp.asarray(q, jnp.bfloat16), _fuse(kp, vp).astype(jnp.bfloat16),
+        jnp.asarray(row), np.int32(0))
     assert b16.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(b16, np.float32), ref,
                                rtol=0.06, atol=0.06)
@@ -451,12 +457,12 @@ def test_verify_matches_dense_causal_oracle(L, W, impl):
     q = rng.randn(1, W, H, D).astype(np.float32)
     if impl == "pallas_interpret":
         got = _ragged_verify_pallas(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(q), _fuse(kp, vp),
             jnp.asarray(pt), jnp.asarray([L], jnp.int32),
             jnp.asarray([W - 1], jnp.int32), D ** -0.5, True)
     else:
         got = ragged_verify_reference(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(q), _fuse(kp, vp),
             jnp.asarray(pt), jnp.asarray([L], jnp.int32))
     ref = _verify_oracle(q[0], tok_k, tok_v, L)
     np.testing.assert_allclose(np.asarray(got)[0], ref, rtol=2e-5,
@@ -472,11 +478,12 @@ def test_verify_w1_matches_decode_reference_bitwise():
     lengths = [0, 1, 8, 9, 24]
     q, kp, vp, pt, ln = _make_case(rng, len(lengths), 2, 16, 8, 3,
                                    lengths)
-    dec = np.asarray(ragged_attention_reference(q, kp, vp, pt, ln))
-    ver = np.asarray(ragged_verify_reference(q[:, None], kp, vp, pt, ln))
+    dec = np.asarray(ragged_attention_reference(q, _fuse(kp, vp), pt, ln))
+    ver = np.asarray(ragged_verify_reference(q[:, None], _fuse(kp, vp), pt,
+                                             ln))
     np.testing.assert_array_equal(ver[:, 0], dec)
     pal = np.asarray(_ragged_verify_pallas(
-        q[:, None], kp, vp, pt, ln,
+        q[:, None], _fuse(kp, vp), pt, ln,
         jnp.zeros((len(lengths),), jnp.int32), 16 ** -0.5, True))
     for s, l in enumerate(lengths):      # dead rows: exactly zero
         if l == 0:
@@ -504,11 +511,11 @@ def test_verify_pallas_matches_jnp_reference_mixed_slots():
         pt[s, :n_live] = perm[used:used + n_live]
         used += n_live
     a = np.asarray(_ragged_verify_pallas(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), _fuse(kp, vp),
         jnp.asarray(pt), jnp.asarray(lengths),
         jnp.full((S,), W - 1, jnp.int32), 16 ** -0.5, True))
     b = np.asarray(ragged_verify_reference(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), _fuse(kp, vp),
         jnp.asarray(pt), jnp.asarray(lengths)))
     np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
 
@@ -527,7 +534,7 @@ def test_verify_causal_window_masking():
 
     def run(kparr, vparr):
         return np.asarray(_ragged_verify_pallas(
-            jnp.asarray(q), jnp.asarray(kparr), jnp.asarray(vparr),
+            jnp.asarray(q), _fuse(kparr, vparr),
             jnp.asarray(pt), jnp.asarray([L], jnp.int32),
             jnp.asarray([W - 1], jnp.int32), D ** -0.5, True))
 
@@ -550,10 +557,10 @@ def test_verify_causal_window_masking():
     np.testing.assert_array_equal(run(kp3, vp3), base)
     # jnp reference: same two properties
     refb = np.asarray(ragged_verify_reference(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), _fuse(kp, vp),
         jnp.asarray(pt), jnp.asarray([L], jnp.int32)))
     refg = np.asarray(ragged_verify_reference(
-        jnp.asarray(q), jnp.asarray(kp2), jnp.asarray(vp2),
+        jnp.asarray(q), _fuse(kp2, vp2),
         jnp.asarray(pt), jnp.asarray([L], jnp.int32)))
     np.testing.assert_array_equal(refg[0, :r0 + 1], refb[0, :r0 + 1])
 
@@ -580,12 +587,12 @@ def test_verify_nan_propagates():
     vp2 = vp.copy()
     vp2[pages[0], :, t % ps, :] = np.nan
     ref = np.asarray(ragged_verify_reference(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp2),
+        jnp.asarray(q), _fuse(kp, vp2),
         jnp.asarray(pt), jnp.asarray([L], jnp.int32)))
     assert np.isfinite(ref[0, 0]).all()   # row 0 cannot see position L
     assert np.isnan(ref[0, 1:]).all()
     pal = np.asarray(_ragged_verify_pallas(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp2),
+        jnp.asarray(q), _fuse(kp, vp2),
         jnp.asarray(pt), jnp.asarray([L], jnp.int32),
         jnp.asarray([W - 1], jnp.int32), D ** -0.5, True))
     assert np.isnan(pal[0, 1:]).all()     # seeing rows must be poisoned
@@ -609,14 +616,14 @@ def test_verify_unwritten_tail_nan_does_not_poison_consumed_rows():
     q = rng.randn(1, W, H, D).astype(np.float32)
     dl = 0                                # no drafts: only row 0 consumed
     ref = np.asarray(ragged_verify_reference(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(q), _fuse(kp, vp),
         jnp.asarray(pt), jnp.asarray([L], jnp.int32)))
     # poison every position past the written extent L - 1 + dl
     kp2, vp2 = kp.copy(), vp.copy()
     kp2[pages[0], :, L + dl:, :] = np.nan
     vp2[pages[0], :, L + dl:, :] = np.nan
     pal = np.asarray(_ragged_verify_pallas(
-        jnp.asarray(q), jnp.asarray(kp2), jnp.asarray(vp2),
+        jnp.asarray(q), _fuse(kp2, vp2),
         jnp.asarray(pt), jnp.asarray([L], jnp.int32),
         jnp.asarray([dl], jnp.int32), D ** -0.5, True))
     assert np.isfinite(pal[0, :dl + 1]).all(), \
@@ -629,7 +636,7 @@ def test_verify_unwritten_tail_nan_does_not_poison_consumed_rows():
     kp3[pages[0], :, L + dl:, :] = np.nan
     vp3[pages[0], :, L + dl:, :] = np.nan
     pal = np.asarray(_ragged_verify_pallas(
-        jnp.asarray(q), jnp.asarray(kp3), jnp.asarray(vp3),
+        jnp.asarray(q), _fuse(kp3, vp3),
         jnp.asarray(pt), jnp.asarray([L], jnp.int32),
         jnp.asarray([dl], jnp.int32), D ** -0.5, True))
     assert np.isfinite(pal[0, :dl + 1]).all()
@@ -647,16 +654,15 @@ def test_verify_dispatcher_and_dtype():
     pt[0, :2] = pages
     kp, vp, tok_k, tok_v = _make_verify_case(rng, H, D, ps, L, W, pages)
     q = rng.randn(1, W, H, D).astype(np.float32)
-    out = ragged_verify_attention(jnp.asarray(q), jnp.asarray(kp),
-                                  jnp.asarray(vp), jnp.asarray(pt),
+    out = ragged_verify_attention(jnp.asarray(q), _fuse(kp, vp),
+                                  jnp.asarray(pt),
                                   jnp.asarray([L], jnp.int32))
     ref = _verify_oracle(q[0], tok_k, tok_v, L)
     np.testing.assert_allclose(np.asarray(out)[0], ref, rtol=2e-5,
                                atol=2e-5)
     b16 = ragged_verify_attention(
-        jnp.asarray(q, jnp.bfloat16), jnp.asarray(kp, jnp.bfloat16),
-        jnp.asarray(vp, jnp.bfloat16), jnp.asarray(pt),
-        jnp.asarray([L], jnp.int32))
+        jnp.asarray(q, jnp.bfloat16), _fuse(kp, vp).astype(jnp.bfloat16),
+        jnp.asarray(pt), jnp.asarray([L], jnp.int32))
     assert b16.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(b16, np.float32)[0], ref,
                                rtol=0.06, atol=0.06)
@@ -680,7 +686,7 @@ def test_kernel_page_table_permutation_invariance():
             vp[p] = tokens_v[j * ps:(j + 1) * ps].transpose(1, 0, 2)
         pt = jnp.asarray(np.asarray([pages], np.int32))
         outs.append(np.asarray(_ragged_pallas(
-            q, jnp.asarray(kp), jnp.asarray(vp), pt,
+            q, _fuse(kp, vp), pt,
             jnp.asarray([12], np.int32), D ** -0.5, True)))
     np.testing.assert_array_equal(outs[0], outs[1])
 
@@ -701,13 +707,13 @@ def _quantize_pools(k_pool, v_pool):
     spec = kv_quant_spec("int8")
     P, H, ps, D = k_pool.shape
     pages = jnp.arange(P, dtype=jnp.int32)
-    rows_k = jnp.moveaxis(jnp.asarray(k_pool), 1, 2).reshape(P * ps, H, D)
-    rows_v = jnp.moveaxis(jnp.asarray(v_pool), 1, 2).reshape(P * ps, H, D)
-    kq = jnp.zeros((P, H, ps, D), spec.dtype)
-    vq = jnp.zeros((P, H, ps, D), spec.dtype)
-    kq, kam = write_prompt_kv_q(kq, jnp.zeros((P,)), rows_k, pages, spec)
-    vq, vam = write_prompt_kv_q(vq, jnp.zeros((P,)), rows_v, pages, spec)
-    return kq, vq, page_scales(kam, spec), page_scales(vam, spec), spec
+    rows = jnp.moveaxis(_fuse(k_pool, v_pool), 1, 2).reshape(P * ps, H,
+                                                             2 * D)
+    pool, kam, vam = write_prompt_kv_q(
+        jnp.zeros((P, H, ps, 2 * D), spec.dtype), jnp.zeros((P,)),
+        jnp.zeros((P,)), rows, pages, spec)
+    return (pool[..., :D], pool[..., D:], page_scales(kam, spec),
+            page_scales(vam, spec), spec)
 
 
 def _quant_tol(k_pool, v_pool):
@@ -724,9 +730,9 @@ def test_quantized_decode_matches_f32_oracle(lengths):
     rng = np.random.RandomState(31)
     q, k_pool, v_pool, pt, ln = _make_case(rng, 5, 2, 8, 8, 4, lengths)
     kq, vq, ks, vs, _ = _quantize_pools(k_pool, v_pool)
-    oracle = np.asarray(ragged_attention_reference(q, k_pool, v_pool,
+    oracle = np.asarray(ragged_attention_reference(q, _fuse(k_pool, v_pool),
                                                    pt, ln))
-    got = np.asarray(ragged_attention_reference(q, kq, vq, pt, ln,
+    got = np.asarray(ragged_attention_reference(q, _fuse(kq, vq), pt, ln,
                                                 k_scale=ks, v_scale=vs))
     assert np.abs(got - oracle).max() <= _quant_tol(k_pool, v_pool)
     # the masked-row contract survives quantization: length-0 slots
@@ -741,21 +747,18 @@ def test_quantized_decode_pallas_interpret_matches_reference():
     jnp gather-dequant reference to float rounding — the same
     cross-backend contract as the unquantized kernel, at the quantized
     operand dtypes."""
-    from incubator_mxnet_tpu.ops.ragged_attention import _ragged_pallas_q
     rng = np.random.RandomState(32)
     q, k_pool, v_pool, pt, ln = _make_case(rng, 4, 2, 8, 8, 4,
                                            [0, 5, 16, 27])
     kq, vq, ks, vs, _ = _quantize_pools(k_pool, v_pool)
-    ref = np.asarray(ragged_attention_reference(q, kq, vq, pt, ln,
+    ref = np.asarray(ragged_attention_reference(q, _fuse(kq, vq), pt, ln,
                                                 k_scale=ks, v_scale=vs))
-    got = np.asarray(_ragged_pallas_q(q, kq, vq, pt, ln, ks, vs,
-                                      8 ** -0.5, True))
+    got = np.asarray(_ragged_pallas(q, _fuse(kq, vq), pt, ln, 8 ** -0.5,
+                                    True, ks, vs))
     np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
 
 
 def test_quantized_prefill_matches_f32_oracle_and_kernel():
-    from incubator_mxnet_tpu.ops.ragged_attention import \
-        _ragged_prefill_pallas_q
     rng = np.random.RandomState(33)
     _, k_pool, v_pool, pt, _ = _make_case(rng, 1, 2, 8, 8, 4, [32])
     kq, vq, ks, vs, _ = _quantize_pools(k_pool, v_pool)
@@ -763,21 +766,19 @@ def test_quantized_prefill_matches_f32_oracle_and_kernel():
     qc = jnp.asarray(rng.randn(C, 2, 8).astype(np.float32))
     row = pt[0]
     oracle = np.asarray(ragged_prefill_reference(
-        qc, k_pool, v_pool, row, jnp.int32(16), n_real=6))
+        qc, _fuse(k_pool, v_pool), row, jnp.int32(16), n_real=6))
     got = np.asarray(ragged_prefill_reference(
-        qc, kq, vq, row, jnp.int32(16), n_real=6, k_scale=ks,
+        qc, _fuse(kq, vq), row, jnp.int32(16), n_real=6, k_scale=ks,
         v_scale=vs))
     assert np.abs(got[:6] - oracle[:6]).max() <= \
         _quant_tol(k_pool, v_pool)
-    kern = np.asarray(_ragged_prefill_pallas_q(
-        qc, kq, vq, row, jnp.asarray([16, 6], dtype=jnp.int32), ks, vs,
-        8 ** -0.5, True))
+    kern = np.asarray(_ragged_prefill_pallas(
+        qc, _fuse(kq, vq), row, jnp.asarray([16, 6], dtype=jnp.int32),
+        8 ** -0.5, True, ks, vs))
     np.testing.assert_allclose(kern[:6], got[:6], rtol=2e-5, atol=2e-5)
 
 
 def test_quantized_verify_matches_f32_oracle_and_kernel():
-    from incubator_mxnet_tpu.ops.ragged_attention import \
-        _ragged_verify_pallas_q
     rng = np.random.RandomState(34)
     _, k_pool, v_pool, pt, _ = _make_case(rng, 3, 2, 8, 8, 4,
                                           [5, 17, 0])
@@ -786,14 +787,14 @@ def test_quantized_verify_matches_f32_oracle_and_kernel():
     qv = jnp.asarray(rng.randn(3, W, 2, 8).astype(np.float32))
     ln = jnp.asarray(np.array([3, 9, 0], np.int32))
     dl = jnp.asarray(np.array([2, 2, 0], np.int32))
-    oracle = np.asarray(ragged_verify_reference(qv, k_pool, v_pool,
+    oracle = np.asarray(ragged_verify_reference(qv, _fuse(k_pool, v_pool),
                                                 pt, ln))
-    got = np.asarray(ragged_verify_reference(qv, kq, vq, pt, ln,
+    got = np.asarray(ragged_verify_reference(qv, _fuse(kq, vq), pt, ln,
                                              k_scale=ks, v_scale=vs))
     assert np.abs(got - oracle).max() <= _quant_tol(k_pool, v_pool)
     np.testing.assert_array_equal(got[2], 0.0)    # dead slot stays zero
-    kern = np.asarray(_ragged_verify_pallas_q(qv, kq, vq, pt, ln, dl,
-                                              ks, vs, 8 ** -0.5, True))
+    kern = np.asarray(_ragged_verify_pallas(qv, _fuse(kq, vq), pt, ln, dl,
+                                            8 ** -0.5, True, ks, vs))
     # consumed rows (<= dl) must match; later rows are contractually
     # discarded by the engine
     for s in range(3):
@@ -812,11 +813,68 @@ def test_poisoned_page_scale_propagates_and_isolates():
                                            [16, 16, 8])
     kq, vq, ks, vs, _ = _quantize_pools(k_pool, v_pool)
     clean = np.asarray(ragged_attention_reference(
-        q, kq, vq, pt, ln, k_scale=ks, v_scale=vs))
+        q, _fuse(kq, vq), pt, ln, k_scale=ks, v_scale=vs))
     page = int(np.asarray(pt)[0, 0])              # slot 0's first page
     ks_bad = ks.at[page].set(jnp.nan)
     got = np.asarray(ragged_attention_reference(
-        q, kq, vq, pt, ln, k_scale=ks_bad, v_scale=vs))
+        q, _fuse(kq, vq), pt, ln, k_scale=ks_bad, v_scale=vs))
     assert np.isnan(got[0]).all()                 # poisoned slot visible
     np.testing.assert_array_equal(got[1], clean[1])
     np.testing.assert_array_equal(got[2], clean[2])
+
+
+# ------------------------------------------------------------------- #
+# the fused pool at every head size the engines run: keys | values on
+# 2 * D lanes (32 to 256), odd and even head counts, bf16 pages of 16
+# ------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("H", [3, 4])
+@pytest.mark.parametrize("D", [16, 32, 64, 128])
+def test_fused_pool_kernels_match_reference_at_head_sizes(D, H):
+    """The three kernels (interpreted) against the jnp references over
+    one bf16 pool of (P, H, 16, 2 * D): a dead slot emits exactly zero,
+    and the null page, poisoned with NaN in both lane halves, is never
+    read unmasked: the padded query's zero lanes meet the value half of
+    every tile, so a masked row that leaked would show as NaN."""
+    rng = np.random.RandomState(40 + D + H)
+    ps, max_pages, W = 16, 4, 3
+    lengths = [0, 1, 16, 17, 45]
+    S = len(lengths)
+    q, kp, vp, pt, ln = _make_case(rng, S, H, D, ps, max_pages, lengths,
+                                   num_pages=24)
+    pool = _fuse(kp, vp).astype(jnp.bfloat16).at[0].set(jnp.nan)
+    assert pool.shape == (24, H, ps, 2 * D)
+    q = q.astype(jnp.bfloat16)
+    tol = dict(rtol=3e-2, atol=3e-2)
+
+    got = np.asarray(_ragged_pallas(q, pool, pt, ln, D ** -0.5, True),
+                     np.float32)
+    ref = np.asarray(ragged_attention_reference(q, pool, pt, ln),
+                     np.float32)
+    assert got.shape == (S, H, D) and np.isfinite(got).all()
+    np.testing.assert_array_equal(got[0], 0.0)
+    np.testing.assert_allclose(got, ref, **tol)
+
+    # verify: rows 0..dl of each slot are consumed; the window's last
+    # positions are written (every table entry a slot reads is live)
+    qv = jnp.asarray(rng.randn(S, W, H, D), jnp.bfloat16)
+    lv = jnp.asarray([0, 1, 14, 17, 40], jnp.int32)
+    dl = jnp.asarray([0, 2, 2, 1, 2], jnp.int32)
+    got = np.asarray(_ragged_verify_pallas(qv, pool, pt, lv, dl,
+                                           D ** -0.5, True), np.float32)
+    ref = np.asarray(ragged_verify_reference(qv, pool, pt, lv),
+                     np.float32)
+    np.testing.assert_array_equal(got[0], 0.0)
+    for s in range(1, S):
+        d = int(dl[s])
+        np.testing.assert_allclose(got[s, :d + 1], ref[s, :d + 1], **tol)
+
+    # chunk prefill over the longest slot's row: 13 live rows of 16
+    qc = jnp.asarray(rng.randn(16, H, D), jnp.bfloat16)
+    got = np.asarray(_ragged_prefill_pallas(
+        qc, pool, pt[4], jnp.asarray([32, 13], jnp.int32), D ** -0.5,
+        True), np.float32)
+    ref = np.asarray(ragged_prefill_reference(
+        qc, pool, pt[4], jnp.int32(32), n_real=13), np.float32)
+    assert np.isfinite(got[:13]).all()
+    np.testing.assert_allclose(got[:13], ref[:13], **tol)

@@ -602,3 +602,95 @@ def test_prefix_churn_accounting_no_leak_no_double_grant(model):
         "pool never pressured the index — test is not exercising reclaim"
     assert eng.decode_trace_count == 1
     eng.audit_pages()
+
+
+# --------------------------------------------------------------------- #
+# the pool's layout: keys | values of a head side by side, one pool a
+# layer; the host format (tiers, capsules) keeps the two apart
+# --------------------------------------------------------------------- #
+
+def test_pool_layout_is_reported(model):
+    """``kv_page_shape`` is the counter that says the fused layout is in
+    force; a capsule's signature still names a key page's shape."""
+    eng = InferenceEngine(model, num_slots=2, page_size=8, max_len=64)
+    H, D = eng._H, eng._D
+    snap = eng.health_snapshot()
+    assert snap["kv_page_shape"] == (H, 8, 2 * D)
+    assert len(eng._kvpools) == model.num_layers
+    assert all(p.shape == (eng.num_pages, H, 8, 2 * D)
+               for p in eng._kvpools)
+    assert snap["kv_pool_bytes"] == \
+        model.num_layers * eng.num_pages * H * 8 * 2 * D * 4
+    assert eng.kv_wire_sig()[3] == (H, 8, D)
+
+
+def test_gathered_page_payload_is_the_host_format(model):
+    """A page's payload leaves the device as separate key and value
+    arrays of (H, ps, D), byte for byte the cache's contents: what the
+    model wrote (the dense forward's own K and V of that page) and what
+    ``_promote_page`` puts back, crc and all."""
+    from incubator_mxnet_tpu.serve.paged_kv import payload_crc
+    from incubator_mxnet_tpu.models.gpt import _qkv_heads
+    rng = np.random.RandomState(41)
+    prompt = rng.randint(0, 64, size=(17,)).astype(np.int32)
+    eng = InferenceEngine(model, num_slots=2, page_size=8, max_len=64)
+    eng.submit(Request(prompt, max_new_tokens=8))
+    eng.step()                               # prefill: 3 pages written
+    slot, = [i for i, sl in enumerate(eng._slots) if sl is not None]
+    page = int(eng._page_table[slot, 0])
+    assert page != NULL_PAGE
+    k_pl, v_pl, kamax, vamax = eng.gather_page(page)
+    H, D = eng._H, eng._D
+    assert kamax is None and vamax is None
+    assert len(k_pl) == len(v_pl) == model.num_layers
+    assert all(a.shape == (H, 8, D) and a.dtype == np.float32
+               for a in (*k_pl, *v_pl))
+    # layer 0's keys and values of the first page, from the model itself
+    x = model.word_embed(nd.array(prompt[None, :8], dtype="int32")) + \
+        model.position_embed(nd.array(np.arange(8)[None], dtype="int32"))
+    _, k, v = _qkv_heads(model.block0.attn, model.block0.ln1(x))
+    np.testing.assert_allclose(k_pl[0], np.asarray(k[0]).transpose(1, 0, 2),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(v_pl[0], np.asarray(v[0]).transpose(1, 0, 2),
+                               rtol=1e-5, atol=1e-6)
+    # in the pool the two sit side by side on the lanes
+    pool0 = np.asarray(eng._kvpools[0][page])
+    assert pool0[..., :D].tobytes() == k_pl[0].tobytes()
+    assert pool0[..., D:].tobytes() == v_pl[0].tobytes()
+    # promote into another page and gather again: the same bytes
+    dst = eng._alloc.alloc()
+    eng._promote_page(k_pl, v_pl, None, None, dst)
+    k2, v2, _, _ = eng.gather_page(dst)
+    assert [a.tobytes() for a in (*k2, *v2)] == \
+        [a.tobytes() for a in (*k_pl, *v_pl)]
+    assert payload_crc(k2, v2, None, None) == \
+        payload_crc(k_pl, v_pl, None, None)
+    eng._alloc.decref(dst)
+
+
+def test_chunk_prefill_writes_through_the_engine_modules_global(
+        model, monkeypatch):
+    """The chunk-prefill program reaches ``write_token_kv`` through
+    ``serve.engine``'s own global (the benchmark plants its
+    never-written-page fault by patching it there), once a layer, with
+    keys and values fused on the last axis."""
+    from incubator_mxnet_tpu.serve import engine as eng_mod
+    real, seen = eng_mod.write_token_kv, []
+
+    def spy(pool, new, pages, offsets):
+        seen.append((pool.shape, new.shape, pages.shape, offsets.shape))
+        return real(pool, new, pages, offsets)
+
+    monkeypatch.setattr(eng_mod, "write_token_kv", spy)
+    rng = np.random.RandomState(42)
+    prompt = rng.randint(0, 64, size=(19,)).astype(np.int32)
+    eng = InferenceEngine(model, num_slots=2, page_size=8, max_len=64,
+                          chunk_pages=1)
+    req = Request(prompt, max_new_tokens=3)
+    eng.run([req])
+    H, D = eng._H, eng._D
+    assert len(seen) == model.num_layers * len(eng.prefill_trace_counts)
+    assert all(s == ((eng.num_pages, H, 8, 2 * D), (8, H, 2 * D), (8,),
+                     (8,)) for s in seen), seen
+    np.testing.assert_array_equal(np.asarray(req.token_ids, np.int32),
+                                  _solo_reference(model, prompt, 3))
