@@ -18,8 +18,9 @@ nothing is caught and downgraded:
              1 compile step + 5 steps; the compiled step holds the Mosaic
              attention forward AND backward custom calls
   3 serve    ``InferenceEngine`` behind ``ServeFrontend``, 8 HTTP requests;
-             the compiled decode and chunk-prefill programs hold the
-             ragged Mosaic custom calls
+             the compiled decode and chunk-prefill programs (the model's
+             ``cached_forward`` under the engine's paged ``attend``) hold
+             the ragged Mosaic custom calls
 
 The last-but-one line of stdout is one JSON object with every leg's
 verdict and its smoke timings (compile seconds and the rest kept apart —
@@ -634,8 +635,8 @@ def leg_serve(ctx):
             check(plats == {"tpu"}, f"{what} lives on {plats}")
 
     # printed, not gated: bf16 argmax ties on seeded random weights can
-    # flip between the paged and the dense-cache decode (leg 1 is the
-    # numeric gate)
+    # flip between the paged and the dense-cache decode, two callers of
+    # the one GPTModel.cached_forward (leg 1 is the numeric gate)
     name, _, _, tokens = done[0]
     prompt = asked[name]["prompt"]
     ref = gpt_mod.cached_generate(

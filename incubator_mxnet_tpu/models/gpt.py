@@ -215,6 +215,38 @@ class GPTModel(HybridBlock):
             logits = constrain(logits, ("dp", "fsdp"), seq_ax, "tp")
         return logits
 
+    def kv_geometry(self):
+        """(num_layers, heads, head_dim): what a cache has to hold of a
+        position."""
+        attn = self.block0.attn
+        return self.num_layers, attn._heads, attn._units // attn._heads
+
+    def cached_forward(self, ids, pos, attend, last_row=None):
+        """Inference forward of tokens ``ids`` (B, T) at positions
+        ``pos`` (B, T) against a cache the caller keeps: the one layer
+        loop of cached inference (the dense buffers of
+        ``decode_forward``, the page pools of serve/engine.py). The
+        model owns its math; ``attend(i, q, k, v)`` owns where layer
+        ``i``'s keys and values are kept and how they are read: per-head
+        (B, T, H, D) arrays in, the attention output (B, T, H, D) in
+        ``q``'s type out. ``last_row`` (a traced index) keeps that one
+        row before the head. Returns logits (B, T or 1, vocab), f32.
+        No dropout: call it outside training mode."""
+        B, T = ids.shape
+        x = self.word_embed(NDArray(ids)) + self.position_embed(NDArray(pos))
+        if self._dtype != "float32":
+            x = x.astype(self._dtype)
+        for i in range(self.num_layers):
+            blk = getattr(self, f"block{i}")
+            q, k, v = _qkv_heads(blk.attn, blk.ln1(x))
+            out = attend(i, q, k, v)
+            x = x + blk.attn.proj(NDArray(out.reshape(B, T, self._units)))
+            x = x + _mlp(blk, x)
+        if last_row is not None:
+            x = NDArray(lax.dynamic_slice(
+                x._data, (0, last_row, 0), (B, 1, self._units)))
+        return _lm_head(self, x)._data
+
 
 def lm_loss(model: GPTModel, input_ids, labels, weights=None):
     """Next-token cross entropy, shaped for SPMDTrainer.forward_loss.
@@ -363,9 +395,8 @@ def gpt_small(**kwargs) -> GPTModel:
 
 def _qkv_heads(attn: CausalSelfAttention, x):
     """Project and split x (B, Tin, units) into per-head q, k, v jnp
-    arrays shaped (B, Tin, H, D). Shared by the dense KV-cache decode
-    path below and the paged-KV serving engine (serve/engine.py) so the
-    projection/split numerics cannot drift between the two caches."""
+    arrays shaped (B, Tin, H, D): ``GPTModel.cached_forward``'s
+    projection, one for every cache."""
     B, Tin = x.shape[0], x.shape[1]
     H, D = attn._heads, attn._units // attn._heads
     qkv = attn.qkv(x).reshape((B, Tin, 3, H, D))
@@ -380,7 +411,7 @@ def _qkv_heads(attn: CausalSelfAttention, x):
 
 def _mlp(blk: GPTBlock, x):
     """The decode-path FFN half of a block: ln2 → ffn_in → exact gelu →
-    ffn_out (no dropout — inference only). Shared with serve/engine.py."""
+    ffn_out (no dropout — inference only)."""
     return blk.ffn_out(NDArray(jax.nn.gelu(
         blk.ffn_in(blk.ln2(x))._data, approximate=False)))
 
@@ -389,57 +420,21 @@ def _lm_head(model: GPTModel, x):
     """Final norm + tied vocab projection for the decode paths: cast to
     f32 BEFORE ``ln_f`` (norming bf16 then casting would feed
     bf16-rounded activations into the vocab projection and break token
-    parity with the training/greedy path — see ``decode_forward``).
-    Shared by the dense KV-cache decode below and every serving-engine
-    program (prefill, chunk, K-wide speculative verify) so head
-    numerics cannot drift between the caches or between verify
-    positions. x: (B, T, units) NDArray → (B, T, vocab) NDArray."""
+    parity with the training/greedy path). x: (B, T, units) NDArray →
+    (B, T, vocab) NDArray."""
     x = model.ln_f(x.astype("float32"))
     embed_w = model.word_embed.weight.data()
     return x._op("dot", embed_w, transpose_b=True)
 
 
-def _attn_decode(attn: CausalSelfAttention, x, k_buf, v_buf, start_pos):
-    """Run attention for positions [start_pos, start_pos+Tin) against the
-    cache. x: (B, Tin, units); k_buf/v_buf: (B, Tmax, H, D) jnp arrays.
-    Returns (out (B, Tin, units), k_buf, v_buf)."""
-    B, Tin = x.shape[0], x.shape[1]
-    H, D = attn._heads, attn._units // attn._heads
-    Tmax = k_buf.shape[1]
-    q, k, v = _qkv_heads(attn, x)
-    k_buf = lax.dynamic_update_slice(k_buf, k.astype(k_buf.dtype),
-                                     (0, start_pos, 0, 0))
-    v_buf = lax.dynamic_update_slice(v_buf, v.astype(v_buf.dtype),
-                                     (0, start_pos, 0, 0))
-    # causal mask against GLOBAL cache positions (static shapes: iota);
-    # attention itself reuses the shared sdpa op so masking/softmax
-    # numerics stay identical to the training path
-    from ..ops.attention import scaled_dot_product_attention as _sdpa
-    pos_q = start_pos + lax.broadcasted_iota(jnp.int32, (Tin, Tmax), 0)
-    pos_k = lax.broadcasted_iota(jnp.int32, (Tin, Tmax), 1)
-    mask = (pos_k <= pos_q)[None, None]            # (1, 1, Tin, Tmax)
-    out = _sdpa(q, k_buf.astype(q.dtype), v_buf.astype(q.dtype),
-                mask=mask)
-    out = NDArray(out.reshape(B, Tin, attn._units))
-    return attn.proj(out), k_buf, v_buf
-
-
-def _block_decode(blk: GPTBlock, x, k_buf, v_buf, start_pos):
-    h, k_buf, v_buf = _attn_decode(blk.attn, blk.ln1(x), k_buf, v_buf,
-                                   start_pos)
-    x = x + h
-    return x + _mlp(blk, x), k_buf, v_buf
-
-
 def init_kv_cache(model: GPTModel, batch_size: int, max_len=None,
                   dtype=None):
     """Fresh (k, v) cache buffers for every layer."""
-    H = model.block0.attn._heads
-    D = model._units // H
+    L, H, D = model.kv_geometry()
     Tmax = int(max_len or model.max_length)
     dt = jnp.dtype(dtype) if dtype else jnp.dtype(model._dtype)
     mk = lambda: jnp.zeros((batch_size, Tmax, H, D), dt)
-    return [(mk(), mk()) for _ in range(model.num_layers)]
+    return [(mk(), mk()) for _ in range(L)]
 
 
 def decode_forward(model: GPTModel, ids, caches, start_pos,
@@ -457,21 +452,32 @@ def decode_forward(model: GPTModel, ids, caches, start_pos,
         raise MXNetError(
             "decode_forward is inference-only (dropout is skipped); call "
             "it under autograd.predict_mode()")
+    from ..ops.attention import scaled_dot_product_attention as _sdpa
+    ids = ids._data if isinstance(ids, NDArray) else ids
     B, Tin = ids.shape
-    ids_nd = ids if isinstance(ids, NDArray) else NDArray(ids)
-    pos = NDArray(start_pos + lax.broadcasted_iota(jnp.int32, (B, Tin), 1))
-    x = model.word_embed(ids_nd) + model.position_embed(pos)
-    if model._dtype != "float32":
-        x = x.astype(model._dtype)
-    new_caches = []
-    for i in range(model.num_layers):
-        blk = getattr(model, f"block{i}")
+    Tmax = caches[0][0].shape[1]
+    # causal mask against GLOBAL cache positions (static shapes: iota);
+    # attention itself reuses the shared sdpa op so masking/softmax
+    # numerics stay identical to the training path
+    pos_q = start_pos + lax.broadcasted_iota(jnp.int32, (Tin, Tmax), 0)
+    pos_k = lax.broadcasted_iota(jnp.int32, (Tin, Tmax), 1)
+    mask = (pos_k <= pos_q)[None, None]            # (1, 1, Tin, Tmax)
+    new_caches = list(caches)
+
+    def attend(i, q, k, v):
         k_buf, v_buf = caches[i]
-        x, k_buf, v_buf = _block_decode(blk, x, k_buf, v_buf, start_pos)
-        new_caches.append((k_buf, v_buf))
-    if last_only:
-        x = x._op("slice_axis", axis=1, begin=Tin - 1, end=Tin)
-    return _lm_head(model, x), new_caches
+        k_buf = lax.dynamic_update_slice(k_buf, k.astype(k_buf.dtype),
+                                         (0, start_pos, 0, 0))
+        v_buf = lax.dynamic_update_slice(v_buf, v.astype(v_buf.dtype),
+                                         (0, start_pos, 0, 0))
+        new_caches[i] = (k_buf, v_buf)
+        return _sdpa(q, k_buf.astype(q.dtype), v_buf.astype(q.dtype),
+                     mask=mask)
+
+    pos = start_pos + lax.broadcasted_iota(jnp.int32, (B, Tin), 1)
+    logits = model.cached_forward(ids, pos, attend,
+                                  last_row=Tin - 1 if last_only else None)
+    return NDArray(logits), new_caches
 
 
 def cached_generate(model: GPTModel, prompt_ids, max_new_tokens=32,

@@ -89,6 +89,7 @@ multi-tenant decode.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import math
@@ -101,7 +102,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .. import autograd
 from ..base import MXNetError
+from ..gluon.block import _hybrid_trace_scope
 from ..ndarray import NDArray
 from ..ops.attention import scaled_dot_product_attention as _sdpa
 from ..ops.ragged_attention import (ragged_attention_reference,
@@ -284,9 +287,11 @@ def _next_pow2(n: int) -> int:
 
 
 class InferenceEngine:
-    """Fixed-slot continuous-batching decode over a GPT-style model
-    (models/gpt.py — anything exposing word_embed / position_embed /
-    blockN(ln1, attn.{qkv,proj}, ln2, ffn_*) / ln_f and tied LM head).
+    """Fixed-slot continuous-batching decode over a model that brings
+    ``kv_geometry()`` and ``cached_forward(ids, pos, attend, last_row)``
+    (models/gpt.py::GPTModel; docs/SERVING.md "What the engine asks of
+    a model"): the model owns its math, the engine's programs own
+    where keys and values are kept and how they are read.
 
     ``num_pages`` defaults to the worst case (every slot at max_len) so
     admission never stalls; shrink it to trade admission concurrency
@@ -439,8 +444,7 @@ class InferenceEngine:
                 f"({self.chunk_pages * self.page_size} tokens) — a long "
                 f"prompt could never make progress")
 
-        H = model.block0.attn._heads
-        D = model._units // H
+        L, H, D = model.kv_geometry()
         self._H, self._D = H, D
         # quantized KV pools (docs/SERVING.md "Quantized KV cache"):
         # int8/fp8 page payload + per-page symmetric scales. The amax
@@ -458,13 +462,13 @@ class InferenceEngine:
         # one (num_pages, H, page_size, 2 * D) pool a layer: a head's
         # keys | values side by side on the lanes (serve/paged_kv.py)
         self._kvpools = tuple(init_kv_pools(
-            model.num_layers, self.num_pages, H, self.page_size, D,
+            L, self.num_pages, H, self.page_size, D,
             self._dtype, quant=self._kv_spec))
         if self._kv_spec is not None:
             self._kamax = tuple(np.zeros((self.num_pages,), np.float32)
-                                for _ in range(model.num_layers))
+                                for _ in range(L))
             self._vamax = tuple(np.zeros((self.num_pages,), np.float32)
-                                for _ in range(model.num_layers))
+                                for _ in range(L))
         else:
             self._kamax = self._vamax = ()
 
@@ -717,25 +721,42 @@ class InferenceEngine:
             axis=-1)
         return jnp.where(temp > 0, samp, greedy).astype(jnp.int32)
 
-    def _bind_params(self, param_vals):
-        """Context manager: point every model Parameter at the traced
-        ``param_vals`` for the duration of the model math (the
-        SPMDTrainer pure_loss idiom), restoring the eager arrays after.
-        This is what makes weights DATA to the compiled programs."""
-        import contextlib
-
-        @contextlib.contextmanager
-        def scope():
-            saved = [p._data for p in self._eng_params]
-            for p, v in zip(self._eng_params, param_vals):
-                p._data = NDArray(v)
-            try:
+    @contextlib.contextmanager
+    def _model_scope(self, param_vals):
+        """What a program runs the model's math inside: every model
+        Parameter points at the traced ``param_vals`` (the SPMDTrainer
+        pure_loss idiom; this is what makes weights DATA to the compiled
+        programs, the eager arrays come back after), under the hybrid
+        trace and in inference mode."""
+        saved = [p._data for p in self._eng_params]
+        for p, v in zip(self._eng_params, param_vals):
+            p._data = NDArray(v)
+        try:
+            with _hybrid_trace_scope(), \
+                    autograd._ModeScope(recording=False, training=False):
                 yield
-            finally:
-                for p, s in zip(self._eng_params, saved):
-                    p._data = s
+        finally:
+            for p, s in zip(self._eng_params, saved):
+                p._data = s
 
-        return scope()
+    def _write_kv(self, write, write_q, pools, kamax, vamax, i, k, v,
+                  *where):
+        """Write layer ``i``'s new keys | values (joined on the lanes,
+        the pool's format) at ``where`` by the program's own write
+        function, or its quantizing twin where the pools hold codes;
+        ``pools`` / ``kamax`` / ``vamax`` are the program's lists,
+        updated in place. Returns what reading the pool back takes: the
+        key and value scales (None unquantized) and the type attention
+        runs in."""
+        kv = jnp.concatenate([k, v], axis=-1)
+        spec = self._kv_spec
+        if spec is None:
+            pools[i] = write(pools[i], kv, *where)
+            return None, None, pools[i].dtype
+        pools[i], kamax[i], vamax[i] = write_q(
+            pools[i], kamax[i], vamax[i], kv, *where, spec)
+        return (page_scales(kamax[i], spec), page_scales(vamax[i], spec),
+                self._dtype)
 
     def _ragged_attn(self, q, pool, page_table, lengths, ks=None,
                      vs=None):
@@ -906,11 +927,6 @@ class InferenceEngine:
             self.decode_trace_count += 1
         else:
             self.verify_trace_count += 1
-        from ..gluon.block import _hybrid_trace_scope
-        from .. import autograd
-        from ..models.gpt import _lm_head, _mlp, _qkv_heads
-
-        model = self.model
         S, ps = self.num_slots, self.page_size
         W = tokens.shape[1]
         act = lengths > 0
@@ -929,52 +945,27 @@ class InferenceEngine:
         write_off = pos % ps
         # padded columns of a nearly-finished slot can index past the
         # table — clamp for the (masked, discarded) embedding lookup
-        emb_pos = jnp.minimum(pos, model.max_length - 1)
+        emb_pos = jnp.minimum(pos, self.model.max_length - 1)
         eff_len = jnp.where(act, lengths + 1, 0)
+        new_pools = list(pools)
+        new_ka, new_va = list(kamax), list(vamax)
 
-        with self._bind_params(param_vals), _hybrid_trace_scope(), \
-                autograd._ModeScope(recording=False, training=False):
-            x = model.word_embed(NDArray(tokens)) + \
-                model.position_embed(NDArray(emb_pos))
-            if model._dtype != "float32":
-                x = x.astype(model._dtype)
-            new_pools = []
-            new_ka, new_va = [], []
-            spec = self._kv_spec
-            for i in range(model.num_layers):
-                blk = getattr(model, f"block{i}")
-                q, k, v = _qkv_heads(blk.attn, blk.ln1(x))  # (S,W,H,D)
-                kv = jnp.concatenate([k, v], axis=-1)       # (S,W,H,2D)
-                if spec is None:
-                    pool = write_block_kv(pools[i], kv, write_page,
-                                          write_off)
-                    ks = vs = None
-                    adt = pool.dtype
-                else:
-                    # quantize-at-write: the page's scales grow with
-                    # the window's amax and existing codes requantize
-                    # in the same scatter — pure traced data, no new
-                    # programs (trace counts stay asserted at 1)
-                    pool, ka, va = write_block_kv_q(
-                        pools[i], kamax[i], vamax[i], kv, write_page,
-                        write_off, spec)
-                    new_ka.append(ka)
-                    new_va.append(va)
-                    ks = page_scales(ka, spec)
-                    vs = page_scales(va, spec)
-                    adt = self._dtype
-                new_pools.append(pool)
-                out = self._verify_attn(q.astype(adt), pool,
-                                        page_table, eff_len, draft_len,
-                                        ks, vs)
-                out = NDArray(out.astype(q.dtype).reshape(
-                    S, W, model._units))
-                x = x + blk.attn.proj(out)
-                x = x + _mlp(blk, x)
-            # shared head: f32 cast BEFORE ln_f + tied vocab projection
-            # (models/gpt.py::_lm_head — token parity with
-            # decode_forward / the training path)
-            logits = _lm_head(model, x)._data        # (S, W, V)
+        def attend(i, q, k, v):              # (S, W, H, D)
+            # quantize-at-write: the page's scales grow with the
+            # window's amax and existing codes requantize in the same
+            # scatter — pure traced data, no new programs (trace counts
+            # stay asserted at 1)
+            ks, vs, adt = self._write_kv(
+                write_block_kv, write_block_kv_q, new_pools, new_ka,
+                new_va, i, k, v, write_page, write_off)
+            out = self._verify_attn(q.astype(adt), new_pools[i],
+                                    page_table, eff_len, draft_len,
+                                    ks, vs)
+            return out.astype(q.dtype)
+
+        with self._model_scope(param_vals):
+            logits = self.model.cached_forward(tokens, emb_pos,
+                                               attend)    # (S, W, V)
         emitted, n_emit = self._accept_emit(
             logits, tokens, draft_len, temps, slot_keys, pos, act,
             top_k=top_k, top_p=top_p, rep_pen=rep_pen,
@@ -1009,48 +1000,26 @@ class InferenceEngine:
         key_tc = ("dense", ids.shape[1])
         self.prefill_trace_counts[key_tc] = \
             self.prefill_trace_counts.get(key_tc, 0) + 1
-        from jax import lax
-        from ..gluon.block import _hybrid_trace_scope
-        from .. import autograd
-        from ..models.gpt import _mlp, _qkv_heads
-
-        model = self.model
         Tpad = ids.shape[1]
-        with self._bind_params(param_vals), _hybrid_trace_scope(), \
-                autograd._ModeScope(recording=False, training=False):
-            pos = NDArray(lax.broadcasted_iota(jnp.int32, (1, Tpad), 1))
-            x = model.word_embed(NDArray(ids)) + model.position_embed(pos)
-            if model._dtype != "float32":
-                x = x.astype(model._dtype)
-            pos_q = lax.broadcasted_iota(jnp.int32, (Tpad, Tpad), 0)
-            pos_k = lax.broadcasted_iota(jnp.int32, (Tpad, Tpad), 1)
-            mask = ((pos_k <= pos_q) & (pos_k < t0))[None, None]
-            new_pools = list(pools)
-            new_ka, new_va = list(kamax), list(vamax)
-            spec = self._kv_spec
-            for i in range(model.num_layers):
-                blk = getattr(model, f"block{i}")
-                q, k, v = _qkv_heads(blk.attn, blk.ln1(x))  # (1,Tpad,H,D)
-                kv = jnp.concatenate([k[0], v[0]], axis=-1)
-                if spec is None:
-                    new_pools[i] = write_prompt_kv(new_pools[i], kv,
-                                                   pages)
-                else:
-                    # quantize the prompt's pages at FRESH per-page
-                    # scales; the prompt's own attention below runs on
-                    # the exact pre-quantization K/V (only future
-                    # paged reads pay the quantization error)
-                    new_pools[i], new_ka[i], new_va[i] = \
-                        write_prompt_kv_q(new_pools[i], new_ka[i],
-                                          new_va[i], kv, pages, spec)
-                out = _sdpa(q, k, v, mask=mask)
-                x = x + blk.attn.proj(NDArray(out.reshape(
-                    1, Tpad, model._units)))
-                x = x + _mlp(blk, x)
-            last = lax.dynamic_slice(
-                x._data, (0, t0 - 1, 0), (1, 1, model._units))
-            from ..models.gpt import _lm_head
-            logits = _lm_head(model, NDArray(last))._data[:, 0]
+        pos = lax.broadcasted_iota(jnp.int32, (1, Tpad), 1)
+        pos_q = lax.broadcasted_iota(jnp.int32, (Tpad, Tpad), 0)
+        pos_k = lax.broadcasted_iota(jnp.int32, (Tpad, Tpad), 1)
+        mask = ((pos_k <= pos_q) & (pos_k < t0))[None, None]
+        new_pools = list(pools)
+        new_ka, new_va = list(kamax), list(vamax)
+
+        def attend(i, q, k, v):              # (1, Tpad, H, D)
+            # quantized pools take the prompt's pages at FRESH per-page
+            # scales; the prompt's own attention runs on the exact
+            # pre-quantization K/V (only future paged reads pay the
+            # quantization error)
+            self._write_kv(write_prompt_kv, write_prompt_kv_q, new_pools,
+                           new_ka, new_va, i, k[0], v[0], pages)
+            return _sdpa(q, k, v, mask=mask)
+
+        with self._model_scope(param_vals):
+            logits = self.model.cached_forward(
+                ids, pos, attend, last_row=t0 - 1)[:, 0]
         # the first generated token occupies position t0: its draw is
         # keyed by fold_in(request_key, t0), the engine-wide convention
         tok = self._sample_one(logits[0], temp,
@@ -1080,56 +1049,29 @@ class InferenceEngine:
         key_tc = ("chunk", ids.shape[1])
         self.prefill_trace_counts[key_tc] = \
             self.prefill_trace_counts.get(key_tc, 0) + 1
-        from jax import lax
-        from ..gluon.block import _hybrid_trace_scope
-        from .. import autograd
-        from ..models.gpt import _mlp, _qkv_heads
-
-        model = self.model
         ps = self.page_size
         Cpad = ids.shape[1]
-        with self._bind_params(param_vals), _hybrid_trace_scope(), \
-                autograd._ModeScope(recording=False, training=False):
-            pos = start + lax.broadcasted_iota(jnp.int32, (1, Cpad), 1)
-            x = model.word_embed(NDArray(ids)) + \
-                model.position_embed(NDArray(pos))
-            if model._dtype != "float32":
-                x = x.astype(model._dtype)
-            live = lax.broadcasted_iota(jnp.int32, (Cpad,), 0) < n_real
-            page_idx = jnp.clip(pos[0] // ps, 0, self.max_pages - 1)
-            tok_pages = jnp.where(live, page_row[page_idx], NULL_PAGE)
-            tok_off = pos[0] % ps
-            new_pools = list(pools)
-            new_ka, new_va = list(kamax), list(vamax)
-            spec = self._kv_spec
-            for i in range(model.num_layers):
-                blk = getattr(model, f"block{i}")
-                q, k, v = _qkv_heads(blk.attn, blk.ln1(x))  # (1,Cpad,H,D)
-                kv = jnp.concatenate([k[0], v[0]], axis=-1)
-                if spec is None:
-                    # through this module's global: the benchmark's
-                    # never-written-page control patches it here
-                    new_pools[i] = write_token_kv(new_pools[i], kv,
-                                                  tok_pages, tok_off)
-                    ks = vs = None
-                    adt = new_pools[i].dtype
-                else:
-                    new_pools[i], new_ka[i], new_va[i] = \
-                        write_token_kv_q(new_pools[i], new_ka[i],
-                                         new_va[i], kv, tok_pages,
-                                         tok_off, spec)
-                    ks = page_scales(new_ka[i], spec)
-                    vs = page_scales(new_va[i], spec)
-                    adt = self._dtype
-                out = self._prefill_attn(q[0].astype(adt), new_pools[i],
-                                         page_row, start, n_real, ks, vs)
-                x = x + blk.attn.proj(NDArray(out.astype(q.dtype).reshape(
-                    1, Cpad, model._units)))
-                x = x + _mlp(blk, x)
-            last = lax.dynamic_slice(
-                x._data, (0, n_real - 1, 0), (1, 1, model._units))
-            from ..models.gpt import _lm_head
-            logits = _lm_head(model, NDArray(last))._data[:, 0]
+        pos = start + lax.broadcasted_iota(jnp.int32, (1, Cpad), 1)
+        live = lax.broadcasted_iota(jnp.int32, (Cpad,), 0) < n_real
+        page_idx = jnp.clip(pos[0] // ps, 0, self.max_pages - 1)
+        tok_pages = jnp.where(live, page_row[page_idx], NULL_PAGE)
+        tok_off = pos[0] % ps
+        new_pools = list(pools)
+        new_ka, new_va = list(kamax), list(vamax)
+
+        def attend(i, q, k, v):              # (1, Cpad, H, D)
+            # write_token_kv through this module's global: the
+            # benchmark's never-written-page control patches it here
+            ks, vs, adt = self._write_kv(
+                write_token_kv, write_token_kv_q, new_pools, new_ka,
+                new_va, i, k[0], v[0], tok_pages, tok_off)
+            out = self._prefill_attn(q[0].astype(adt), new_pools[i],
+                                     page_row, start, n_real, ks, vs)
+            return out.astype(q.dtype)[None]
+
+        with self._model_scope(param_vals):
+            logits = self.model.cached_forward(
+                ids, pos, attend, last_row=n_real - 1)[:, 0]
         # on the FINAL chunk start + n_real == t0, so the draw key
         # matches the dense prefill's exactly — chunked vs monolithic
         # prefill emit the identical first token even at temperature

@@ -237,24 +237,58 @@ def test_gpt_kv_cache_decode_matches_full_recompute():
     np.testing.assert_array_equal(b1, b2)
 
 
-def test_gpt_decode_forward_logits_match_full_forward():
+@pytest.mark.parametrize("caller", ["decode_forward", "cached_forward"])
+def test_gpt_decode_forward_logits_match_full_forward(caller):
     """Prefill logits from the KV-cache path must match the training
-    forward position-for-position (not just argmax parity)."""
+    forward position-for-position (not just argmax parity): through
+    ``decode_forward``, and through the model's seam itself under a
+    dense-buffer ``attend`` of the test's own, the prompt in two pieces
+    so that the second reads what the first wrote."""
+    import jax.numpy as jnp
     from incubator_mxnet_tpu.models import gpt as g
     from incubator_mxnet_tpu.gluon.block import _hybrid_trace_scope
+    from incubator_mxnet_tpu.ops.attention import \
+        scaled_dot_product_attention as sdpa
 
     mx.random.seed(2)
     m = g.gpt_mini(vocab_size=64, max_length=32)
     m.initialize()
     rng = np.random.RandomState(0)
     ids = nd.array(rng.randint(0, 64, (2, 16)), dtype="int32")
+    assert m.kv_geometry() == (2, 4, 32)
     with autograd.predict_mode():
         full = m(ids).asnumpy()                       # (2, 16, 64)
         caches = g.init_kv_cache(m, 2, max_len=16)
-        with _hybrid_trace_scope():
-            logits, _ = g.decode_forward(m, ids, caches, 0)
-    np.testing.assert_allclose(logits.asnumpy(), full, rtol=2e-4,
-                               atol=2e-5)
+        assert len(caches) == 2 and caches[0][0].shape == (2, 16, 4, 32)
+        if caller == "decode_forward":
+            with _hybrid_trace_scope():
+                logits, _ = g.decode_forward(m, ids, caches, 0)
+            logits = logits.asnumpy()
+        else:
+            bufs = [list(kv) for kv in caches]
+
+            def piece(lo, hi, last_row=None):
+                mask = (jnp.arange(16)[None] <=
+                        jnp.arange(lo, hi)[:, None])[None, None]
+
+                def attend(i, q, k, v):
+                    assert q.shape == k.shape == v.shape == \
+                        (2, hi - lo, 4, 32)
+                    bufs[i][0] = bufs[i][0].at[:, lo:hi].set(k)
+                    bufs[i][1] = bufs[i][1].at[:, lo:hi].set(v)
+                    return sdpa(q, bufs[i][0], bufs[i][1], mask=mask)
+
+                pos = jnp.broadcast_to(jnp.arange(lo, hi), (2, hi - lo))
+                with _hybrid_trace_scope():
+                    return np.asarray(m.cached_forward(
+                        ids._data[:, lo:hi], pos, attend,
+                        last_row=last_row))
+
+            logits = np.concatenate([piece(0, 10), piece(10, 16)], axis=1)
+            # last_row (a traced index in the programs) keeps that row
+            np.testing.assert_array_equal(
+                piece(10, 16, last_row=jnp.int32(5)), logits[:, -1:])
+    np.testing.assert_allclose(logits, full, rtol=2e-4, atol=2e-5)
 
 
 def test_bert_mlm_onehot_gather_is_exact_gather():

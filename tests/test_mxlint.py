@@ -165,6 +165,74 @@ def test_trace_pass_decorated_and_method_roots(tmp_path):
     assert "Engine._step_fn" in symbols
 
 
+def test_trace_pass_follows_a_closure_into_the_model_it_is_handed_to(
+        tmp_path):
+    """The serving programs' shape: a jitted engine method hands a
+    closure to a method of the model it holds. Both bodies run under
+    the trace, and so does a function the closure passes on by name;
+    ``self.n += 1`` in the program is a store into the object, not a
+    rebinding of the ``self`` the closure captures."""
+    files = {
+        "incubator_mxnet_tpu/models/seam.py": """
+            class Model:
+                def cached_forward(self, ids, attend):
+                    return attend(0, ids) + float(ids)
+        """,
+        "incubator_mxnet_tpu/serve/seam_engine.py": """
+            import jax
+
+
+            def write(pool, new):
+                return pool + int(new)
+
+
+            class Engine:
+                def __init__(self, model):
+                    self.model = model
+                    self.traces = 0
+                    self._step = jax.jit(self._step_fn)
+
+                def _put(self, write_fn, pool, new):
+                    return write_fn(pool, new)
+
+                def _step_fn(self, pool, ids):
+                    self.traces += 1
+                    self.traces += 1
+
+                    def attend(i, q):
+                        return self._put(write, pool, q) + bool(q)
+
+                    return self.model.cached_forward(ids, attend)
+        """,
+    }
+    found = _findings(tmp_path, files, rule="trace-host-leak")
+    assert {f.symbol for f in _active(found)} == {
+        "Model.cached_forward", "Engine._step_fn.attend", "write"}
+    assert not [f for f in found if "captures" in f.message]
+
+
+def test_serve_imports_no_private_name_of_models():
+    """serve/ asks a model for ``kv_geometry`` and ``cached_forward``
+    (docs/SERVING.md "What the engine asks of a model") and reads none
+    of its insides: no underscore name crosses from models/ into
+    serve/, which is what keeps the scheduler from growing back into
+    the model."""
+    import ast
+    serve = os.path.join(REPO_ROOT, "incubator_mxnet_tpu", "serve")
+    private = []
+    for name in sorted(os.listdir(serve)):
+        if not name.endswith(".py"):
+            continue
+        with open(os.path.join(serve, name)) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.ImportFrom) and \
+                    "models" in (node.module or "").split("."):
+                private += [f"{name}:{node.lineno} {a.name}"
+                            for a in node.names if a.name.startswith("_")]
+    assert not private, private
+
+
 # --------------------------------------------------------------------- #
 # pass 2: terminal-outcome (the PR-9 double-finish race, distilled)
 # --------------------------------------------------------------------- #

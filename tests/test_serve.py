@@ -48,6 +48,46 @@ def test_single_request_matches_cached_generate(model):
     assert eng.decode_trace_count == 1
 
 
+class _SeamOnly:
+    """Of a model, what the engine's constructor and the seam name
+    (docs/SERVING.md "What the engine asks of a model"); any other read
+    fails the test."""
+    _ASKED = {"kv_geometry", "cached_forward", "collect_params",
+              "vocab_size", "max_length", "_dtype"}
+
+    def __init__(self, model):
+        self._model = model
+
+    def __getattr__(self, name):
+        if name not in self._ASKED:
+            raise AssertionError(f"the engine read model.{name}")
+        return getattr(self._model, name)
+
+
+@pytest.mark.parametrize("engine_kw", [
+    {}, {"chunk_pages": 1}, {"kv_quant": "int8", "spec_k": 2}],
+    ids=["monolithic", "chunked", "int8-verify"])
+def test_engine_reads_a_model_through_its_seam_only(model, engine_kw):
+    """An engine over a proxy that hides the model's insides serves the
+    tokens of the engine over the model itself: the scheduler knows
+    slots, pages and sampling, and nothing of blocks, norms or heads."""
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(0, 64, size=(n,)).astype(np.int32)
+               for n in (5, 19, 11)]
+    served = []
+    for m in (model, _SeamOnly(model)):
+        eng = InferenceEngine(m, num_slots=2, page_size=8, max_len=64,
+                              **engine_kw)
+        reqs = [Request(p, max_new_tokens=6 + i, seed=i,
+                        temperature=0.0 if i < 2 else 0.7)
+                for i, p in enumerate(prompts)]
+        eng.run(reqs)
+        eng.audit_pages()
+        served.append([list(r.token_ids) for r in reqs])
+    assert served[0] == served[1]
+    assert all(len(t) == 6 + i for i, t in enumerate(served[1]))
+
+
 @pytest.mark.slow   # 13-21s (round-10 tier-1 budget repair); ci stage_unit runs it
 def test_mixed_occupancy_no_cross_contamination_and_slot_reuse(model):
     """5 ragged requests through 3 slots with staggered arrivals: every

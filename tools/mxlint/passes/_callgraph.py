@@ -4,9 +4,14 @@ Name-based, flow-insensitive resolution — deliberately the same
 fidelity as a reviewer reading the code: a call to a bare name binds to
 the nested/module function of that name (or the function it was
 imported from, project-wide); ``self.m(...)`` binds to method ``m`` of
-the enclosing class. Anything dynamic (getattr, dict-of-functions,
-higher-order args) is out of scope; the passes that ride on this are
-designed so a missed edge means a missed finding, never a false one.
+the enclosing class; ``self.held.m(...)`` binds to method ``m`` of the
+one project class that defines it (an engine calling the model it
+holds). A function NAMED as an argument of a call that resolved is
+reached with it (project code handed a closure runs it: the serving
+programs' ``attend``); what is handed to a library call is not
+followed. Anything more dynamic (getattr, dict-of-functions) is out of
+scope; the passes that ride on this are designed so a missed edge means
+a missed finding, never a false one.
 """
 
 from __future__ import annotations
@@ -39,6 +44,8 @@ class CallGraph:
         self.module_defs: Dict[str, Dict[str, List[ast.AST]]] = {}
         # (module, class, method) -> FunctionDef
         self.methods: Dict[Tuple[str, str, str], ast.AST] = {}
+        # method name -> every FunctionDef of that name, project-wide
+        self.methods_named: Dict[str, List[ast.AST]] = {}
         for unit in project.units:
             if unit.tree is None:
                 continue
@@ -55,6 +62,7 @@ class CallGraph:
                     mdefs.setdefault(node.name, []).append(node)
                 elif isinstance(par, ast.ClassDef):
                     self.methods[(unit.module, par.name, node.name)] = node
+                    self.methods_named.setdefault(node.name, []).append(node)
 
     # ------------------------------------------------------------------ #
     def _nested_lookup(self, name: str, from_node: ast.AST) \
@@ -111,6 +119,10 @@ class CallGraph:
             if d is None:
                 return []
             head, _, rest = d.partition(".")
+            if head == "self" and rest.count(".") == 1:
+                # a method of an object the instance holds
+                named = self.methods_named.get(func.attr, [])
+                return list(named) if len(named) == 1 else []
             # module-alias call: `import x.y as z; z.f(...)` or
             # `from . import sub; sub.f(...)`
             mod = unit.import_modules.get(head)
@@ -139,9 +151,12 @@ class CallGraph:
                 continue
             for sub in walk_own(node):
                 if isinstance(sub, ast.Call):
-                    for tgt in self.resolve_call(sub, unit, node):
-                        if id(tgt) not in seen:
-                            work.append(tgt)
+                    callees = self.resolve_call(sub, unit, node)
+                    work.extend(callees)
+                    for arg in sub.args if callees else ():
+                        if isinstance(arg, ast.Name):
+                            work.extend(self.resolve_name(arg.id, unit,
+                                                          node))
         return seen
 
 

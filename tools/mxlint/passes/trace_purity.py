@@ -295,14 +295,20 @@ class TracePurityPass:
         """Names the enclosing scope assigns more than once (its OWN
         statements — walk_own already excludes the traced function's
         body and other nested defs)."""
+        def stored(target: ast.AST) -> Set[str]:
+            # ``self.n += 1`` stores into the object, not the name
+            return {n.id for n in ast.walk(target)
+                    if isinstance(n, ast.Name)
+                    and isinstance(n.ctx, ast.Store)}
+
         counts: Dict[str, int] = {}
         for node in walk_own(encl):
             tgt_names: Set[str] = set()
             if isinstance(node, ast.Assign):
                 for t in node.targets:
-                    tgt_names.update(_names_in(t))
+                    tgt_names.update(stored(t))
             elif isinstance(node, (ast.AugAssign, ast.For)):
-                tgt_names.update(_names_in(node.target))
+                tgt_names.update(stored(node.target))
             for n in tgt_names:
                 counts[n] = counts.get(n, 0) + 1
         return {n for n, c in counts.items() if c >= 2}
