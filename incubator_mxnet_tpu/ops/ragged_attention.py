@@ -23,24 +23,42 @@ and its contents are garbage by construction — every read of it is
 masked by the slot's length.
 
 Kernel design (per /opt/skills/guides/pallas_guide.md):
-  - grid (S, max_pages) under a ``PrefetchScalarGridSpec``: the page
-    table and per-slot lengths are scalar-prefetched, so the K/V
-    BlockSpec index_map dereferences ``page_table[s, j]`` to DMA exactly
-    the page that grid step needs — the kernel never sees a gather.
-  - the last grid dimension is sequential on TPU, so the online-softmax
-    state (m, l, acc) carries across pages in VMEM scratch: init at
-    j == 0, accumulate per live page, finalize (acc / l, masked rows
-    zeroed) at j == max_pages - 1.
-  - DEAD PAGES COST NOTHING: ``pl.when(j * page_size < length)`` skips
-    the compute, and because every dead entry indexes the null page the
-    block index is unchanged between consecutive dead steps — Pallas
-    skips the re-DMA. A slot at length L pays for ceil(L / page_size)
-    pages, not max_pages.
-  - one decode query per slot: scores are (1, page_size) rows per head,
-    dot operands stay in the input dtype, accumulation is f32 via
-    ``preferred_element_type`` (same dtype discipline as the training
-    kernels). Decode attention is a prefix mask — the query IS position
-    ``length - 1`` — so no causal triangle is needed.
+  - grid (S,) under a ``PrefetchScalarGridSpec``: ONE PROGRAM A SLOT.
+    The page table and per-slot lengths are scalar-prefetched; the pool
+    operand stays in HBM as it lies (``memory_space=pl.ANY``) and the
+    program fetches its own pages, ``pool[page_table[s, j]]``, by
+    ``pltpu.make_async_copy`` — the kernel never sees a gather.
+  - the program WALKS ONLY WHAT IS LIVE: ``cdiv(length, G *
+    page_size)`` turns of a ``lax.fori_loop``, each over one BLOCK of
+    G pages laid one under another in a VMEM buffer; block b + 1 is
+    in flight into the buffer's other half while block b is consumed.
+    A slot at length L pays ceil(L / (G * page_size)) blocks; a slot
+    at length 0 pays a grid step and a store of zeros. (The grid used
+    to be (S, max_pages), one page a step, dead steps skipped by
+    ``pl.when``: on the chip the skipped steps were half of the
+    kernel's time at a tenth of the slots live; PERF.md, PR 35.)
+  - G = min(max(1, 128 // page_size), max_pages), read off the shapes:
+    8 pages of 16, so that a head's score row fills the 128 lanes and
+    the online-softmax state (m, l, acc in VMEM scratch) is updated
+    once a head a BLOCK, with a 128-deep ``p · kv`` contraction — not
+    once a head a 16-key page.
+  - inside the last live block the table's entries past the last live
+    page are fetched with the rest (null-page entries by the table's
+    contract) and SELECTED out with the tail of the partial page, once,
+    in the buffer, before any head reads it: what they hold, NaN
+    included, never matters.
+  - one decode query per slot, so a head's scores are ONE row: the
+    scores of a GROUP of heads (``_head_group``: all of them at a
+    decode or verify step) lie one under another and take one softmax
+    update together — 25 single-row heads fill four vregs where they
+    would half-fill 25, and the lane reductions and ``exp`` run four
+    times a block, not 25 (on the chip the live part of the decode
+    kernel fell from 19 to 5 us a layer a slot at a context of 586;
+    PERF.md, PR 35). Dot operands stay in the input dtype,
+    accumulation is f32 via ``preferred_element_type`` (same dtype
+    discipline as the training kernels). Decode attention is a prefix
+    mask — the query IS position ``length - 1`` — so no causal triangle
+    is needed.
   - NO LANE SLICE in the head loop: the query arrives zero-padded to
     2 * D lanes, so ``q_pad · kvᵀ`` is exactly ``q · kᵀ``; ``p · kv``
     yields ``[p·k | p·v]`` and the caller keeps lanes [D, 2 * D) of the
@@ -59,10 +77,11 @@ C consecutive prompt tokens of ONE slot (absolute positions
 ``q_start + i``) attends the slot's already-populated paged prefix plus
 the causal intra-chunk part — the chunk's own K/V is scattered into the
 pages first, so a single per-query prefix mask ``pos_k <= pos_q``
-covers both. Same kernel shape as decode (grid over the page axis,
-online-softmax scratch carried across pages, dead pages skipped via the
-repeated-null-page index trick), with C query rows per head instead of
-one; same jnp gather fallback as CPU path and oracle.
+covers both. The same walk as decode (``_walk_live_blocks``, the one
+body of the three kernels: one program over the slot's row, as many
+blocks as ``q_start + n_real`` keys fill), with C query rows per head
+instead of one; same jnp gather fallback as CPU path and oracle.
+``ragged_verify_attention`` is decode with W query rows a slot.
 """
 
 from __future__ import annotations
@@ -95,19 +114,37 @@ def _pad_lanes(q):
     return jnp.pad(q, [(0, 0)] * (q.ndim - 1) + [(0, q.shape[-1])])
 
 
-def _page_scale(scale_refs, page, lanes):
-    """(1, lanes) f32 inline-dequant scales of one quantized page: lanes
-    [0, D) by its key scale, lanes [D, 2 * D) by its value scale; None
-    for an unquantized pool (no scale refs). The (P,) per-page scale
-    arrays ride the SAME scalar-prefetch path as the page table: the
-    grid step that DMAs a page reads that page's scales from SMEM and
-    dequantizes the int8/fp8 block at the DMA boundary — the pool never
-    materializes in float anywhere."""
+def _block_scale(scale_refs, pages, valid, page_size, lanes):
+    """(block, lanes) f32 inline-dequant scales of one block of
+    quantized pages: page g's rows by its own pair, lanes [0, D) by its
+    key scale and lanes [D, 2 * D) by its value scale, the rows no
+    consumed query may read (``valid`` false: a dead entry's scale may
+    be anything) by zero; None for an unquantized pool (no scale
+    refs). The (P,) per-page scale arrays
+    ride the SAME scalar-prefetch path as the page table: the loop turn
+    that consumes a block reads its pages' scales from SMEM and
+    dequantizes the int8/fp8 block on its way out of the DMA buffer —
+    the pool never materializes in float anywhere."""
     if not scale_refs:
         return None
     ks_ref, vs_ref = scale_refs
+    block = len(pages) * page_size
+    row = lax.broadcasted_iota(jnp.int32, (block, 1), 0)
     lane = lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
-    return jnp.where(lane < lanes // 2, ks_ref[page], vs_ref[page])
+    out = jnp.zeros((block, lanes), jnp.float32)
+    for g, page in enumerate(pages):
+        pair = jnp.where(lane < lanes // 2, ks_ref[page], vs_ref[page])
+        out = jnp.where(row >= g * page_size, pair, out)
+    return jnp.where(valid, out, 0.0)
+
+
+def _head_group(heads, rows):
+    """Heads a loop turn of the kernels takes together: as many as
+    keep the turn's scores, one head's under another's, within 512
+    rows (all of them for a decode or verify step, 5 of 25 at a
+    64-row chunk, one from 256 rows on), and a divisor of ``heads``.
+    Read off the shapes, like the block."""
+    return _pa._largest_divisor(heads, max(1, 512 // rows))
 
 
 def _init_state(m_ref, l_ref, acc_ref):
@@ -116,49 +153,73 @@ def _init_state(m_ref, l_ref, acc_ref):
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
 
-def _accumulate_page(q_ref, kv_ref, m_ref, l_ref, acc_ref, *, heads,
-                     scale, valid, visible, page_scale):
-    """One page's online-softmax update for every head (unrolled).
-    ``valid`` (page_size, 1): positions some consumed query row may
-    read; ``visible`` (rows, page_size): the per-row prefix mask;
-    ``page_scale`` (1, 2 * D) f32 or None (unquantized pool)."""
-    for h in range(heads):
+def _for_turns(m_ref, turn):
+    """``turn(g)`` for every group of heads (``m_ref``: a row a
+    group)."""
+    if m_ref.shape[0] == 1:
+        turn(0)
+    else:
+        lax.fori_loop(0, m_ref.shape[0], lambda g, c: (turn(g), c)[1], 0)
+
+
+def _accumulate_block(q_ref, kv_ref, m_ref, l_ref, acc_ref, *, scale,
+                      visible, block_scale):
+    """One block's online-softmax update, a group of heads a loop turn
+    and ONE update a group: the scores of the group's heads lie one
+    under another, (group * rows, block), so that a decode step's
+    25 single-row heads fill four vregs where they would half-fill 25,
+    and the row maxima, the two ``exp`` and the row sums are taken
+    once. ``kv_ref`` (H, block, 2D): the block's pages one under
+    another, positions no consumed row may read already selected out;
+    ``visible`` (group * rows, block): the per-row prefix mask;
+    ``block_scale`` (block, 2 * D) f32 or None (unquantized pool)."""
+    rows = q_ref.shape[2]
+    group = q_ref.shape[1] // m_ref.shape[0]
+
+    def tile(h):
         q = q_ref[0, h]                 # (rows, 2D), lanes [D, 2D) zero
-        kv = kv_ref[0, h]               # (page_size, 2D): keys | values
-        if page_scale is not None:      # inline dequant
+        kv = kv_ref[h]                  # (block, 2D): keys | values
+        if block_scale is not None:     # inline dequant
             q = q.astype(jnp.float32)
-            kv = kv.astype(jnp.float32) * page_scale
-        # SELECT masked rows out of the tile (not just zero-weight
-        # them): a freed page can be reused carrying non-finite garbage
-        # in positions past the new owner's length, and 0 * NaN = NaN
-        # would leak it through the weighted sum (and, keys and values
-        # sharing the tile, through the padded query's zero lanes) —
-        # masked reads must never matter, even poisoned ones (a
-        # quantized pool's NaN channel is the page SCALE — the select
-        # covers it the same way)
-        kv = jnp.where(valid, kv, 0.0)
-        sc = jnp.dot(q, kv.T, preferred_element_type=jnp.float32,
-                     precision=lax.Precision.DEFAULT) * scale
+            kv = kv.astype(jnp.float32) * block_scale
+        return q, kv
+
+    def turn(g):
+        heads = [g * group + u for u in range(group)]
+        sc = jnp.concatenate([
+            jnp.dot(q, kv.T, preferred_element_type=jnp.float32,
+                    precision=lax.Precision.DEFAULT)
+            for q, kv in map(tile, heads)], axis=0) * scale
         sc = jnp.where(visible, sc, _NEG_INF)
-        m_prev = m_ref[h]               # (rows,)
-        l_prev = l_ref[h]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
-        p = jnp.exp(sc - m_new[:, None])        # (rows, page_size) f32
+        m_prev = m_ref[g][:, None]      # (group * rows, 1)
+        l_prev = l_ref[g][:, None]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_new)         # (group * rows, block) f32
         alpha = jnp.exp(m_prev - m_new)
-        m_ref[h] = m_new
-        l_ref[h] = l_prev * alpha + jnp.sum(p, axis=-1)
-        # [p·k | p·v]: the caller keeps lanes [D, 2D)
-        acc_ref[h] = acc_ref[h] * alpha[:, None] + jnp.dot(
-            p.astype(kv.dtype), kv, preferred_element_type=jnp.float32,
-            precision=lax.Precision.DEFAULT)
+        m_ref[g] = m_new[:, 0]
+        l_ref[g] = (l_prev * alpha +
+                    jnp.sum(p, axis=-1, keepdims=True))[:, 0]
+        for u, h in enumerate(heads):
+            _, kv = tile(h)
+            mine = slice(u * rows, (u + 1) * rows)
+            # [p·k | p·v]: the caller keeps lanes [D, 2D)
+            acc_ref[h] = acc_ref[h] * alpha[mine] + jnp.dot(
+                p[mine].astype(kv.dtype), kv,
+                preferred_element_type=jnp.float32,
+                precision=lax.Precision.DEFAULT)
+
+    _for_turns(m_ref, turn)
 
 
-def _finalize(o_ref, m_ref, l_ref, acc_ref, heads):
-    for h in range(heads):
-        m = m_ref[h]
-        l_safe = jnp.maximum(l_ref[h], 1e-30)
+def _finalize(o_ref, m_ref, l_ref, acc_ref):
+    rows = o_ref.shape[2]
+    group = o_ref.shape[1] // m_ref.shape[0]
+
+    def turn(g):
+        m = m_ref[g][:, None]
+        l_safe = jnp.maximum(l_ref[g][:, None], 1e-30)
         # rows that never accumulated (a length-0 slot, a dead verify
-        # slot, padded chunk rows past every accumulated page): m never
+        # slot, padded chunk rows past every accumulated block): m never
         # left _NEG_INF — emit exactly zero, the masked-row contract
         # shared with the training kernels (ops.pallas_attention).
         # Negated-compare form so a NaN running max (poisoned K/V page)
@@ -167,45 +228,182 @@ def _finalize(o_ref, m_ref, l_ref, acc_ref, heads):
         # depends on corruption staying visible in the output.
         # (the compare runs on the already-expanded f32 column:
         # Mosaic cannot reshape an i1 vector)
-        row_ok = ~(m[:, None] <= _NEG_INF / 2)
-        o_ref[0, h] = jnp.where(row_ok, acc_ref[h] / l_safe[:, None],
-                                0.0).astype(o_ref.dtype)
+        row_ok = ~(m <= _NEG_INF / 2)
+        for u in range(group):
+            h, mine = g * group + u, slice(u * rows, (u + 1) * rows)
+            o_ref[0, h] = jnp.where(row_ok[mine], acc_ref[h] / l_safe[mine],
+                                    0.0).astype(o_ref.dtype)
+
+    _for_turns(m_ref, turn)
 
 
-def _paged_call(kernel, name, grid, prefetch, q4, kv_pool, q_map, kv_map,
-                interpret):
-    """The one ``pallas_call`` shape of the three kernels: ``prefetch``
-    int32 page table / lengths (and f32 page scales) in SMEM, queries
-    ``q4`` (G, H, rows, D) blocked by ``q_map``, the pool blocked a
-    page at a time by ``kv_map`` (which dereferences the prefetched
-    page table, so the kernel never sees a gather), online-softmax
-    state in VMEM scratch. Returns (G, H, rows, D)."""
+def _walk_live_blocks(refs, page_at, live_keys, visible, *, scale,
+                      page_size, n_pages):
+    """The body the three kernels share: one program walks ONE page-
+    table row, and only as far as it is live. ``page_at(j)`` reads the
+    row's j-th entry from SMEM, ``live_keys`` (traced scalar) is the
+    count of key positions some consumed query row may read, and
+    ``visible(pos, row)`` is the kernel's own prefix mask over a
+    (1, block) row of key positions and a (n, 1) column of query-row
+    indices.
+
+    A block is ``per_block`` pages one under another in a VMEM buffer,
+    a lane-full row of keys where the page size allows; the program's
+    own DMAs fetch block b + 1 into the buffer's other half while
+    block b is consumed. No block past the last live one is fetched or
+    visited; inside the last live block the entries past the last live
+    page (null-page entries by the table's contract) are fetched with
+    the rest and selected out like the tail of a partial page. Zero
+    live keys: zero turns and exact zeros out."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    *scale_refs, q_ref, pool_ref, o_ref, m_ref, l_ref, acc_ref, \
+        buf_ref, sem_ref = refs
+    heads, rows = q_ref.shape[1], q_ref.shape[2]
+    block = buf_ref.shape[2]
+    per_block = block // page_size
+    live_keys = jnp.minimum(live_keys, n_pages * page_size)
+    n_blocks = pl.cdiv(live_keys, block)
+    # the query row of each score row of a loop turn's stack of heads
+    row = jnp.concatenate(
+        [lax.broadcasted_iota(jnp.int32, (rows, 1), 0)] *
+        (heads // m_ref.shape[0]), axis=0)
+
+    def pages_of(b):
+        # the table's last block may be short of ``per_block`` entries
+        return [page_at(jnp.minimum(b * per_block + g, n_pages - 1))
+                for g in range(per_block)]
+
+    def fetch(b, half):
+        return [pltpu.make_async_copy(
+            pool_ref.at[page],
+            buf_ref.at[half, :, pl.ds(g * page_size, page_size)],
+            sem_ref.at[half]) for g, page in enumerate(pages_of(b))]
+
+    def turn(b, carry):
+        half = lax.rem(b, 2)
+
+        @pl.when(b + 1 < n_blocks)
+        def _next():
+            for dma in fetch(b + 1, 1 - half):
+                dma.start()
+
+        for dma in fetch(b, half):
+            dma.wait()
+        valid = (b * block + lax.broadcasted_iota(
+            jnp.int32, (block, 1), 0)) < live_keys
+        block_scale = _block_scale(scale_refs, pages_of(b), valid,
+                                   page_size, buf_ref.shape[-1])
+
+        # SELECT masked rows out of the tile (not just zero-weight
+        # them): a freed page can be reused carrying non-finite garbage
+        # in positions past the new owner's length, and 0 * NaN = NaN
+        # would leak it through the weighted sum (and, keys and values
+        # sharing the tile, through the padded query's zero lanes) —
+        # masked reads must never matter, even poisoned ones (a
+        # quantized pool's NaN channel is the page SCALE — the select
+        # covers it the same way). Only the last live block holds such
+        # positions, so the select runs once a walk, in the buffer,
+        # before any head reads it
+        @pl.when((b + 1) * block > live_keys)
+        def _tail():
+            for h in range(heads):
+                buf_ref[half, h] = jnp.where(
+                    valid, buf_ref[half, h], 0).astype(buf_ref.dtype)
+
+        pos = b * block + lax.broadcasted_iota(jnp.int32, (1, block), 1)
+        _accumulate_block(
+            q_ref, buf_ref.at[half], m_ref, l_ref, acc_ref, scale=scale,
+            visible=visible(pos, row), block_scale=block_scale)
+        return carry
+
+    @pl.when(n_blocks == 0)
+    def _dead():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_blocks > 0)
+    def _live():
+        _init_state(m_ref, l_ref, acc_ref)
+        for dma in fetch(0, 0):
+            dma.start()
+        lax.fori_loop(0, n_blocks, turn, 0)
+        _finalize(o_ref, m_ref, l_ref, acc_ref)
+
+
+def _block_pages(page_size, n_pages):
+    """Pages a block: as many as fill the 128 lanes of a score row with
+    keys (8 at a page of 16), no more than the table has. Read off the
+    shapes; nothing else chooses it."""
+    return min(max(1, 128 // page_size), n_pages)
+
+
+def _paged_vmem_limit(H, rows, lanes, block, stack, q_itemsize,
+                      kv_itemsize):
+    """Scoped-VMEM request for one paged program: the query and output
+    blocks double-buffered by Pallas, the f32 accumulator, m and l, the
+    two halves of the DMA buffer, eight (stack, block) f32 tiles for a
+    loop turn's scores and probabilities and 4 MiB of slack. Mosaic's
+    default scoped limit is 16 MiB (the chunk-prefill kernel at 25
+    heads needs more from 512 rows on); the request is a cap, not an
+    allocation."""
+    rows8 = -(-rows // 8) * 8
+    need = H * rows8 * lanes * (4 * q_itemsize + 4) \
+        + 2 * 8 * H * rows8 * 4 \
+        + 2 * H * block * lanes * kv_itemsize \
+        + 8 * max(stack, 8) * max(block, 128) * 4 + (4 << 20)
+    return min(max(need, 16 << 20), 100 << 20)
+
+
+def _paged_call(kernel, name, scale, prefetch, q4, kv_pool, interpret):
+    """The one ``pallas_call`` shape of the three kernels: a program a
+    page-table row (grid ``(q4.shape[0],)``: a slot, or the one slot of
+    a chunk), ``prefetch`` int32 page table (first) / lengths (and f32
+    page scales) in SMEM, queries ``q4`` (S, H, rows, D) a row's block at a
+    time, the pool left in HBM as it lies for the program's own DMAs
+    (so the kernel never sees a gather, and no grid step is spent on a
+    page nobody holds), online-softmax state and the two-block DMA
+    buffer in VMEM scratch. Returns (S, H, rows, D)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     D = q4.shape[-1]
     q4 = _pad_lanes(q4)
-    _, H, rows, lanes = q4.shape
+    S, H, rows, lanes = q4.shape
     page_size = kv_pool.shape[2]
+    n_pages = prefetch[0].shape[-1]
+    block = _block_pages(page_size, n_pages) * page_size
+    group = _head_group(H, rows)
+
+    def q_map(s, *_):
+        return (s, 0, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=grid,
+        grid=(S,),
         in_specs=[
             pl.BlockSpec((1, H, rows, lanes), q_map),
-            pl.BlockSpec((1, H, page_size, lanes), kv_map),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, H, rows, lanes), q_map),
         scratch_shapes=[
-            pltpu.VMEM((H, rows), jnp.float32),         # m
-            pltpu.VMEM((H, rows), jnp.float32),         # l
+            pltpu.VMEM((H // group, group * rows), jnp.float32),    # m
+            pltpu.VMEM((H // group, group * rows), jnp.float32),    # l
             pltpu.VMEM((H, rows, lanes), jnp.float32),  # acc
+            pltpu.VMEM((2, H, block, lanes), kv_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     out = pl.pallas_call(
-        kernel,
+        functools.partial(kernel, scale=scale, page_size=page_size,
+                          n_pages=n_pages),
         name=name,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_paged_vmem_limit(
+                H, rows, lanes, block, group * rows, q4.dtype.itemsize,
+                kv_pool.dtype.itemsize)),
         interpret=interpret,
     )(*prefetch, q4, kv_pool)
     return out[..., D:]
@@ -218,34 +416,15 @@ def _scale_prefetch(k_scale, v_scale):
     return (k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
 
 
-def _ragged_kernel(pt_ref, ln_ref, *refs, scale, page_size, n_pages,
-                   heads):
+def _ragged_kernel(pt_ref, ln_ref, *refs, **static):
     from jax.experimental import pallas as pl
 
-    *scale_refs, q_ref, kv_ref, o_ref, m_ref, l_ref, acc_ref = refs
     s = pl.program_id(0)
-    j = pl.program_id(1)
     length = ln_ref[s]                          # live tokens this slot
-
-    @pl.when(j == 0)
-    def _init():
-        _init_state(m_ref, l_ref, acc_ref)
-
-    @pl.when(j * page_size < length)
-    def _accumulate():
-        valid = (j * page_size + lax.broadcasted_iota(
-            jnp.int32, (page_size, 1), 0)) < length
-        pos = j * page_size + lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        _accumulate_page(
-            q_ref, kv_ref, m_ref, l_ref, acc_ref, heads=heads,
-            scale=scale, valid=valid, visible=pos < length,
-            page_scale=_page_scale(scale_refs, pt_ref[s, j],
-                                   kv_ref.shape[-1]))
-
-    @pl.when(j == n_pages - 1)
-    def _fin():
-        _finalize(o_ref, m_ref, l_ref, acc_ref, heads)
+    # decode attention is a prefix mask: the query IS position
+    # length - 1
+    _walk_live_blocks(refs, lambda j: pt_ref[s, j], length,
+                      lambda pos, row: pos < length, **static)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -255,20 +434,13 @@ def _ragged_pallas(q, kv_pool, page_table, lengths, scale, interpret,
     (S, max_pages) int32; lengths: (S,) int32; k_scale/v_scale: (P,)
     f32 per-page scales of a quantized pool, or None. Returns
     (S, H, D)."""
-    S, H, _ = q.shape
-    n_pages = page_table.shape[1]
     quant = k_scale is not None
-    kernel = functools.partial(
-        _ragged_kernel, scale=scale, page_size=kv_pool.shape[2],
-        n_pages=n_pages, heads=H)
     out = _paged_call(
-        kernel, "mxtpu_ragged_decode" + ("_q" if quant else ""),
-        (S, n_pages),
+        _ragged_kernel, "mxtpu_ragged_decode" + ("_q" if quant else ""),
+        scale,
         (page_table.astype(jnp.int32), lengths.astype(jnp.int32),
          *_scale_prefetch(k_scale, v_scale)),
-        q[:, :, None, :], kv_pool,
-        lambda s, j, *_: (s, 0, 0, 0),
-        lambda s, j, pt, *_: (pt[s, j], 0, 0, 0), interpret)
+        q[:, :, None, :], kv_pool, interpret)
     return out[:, :, 0, :]
 
 
@@ -371,50 +543,27 @@ def ragged_paged_attention(q, kv_pool, page_table, lengths, scale=None,
 # prefill over a paged prefix (the chunked-prefill attention variant)
 # --------------------------------------------------------------------- #
 
-def _ragged_prefill_kernel(pr_ref, qi_ref, *refs, scale, page_size,
-                           n_pages, heads, chunk):
-    from jax.experimental import pallas as pl
-
-    *scale_refs, q_ref, kv_ref, o_ref, m_ref, l_ref, acc_ref = refs
-    j = pl.program_id(0)
+def _ragged_prefill_kernel(pr_ref, qi_ref, *refs, **static):
     start = qi_ref[0]                # first query's absolute position
     n_real = qi_ref[1]               # live queries in the chunk
 
-    @pl.when(j == 0)
-    def _init():
-        _init_state(m_ref, l_ref, acc_ref)
-
-    # pages whose first key position is past the last real query's
-    # position contribute nothing to any live row — skip them, and
-    # (dead entries all indexing the null page) skip their re-DMA too
-    @pl.when(j * page_size < start + n_real)
-    def _accumulate():
-        # positions past the last real query's view are masked for
-        # EVERY row — select them out of the tile so reused-page
-        # garbage (possibly non-finite) cannot leak through 0-weight
-        # terms
-        valid = (j * page_size + lax.broadcasted_iota(
-            jnp.int32, (page_size, 1), 0)) < start + n_real
-        pos_k = j * page_size + lax.broadcasted_iota(
-            jnp.int32, (chunk, page_size), 1)
-        pos_q = start + lax.broadcasted_iota(
-            jnp.int32, (chunk, page_size), 0)
+    def visible(pos, row):
         # per-query prefix mask: query i (absolute pos start + i) sees
         # keys [0, start + i] — the paged prefix AND the causal
         # intra-chunk part in one predicate (the chunk's own K/V is
         # already scattered into these pages)
-        _accumulate_page(
-            q_ref, kv_ref, m_ref, l_ref, acc_ref, heads=heads,
-            scale=scale, valid=valid, visible=pos_k <= pos_q,
-            page_scale=_page_scale(scale_refs, pr_ref[j],
-                                   kv_ref.shape[-1]))
+        return pos <= start + row
 
-    # every live query attends at least position 0, so only rows that
-    # saw no page at all (possible when padded rows extend past every
-    # accumulated page) stay at _NEG_INF and emit zero
-    @pl.when(j == n_pages - 1)
-    def _fin():
-        _finalize(o_ref, m_ref, l_ref, acc_ref, heads)
+    # blocks whose first key position is past the last real query's
+    # position contribute nothing to any live row and are not walked;
+    # positions past that query's view are masked for EVERY row, so
+    # they are selected out of the tile and reused-page garbage
+    # (possibly non-finite) cannot leak through 0-weight terms. Every
+    # live query attends at least position 0, so only rows that saw no
+    # block at all (padded rows past every walked block) stay at
+    # _NEG_INF and emit zero
+    _walk_live_blocks(refs, lambda j: pr_ref[j], start + n_real, visible,
+                      **static)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -423,20 +572,14 @@ def _ragged_prefill_pallas(q, kv_pool, page_row, qinfo, scale, interpret,
     """q: (C, H, D) chunk queries of ONE slot; kv_pool: (P, H, ps, 2D);
     page_row: (max_pages,) int32; qinfo: (2,) int32 = [q_start,
     n_real]; k_scale/v_scale: (P,) f32 or None. Returns (C, H, D)."""
-    C, H, _ = q.shape
-    n_pages = page_row.shape[0]
     quant = k_scale is not None
-    kernel = functools.partial(
-        _ragged_prefill_kernel, scale=scale, page_size=kv_pool.shape[2],
-        n_pages=n_pages, heads=H, chunk=C)
     out = _paged_call(
-        kernel, "mxtpu_ragged_prefill" + ("_q" if quant else ""),
-        (n_pages,),
+        _ragged_prefill_kernel,
+        "mxtpu_ragged_prefill" + ("_q" if quant else ""), scale,
         (page_row.astype(jnp.int32), qinfo.astype(jnp.int32),
          *_scale_prefetch(k_scale, v_scale)),
         q.transpose(1, 0, 2)[None], kv_pool,            # (1, H, C, D)
-        lambda j, *_: (0, 0, 0, 0),
-        lambda j, pr, *_: (pr[j], 0, 0, 0), interpret)
+        interpret)
     return out[0].transpose(1, 0, 2)
 
 
@@ -505,67 +648,47 @@ def ragged_prefill_reference(q, kv_pool, page_row, q_start, scale=None,
 # draft-then-verify attention variant)
 # --------------------------------------------------------------------- #
 
-def _ragged_verify_kernel(pt_ref, ln_ref, dl_ref, *refs, scale,
-                          page_size, n_pages, heads, window):
-    """Decode kernel generalized to ``window`` queries per slot: query
+def _ragged_verify_kernel(pt_ref, ln_ref, dl_ref, *refs, **static):
+    """Decode kernel generalized to W queries per slot: query
     row r of slot s sits at absolute position ``lengths[s] - 1 + r``
     (row 0 IS the ordinary decode query) and attends keys
     ``[0, lengths[s] - 1 + r]`` — the slot's paged prefix plus the
     causal intra-window part in one predicate, exactly the
-    chunked-prefill masking with a per-SLOT dynamic start. Same
-    online-softmax scratch carried across the page axis, same
-    dead-page skip via the repeated-null-page index, same NaN
-    propagation / masked-tile-select contract as the decode kernel."""
+    chunked-prefill masking with a per-SLOT dynamic start. Same walk of
+    the slot's live blocks, same NaN propagation / masked-tile-select
+    contract as the decode kernel."""
     from jax.experimental import pallas as pl
 
-    *scale_refs, q_ref, kv_ref, o_ref, m_ref, l_ref, acc_ref = refs
     s = pl.program_id(0)
-    j = pl.program_id(1)
     length = ln_ref[s]               # keys visible to query row 0
     dl = dl_ref[s]                   # slot's REAL draft count this step
 
-    @pl.when(j == 0)
-    def _init():
-        _init_state(m_ref, l_ref, acc_ref)
-
-    # the last CONSUMED row (row dl — accepted drafts + the
-    # bonus/correction) sees keys up to length + dl - 1, and that is
-    # also the last position freshly written this step; pages wholly
-    # past it (and every page of a dead slot) contribute nothing —
-    # dead entries all index the null page, so skipping also skips the
-    # re-DMA
-    @pl.when((length > 0) & (j * page_size < length + dl))
-    def _accumulate():
-        # positions no CONSUMED row may ever see are selected out of
-        # the tile so reused-page garbage (possibly non-finite) cannot
-        # leak through 0-weight terms. The bound must be the slot's
-        # real written extent length + dl, NOT length + window - 1:
-        # when a slot drafts fewer than window - 1 tokens, positions in
-        # [length + dl, length + window - 1) are UNWRITTEN — a recycled
-        # page can carry a quarantined slot's non-finite K/V there, and
-        # 0 * NaN = NaN would poison every consumed row, falsely
-        # quarantining a healthy slot (same rule as the chunked-prefill
-        # kernel's n_real bound). Rows past dl may now read fewer
-        # positions than their nominal visibility; their output is
-        # discarded by the engine and never feeds acceptance (the op's
-        # documented PRECONDITION).
-        valid = (j * page_size + lax.broadcasted_iota(
-            jnp.int32, (page_size, 1), 0)) < length + dl
-        pos_k = j * page_size + lax.broadcasted_iota(
-            jnp.int32, (window, page_size), 1)
-        row = lax.broadcasted_iota(jnp.int32, (window, page_size), 0)
+    def visible(pos, row):
         # row r (absolute position length - 1 + r) sees keys
         # [0, length - 1 + r]: prefix + causal intra-window in one
         # predicate
-        _accumulate_page(
-            q_ref, kv_ref, m_ref, l_ref, acc_ref, heads=heads,
-            scale=scale, valid=valid, visible=pos_k < length + row,
-            page_scale=_page_scale(scale_refs, pt_ref[s, j],
-                                   kv_ref.shape[-1]))
+        return pos < length + row
 
-    @pl.when(j == n_pages - 1)
-    def _fin():
-        _finalize(o_ref, m_ref, l_ref, acc_ref, heads)
+    # the last CONSUMED row (row dl — accepted drafts + the
+    # bonus/correction) sees keys up to length + dl - 1, and that is
+    # also the last position freshly written this step; blocks wholly
+    # past it (and every block of a dead slot) are not walked.
+    # Positions no CONSUMED row may ever see are selected out of the
+    # tile so reused-page garbage (possibly non-finite) cannot leak
+    # through 0-weight terms. The bound must be the slot's real written
+    # extent length + dl, NOT length + window - 1: when a slot drafts
+    # fewer than window - 1 tokens, positions in
+    # [length + dl, length + window - 1) are UNWRITTEN — a recycled
+    # page can carry a quarantined slot's non-finite K/V there, and
+    # 0 * NaN = NaN would poison every consumed row, falsely
+    # quarantining a healthy slot (same rule as the chunked-prefill
+    # kernel's n_real bound). Rows past dl may now read fewer
+    # positions than their nominal visibility; their output is
+    # discarded by the engine and never feeds acceptance (the op's
+    # documented PRECONDITION).
+    _walk_live_blocks(refs, lambda j: pt_ref[s, j],
+                      jnp.where(length > 0, length + dl, 0), visible,
+                      **static)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -577,21 +700,15 @@ def _ragged_verify_pallas(q, kv_pool, page_table, lengths, draft_len,
     draft_len: (S,) int32 = the slot's real draft count (index of its
     last consumed row, bounding the freshly-written extent);
     k_scale/v_scale: (P,) f32 or None. Returns (S, W, H, D)."""
-    S, W, H, _ = q.shape
-    n_pages = page_table.shape[1]
     quant = k_scale is not None
-    kernel = functools.partial(
-        _ragged_verify_kernel, scale=scale, page_size=kv_pool.shape[2],
-        n_pages=n_pages, heads=H, window=W)
     out = _paged_call(
-        kernel, "mxtpu_ragged_verify" + ("_q" if quant else ""),
-        (S, n_pages),
+        _ragged_verify_kernel,
+        "mxtpu_ragged_verify" + ("_q" if quant else ""), scale,
         (page_table.astype(jnp.int32), lengths.astype(jnp.int32),
          draft_len.astype(jnp.int32),
          *_scale_prefetch(k_scale, v_scale)),
         q.transpose(0, 2, 1, 3), kv_pool,               # (S, H, W, D)
-        lambda s, j, *_: (s, 0, 0, 0),
-        lambda s, j, pt, *_: (pt[s, j], 0, 0, 0), interpret)
+        interpret)
     return out.transpose(0, 2, 1, 3)
 
 

@@ -2763,9 +2763,13 @@ class InferenceEngine:
         self._lengths = new_lengths
         dt = time.perf_counter() - t_start
         self.decode_steps += 1
+        # pages: what the step's attention walked, the live share of a
+        # (num_slots x max_pages) table
         self.flight.emit(self._component, EventType.DECODE_STEP,
                          ts=t_start, step=self.decode_steps, width=W,
-                         live=len(live), dur_s=dt)
+                         live=len(live), dur_s=dt, pages=int(
+                             (-(-new_lengths[live] // self.page_size))
+                             .sum()))
         for s in live:
             if emitted[s, 0] < 0:            # sign-encoded guard flag
                 # poisoned verify: NOTHING from this step is recorded —

@@ -221,6 +221,28 @@ def test_engine_outcomes_emit_exactly_one_terminal(model):
         preempts[0].request_id == batch.request_id
 
 
+def test_decode_step_counts_the_pages_its_attention_walked(model):
+    """``DECODE_STEP.pages``: the live slots' ``ceil(length /
+    page_size)`` summed, lengths as the step's attention read them
+    (the token written this step included): the live share of the
+    (num_slots x max_pages) table the ragged kernel walks."""
+    rng = np.random.RandomState(9)
+    eng = InferenceEngine(model, num_slots=3, page_size=8, max_len=64)
+    reqs = [Request(_prompt(rng, n), max_new_tokens=6) for n in (3, 8, 21)]
+    for r in reqs:
+        assert eng.submit(r)
+    _drain(eng, reqs)
+    steps = eng.flight.events(etype=EventType.DECODE_STEP)
+    # all three prompts prefill in step 1, so decode step i reads
+    # prompt + i keys in every slot still live; a request's last token
+    # comes from the step after its fifth decode write
+    want = [sum(-(-(n + i) // 8) for n in (3, 8, 21))
+            for i in range(1, 6)]
+    got = [e.data["pages"] for e in steps]
+    assert [e.data["live"] for e in steps] == [3] * len(steps)
+    assert got == want, (got, want)
+
+
 def test_nonfinite_quarantine_emits_terminal():
     # PRIVATE model: NaNWeights poisons the weights in place via
     # warm_start — the shared module fixture must never see it

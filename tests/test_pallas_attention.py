@@ -654,14 +654,21 @@ def test_serving_programs_compile_for_v5e_with_no_pool_relayout(
                      dropout=0.0, dtype="bfloat16", flash=False)
     model.initialize()
     eng = InferenceEngine(model, num_slots=64, page_size=16, max_len=1024,
-                          num_pages=P, chunk_pages=16, token_budget=512)
+                          num_pages=P, chunk_pages=32, token_budget=512)
     assert eng.health_snapshot()["kv_page_shape"] == (25, 16, 128)
-    # one request through a chunk and a decode step on the CPU records
-    # each program's abstract arguments
-    eng.run([Request(np.arange(1, 41, dtype=np.int32), max_new_tokens=2)])
+    # two requests through a chunk each and a decode step on the CPU
+    # record each program's abstract arguments. A 512-row chunk's
+    # query, output and accumulator blocks (25 heads x 512 rows x 128
+    # lanes) and the walk's two-block buffer need more than Mosaic's
+    # default 16 MiB: the kernel's own ``vmem_limit_bytes`` is held here
+    eng.run([Request(np.arange(1, 41, dtype=np.int32), max_new_tokens=2),
+             Request(np.arange(1, 501, dtype=np.int32) % 255,
+                     max_new_tokens=1)])
     bodies = {"decode": (eng._decode_step_fn, "mxtpu_ragged_decode"),
               ("chunk", 64): (eng._chunk_prefill_fn,
-                              "mxtpu_ragged_prefill")}
+                              "mxtpu_ragged_prefill"),
+              ("chunk", 512): (eng._chunk_prefill_fn,
+                               "mxtpu_ragged_prefill")}
     monkeypatch.setattr(pa, "_on_tpu", lambda: True)
     pool_elems = P * 25 * 16 * 128
     for name, (body, kernel) in bodies.items():

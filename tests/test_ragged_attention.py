@@ -878,3 +878,158 @@ def test_fused_pool_kernels_match_reference_at_head_sizes(D, H):
         qc, pool, pt[4], jnp.int32(32), n_real=13), np.float32)
     assert np.isfinite(got[:13]).all()
     np.testing.assert_allclose(got[:13], ref[:13], **tol)
+
+
+# ------------------------------------------------------------------- #
+# the walk of the live blocks: a program visits ceil(live keys /
+# (G * page_size)) blocks of G pages and no more (G = 8 at a page of
+# 16: a lane-full row of 128 keys). The edges of that walk, for the
+# three kernels, against the jnp references
+# ------------------------------------------------------------------- #
+
+_WALK_PS, _WALK_PAGES = 16, 11          # one whole block and a short one
+_WALK_BLOCK, _WALK_SPARE = 128, 40
+_WALK_EDGES = [0, 1, _WALK_BLOCK - 1, _WALK_BLOCK, _WALK_BLOCK + 1,
+               _WALK_PAGES * _WALK_PS]
+
+
+def _walk_pool(rng, live, H=2, D=8):
+    """Random pools and a SHUFFLED table of ``_WALK_PAGES`` entries a
+    slot, each slot's row mapping the pages that hold its ``live`` key
+    positions; page 0 is the null page, and it and the last page,
+    ``_WALK_SPARE``, which no slot maps, are filled with NaN."""
+    _, kp, vp, pt, _ = _make_case(rng, len(live), H, D, _WALK_PS,
+                                  _WALK_PAGES, live,
+                                  num_pages=_WALK_SPARE)
+    pool = jnp.pad(_fuse(kp, vp), [(0, 1), (0, 0), (0, 0), (0, 0)])
+    return pool.at[0].set(jnp.nan).at[_WALK_SPARE].set(jnp.nan), pt
+
+
+def _walk(kernel, rng, pool, pt, live, scales=(), C=8):
+    """``kernel`` (interpreted) and its jnp reference over slots whose
+    consumed query rows read ``live[s]`` key positions. Returns
+    ``(got, ref, rows)``: both outputs with a leading slot axis, and
+    each slot's count of consumed query rows."""
+    H, D = pool.shape[1], pool.shape[-1] // 2
+    live = np.asarray(live, np.int32)
+    S, sc, kw = len(live), D ** -0.5, {}
+    if scales:
+        kw = dict(k_scale=scales[0], v_scale=scales[1])
+    if kernel == "decode":
+        q = jnp.asarray(rng.randn(S, H, D), jnp.float32)
+        got = _ragged_pallas(q, pool, pt, jnp.asarray(live), sc, True,
+                             *scales)
+        ref = ragged_attention_reference(q, pool, pt, jnp.asarray(live),
+                                         **kw)
+        rows = [1] * S
+        got, ref = got[:, None], ref[:, None]
+    elif kernel == "verify":
+        W = 3
+        dl = np.clip(live - 1, 0, W - 1)    # live = lengths + draft_len
+        q = jnp.asarray(rng.randn(S, W, H, D), jnp.float32)
+        got = _ragged_verify_pallas(q, pool, pt, jnp.asarray(live - dl),
+                                    jnp.asarray(dl), sc, True, *scales)
+        ref = ragged_verify_reference(q, pool, pt, jnp.asarray(live - dl),
+                                      **kw)
+        rows = [int(d) + 1 if n else W for d, n in zip(dl, live)]
+    else:                                   # one slot: its table row
+        n_real = min(C, int(live[0]))       # live = q_start + n_real
+        q = jnp.asarray(rng.randn(C, H, D), jnp.float32)
+        got = _ragged_prefill_pallas(
+            q, pool, pt[0], jnp.asarray([live[0] - n_real, n_real],
+                                        jnp.int32), sc, True, *scales)[None]
+        ref = ragged_prefill_reference(
+            q, pool, pt[0], jnp.int32(live[0] - n_real), n_real=n_real,
+            **kw)[None]
+        rows = [n_real]
+    return np.asarray(got), np.asarray(ref), rows
+
+
+def _assert_walk(got, ref, rows, live):
+    for s, n in enumerate(live):
+        assert np.isfinite(got[s, :rows[s]]).all(), (s, n)
+        if n == 0:                          # the masked-row contract
+            np.testing.assert_array_equal(got[s], 0.0)
+        else:
+            np.testing.assert_allclose(got[s, :rows[s]], ref[s, :rows[s]],
+                                       rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("edge,quant", [(e, False) for e in _WALK_EDGES] +
+                         [(_WALK_BLOCK + 1, True)])
+@pytest.mark.parametrize("kernel", ["decode", "verify", "prefill"])
+def test_live_block_walk_edges(kernel, edge, quant):
+    """Live keys at 0, 1, a block less one, a block, a block and one,
+    the whole table (11 pages: not a multiple of the 8 a block holds),
+    through shuffled tables, beside slots that end elsewhere."""
+    rng = np.random.RandomState(50 + edge)
+    live = [edge] if kernel == "prefill" else [37, edge, 150]
+    pool, pt = _walk_pool(rng, live)
+    scales = ()
+    if quant:
+        # a NaN page has no scale
+        pool = pool.at[0].set(0.0).at[_WALK_SPARE].set(0.0)
+        kq, vq, ks, vs, _ = _quantize_pools(*np.split(np.asarray(pool),
+                                                      2, -1))
+        pool, scales = _fuse(kq, vq), (ks, vs)
+    got, ref, rows = _walk(kernel, rng, pool, pt, live, scales)
+    _assert_walk(got, ref, rows, live)
+
+
+@pytest.mark.parametrize("edge", [5, _WALK_BLOCK + 1, 140])
+@pytest.mark.parametrize("kernel", ["decode", "verify", "prefill"])
+def test_dead_entries_and_unwritten_tail_change_no_output_bit(kernel,
+                                                              edge):
+    """What a walk fetches and may not use: the dead entries of the
+    last live block, pointed at a NaN-filled page that is NOT the null
+    page, and the last live page's positions past the live keys, NaN
+    as a recycled page may leave them. Every consumed row stays finite,
+    equals the reference, and is bit for bit what the same walk gives
+    over null-page entries and a clean tail."""
+    live = [edge] if kernel == "prefill" else [edge, 0, 33]
+    pool, pt = _walk_pool(np.random.RandomState(60), live)
+    clean = _walk(kernel, np.random.RandomState(61), pool, pt, live)
+    _assert_walk(*clean, live)
+    pt2, pool2 = np.asarray(pt).copy(), np.asarray(pool).copy()
+    for s, n in enumerate(live):
+        held = -(-n // _WALK_PS)
+        pt2[s, held:] = _WALK_SPARE
+        if n % _WALK_PS:
+            pool2[pt2[s, held - 1], :, n % _WALK_PS:] = np.nan
+    dirty = _walk(kernel, np.random.RandomState(61), jnp.asarray(pool2),
+                  jnp.asarray(pt2), live)
+    _assert_walk(*dirty, live)
+    for s, n in enumerate(live):
+        np.testing.assert_array_equal(dirty[0][s, :dirty[2][s]],
+                                      clean[0][s, :clean[2][s]])
+
+
+@pytest.mark.parametrize("kernel", ["decode", "verify"])
+def test_dead_slot_between_live_slots_emits_exact_zeros(kernel):
+    """A length-0 slot runs zero turns of the walk: exact zeros out,
+    whatever its table row points at and whatever the live slots before
+    and after it left in the kernel's buffers."""
+    live = [_WALK_BLOCK + 9, 0, 61]
+    pool, pt = _walk_pool(np.random.RandomState(70), live)
+    pt = jnp.asarray(pt).at[1].set(_WALK_SPARE)
+    got, ref, rows = _walk(kernel, np.random.RandomState(71), pool, pt,
+                           live)
+    np.testing.assert_array_equal(got[1], 0.0)
+    _assert_walk(got, ref, rows, live)
+
+
+def test_chunk_walk_takes_the_heads_a_group_a_loop_turn():
+    """From 256 query rows on the kernels take the heads one a loop
+    turn (``_head_group``: a turn's stack of scores stays within 512
+    rows), where every smaller case here takes them all at once: the
+    rolled loop, its dynamic head index and the per-group state rows
+    against the reference, padded rows past the live keys included."""
+    from incubator_mxnet_tpu.ops.ragged_attention import _head_group
+    assert _head_group(3, 256) == 1 and _head_group(25, 64) == 5
+    assert _head_group(25, 1) == 25 and _head_group(4, 8) == 4
+    rng = np.random.RandomState(80)
+    live = [_WALK_PAGES * _WALK_PS]
+    pool, pt = _walk_pool(rng, live, H=3)
+    got, ref, rows = _walk("prefill", rng, pool, pt, live, C=256)
+    assert rows == live
+    _assert_walk(got, ref, rows, live)
