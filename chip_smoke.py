@@ -22,6 +22,15 @@ nothing is caught and downgraded:
              ``cached_forward`` under the engine's paged ``attend``) hold
              the ragged Mosaic custom calls
 
+  4 hybrid   Granite-4.0-H-Micro at its published widths and a cut depth
+             (5 Mamba-2 layers, 1 grouped-query attention layer, 1 more
+             Mamba-2 layer; the whole vocabulary) through the same
+             ``InferenceEngine``: a handful of prompts, chunked prefill then
+             decoding through the page pool and the state cache, and the
+             gap of every served token to the benchmark's plain reference
+             (float32, the recurrence as a recurrence); the decode program
+             holds ``mxtpu_ssm_decode`` and ``mxtpu_ragged_decode``
+
 The last-but-one line of stdout is one JSON object with every leg's
 verdict and its smoke timings (compile seconds and the rest kept apart —
 NOT metrics: nothing here is a benchmark). The last line is the success
@@ -669,6 +678,106 @@ def leg_serve(ctx):
 
 # --------------------------------------------------------------------- #
 
+# widest gap allowed between a served token's reference logit and the
+# reference's best at its position in the hybrid leg: bf16 matrices against
+# the float32 reference on logits of spread 0.0094; read 0.00023 on the chip
+# at this cut depth with ``dt_bias`` drawn about -3 (the cell, at full depth
+# and about -4.6, reads 0.0007-0.0009 and allows 0.0033; PERF.md section 4)
+HYBRID_GAP_TOL = 0.002
+
+
+def leg_hybrid(ctx):
+    """The second served block family, end to end against its reference.
+    ``dt_bias`` is drawn about -3 (dt about 0.05; the configuration file's
+    own mean is -4.6) so that state some twenty positions old still reaches a
+    logit and a carry lost between chunks or steps would show: the draw the
+    leg's tolerance was read with on the chip."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from incubator_mxnet_tpu import profiler, serve
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    bench = os.path.join(here, "benchmark")
+    sys.path.insert(0, bench)
+    from harness import manifest, refops, weights as hw
+
+    ref = manifest.load_module(os.path.join(
+        bench, "configs", "granite_hybrid_reference.py"))
+    adapter = manifest.load_module(os.path.join(
+        bench, "adapters", "granite_hybrid_mxtpu.py"))
+    z = ctx["size"]
+    with open(os.path.join(bench, "configs", z["hybrid_config"])) as f:
+        cfg = json.load(f)
+    cfg["layer_types"] = cfg["layer_types"][:7]
+    cfg["n_positions"] = z["hybrid_max_len"]
+    cfg["dt_bias_mean"] = -3.0
+    weights = dict(hw.make_weights(ref.param_spec(cfg), 1))
+    profiler.ssm_dispatch(reset=True)
+    model = adapter._model(cfg, dict(weights))
+    engine = serve.InferenceEngine(
+        model, num_slots=4, page_size=16, max_len=z["hybrid_max_len"],
+        prefix_cache=False, chunk_pages=z["hybrid_chunk_pages"],
+        interpret=True if ctx["rehearsal"] else None)
+    rng = np.random.RandomState(2)
+    reqs = [serve.Request(rng.randint(0, cfg["vocab_size"], n)
+                          .astype(np.int32), max_new_tokens=k,
+                          temperature=0.0, eos_id=-1)
+            for n, k in z["hybrid_requests"]]
+    t0 = time.perf_counter()
+    engine.run(reqs, arrival_times=[0.0, 0.0, 0.05, 0.1, 0.15, 0.2])
+    serve_s = time.perf_counter() - t0
+    engine.audit_pages()
+    check(all(r.outcome is not None and r.outcome.name == "MAX_TOKENS"
+              for r in reqs), "every hybrid request runs to its length")
+    check(engine.decode_trace_count == 1, "one decode program")
+    tally = profiler.ssm_dispatch()
+    n_state = cfg["layer_types"].count("mamba")
+    check(tally.get("ssm_decode_pallas") == n_state,
+          f"the decode program's state layers took the kernel: {tally}")
+    text = engine.compiled_program_text("decode")
+    kernels = mosaic_kernels(text)
+    if not ctx["rehearsal"]:
+        for name in ("mxtpu_ssm_decode", "mxtpu_ragged_decode"):
+            check(any(name in k for k in kernels),
+                  f"decode program holds {name}: {sorted(kernels)}")
+    snap = engine.health_snapshot()
+    ops = refops.Ops("f32")
+
+    @jax.jit
+    def gap_of(weights, ids, pos, toks):    # weights: traced, not baked in
+        logits = ref.logits_at(weights, ids, pos, cfg, ops)
+        got = jnp.take_along_axis(logits, toks[:, None], axis=-1)[:, 0]
+        return jnp.max(logits.max(-1) - got), jnp.std(logits)
+
+    gaps, spreads = [], []
+    with jax.default_matmul_precision("highest"):
+        for r in reqs:
+            toks = np.asarray(r.token_ids, np.int32)
+            n_p = r.prompt_ids.size
+            ids = np.zeros(z["hybrid_max_len"], np.int32)
+            ids[:n_p] = r.prompt_ids
+            ids[n_p:n_p + toks.size - 1] = toks[:-1]
+            pos = np.arange(n_p - 1, n_p - 1 + toks.size, dtype=np.int32)
+            g, sd = gap_of(weights, ids, pos, toks)
+            gaps.append(float(g))
+            spreads.append(float(sd))
+    log(f"hybrid: {len(reqs)} requests, "
+        f"{sum(len(r.token_ids) for r in reqs)} tokens in {serve_s:.1f}s "
+        f"(smoke timing); widest gap to the reference "
+        f"{max(gaps):.4f} on logits of spread {np.mean(spreads):.3f}; "
+        f"state cache {snap['state_cache_bytes'] / 1e6:.1f} MB in "
+        f"{snap['state_layers']} layers; dispatch tally {tally}")
+    check(max(gaps) <= HYBRID_GAP_TOL,
+          f"served tokens within {HYBRID_GAP_TOL} of the reference's best: "
+          f"{gaps}")
+    engine.shutdown()
+    return {"requests": len(reqs), "logit_gap_max": max(gaps),
+            "logit_std": float(np.mean(spreads)),
+            "state_cache_bytes": snap["state_cache_bytes"],
+            "ssm_dispatch": tally, "serve_s": round(serve_s, 2)}
+
+
 def _sizes(rehearsal):
     from incubator_mxnet_tpu.models import gpt as gpt_mod
     if rehearsal:
@@ -681,7 +790,11 @@ def _sizes(rehearsal):
                 "prefill_cases": ((16, ((0, 16), (32, 5))),
                                   (32, ((64, 32),))),
                 "train_B": 4, "train_T": 64,
-                "chunk_pages": 2, "prefix_len": 32, "long_len": 70}
+                "chunk_pages": 2, "prefix_len": 32, "long_len": 70,
+                "hybrid_config": "granite-tiny-rehearsal.json",
+                "hybrid_max_len": 96, "hybrid_chunk_pages": 2,
+                "hybrid_requests": ((9, 6), (40, 8), (70, 5), (33, 12),
+                                    (17, 4), (50, 7))}
     # GPT-2-small: 12 layers, 768 units, 12 heads of 64, context 1024;
     # the dense flash pair also at BERT-large's 16 heads, the ragged
     # kernels over the fused page pool at GPT-2-XL's 25
@@ -694,11 +807,16 @@ def _sizes(rehearsal):
             "prefill_cases": ((16, ((0, 16), (32, 5))),
                               (128, ((0, 128), (128, 44), (896, 104)))),
             "train_B": 16, "train_T": 512,
-            "chunk_pages": 8, "prefix_len": 128, "long_len": 300}
+            "chunk_pages": 8, "prefix_len": 128, "long_len": 300,
+            "hybrid_config": "granite-4.0-h-micro.json",
+            "hybrid_max_len": 640, "hybrid_chunk_pages": 16,
+            "hybrid_requests": ((9, 24), (40, 32), (300, 24), (530, 16),
+                                (257, 24), (100, 48))}
 
 
 LEGS = (("device", leg_device), ("kernels", leg_kernels),
-        ("train", leg_train), ("serve", leg_serve))
+        ("train", leg_train), ("serve", leg_serve),
+        ("hybrid", leg_hybrid))
 
 
 def main(argv=None):
@@ -712,7 +830,7 @@ def main(argv=None):
                     help="leg 2's layout over all local devices")
     ap.add_argument("--legs", default=",".join(n for n, _ in LEGS),
                     help="comma-separated subset (the success marker "
-                         "needs all four)")
+                         "needs all five)")
     args = ap.parse_args(argv)
     chosen = args.legs.split(",")
     unknown = set(chosen) - {n for n, _ in LEGS}

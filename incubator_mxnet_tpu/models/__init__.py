@@ -13,7 +13,8 @@ __all__ = ["LeNet", "BERTModel", "BERTForPretraining", "BERTClassifier",
 
 
 def __getattr__(name):
-    if name in ("resnet", "transformer", "ssd", "gpt", "faster_rcnn"):
+    if name in ("resnet", "transformer", "ssd", "gpt", "faster_rcnn",
+                "granite_hybrid"):
         import importlib
         mod = importlib.import_module("." + name, __name__)
         globals()[name] = mod

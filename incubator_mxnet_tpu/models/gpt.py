@@ -215,13 +215,18 @@ class GPTModel(HybridBlock):
             logits = constrain(logits, ("dp", "fsdp"), seq_ax, "tp")
         return logits
 
-    def kv_geometry(self):
-        """(num_layers, heads, head_dim): what a cache has to hold of a
-        position."""
+    def cache_layout(self):
+        """What a cache has to hold, a layer an entry (the serving
+        seam, docs/SERVING.md): every layer of this model keeps keys and
+        values a position, ``heads`` of ``head_dim``, read at the usual
+        ``head_dim ** -0.5``."""
         attn = self.block0.attn
-        return self.num_layers, attn._heads, attn._units // attn._heads
+        D = attn._units // attn._heads
+        return [{"kind": "kv", "kv_heads": attn._heads, "head_dim": D,
+                 "scale": D ** -0.5} for _ in range(self.num_layers)]
 
-    def cached_forward(self, ids, pos, attend, last_row=None):
+    def cached_forward(self, ids, pos, attend, last_row=None, state=None,
+                       real=None):
         """Inference forward of tokens ``ids`` (B, T) at positions
         ``pos`` (B, T) against a cache the caller keeps: the one layer
         loop of cached inference (the dense buffers of
@@ -230,7 +235,9 @@ class GPTModel(HybridBlock):
         ``i``'s keys and values are kept and how they are read: per-head
         (B, T, H, D) arrays in, the attention output (B, T, H, D) in
         ``q``'s type out. ``last_row`` (a traced index) keeps that one
-        row before the head. Returns logits (B, T or 1, vocab), f32.
+        row before the head. ``state`` and ``real`` are the seam's
+        other half, for a model with state layers; this one has none
+        and reads neither. Returns logits (B, T or 1, vocab), f32.
         No dropout: call it outside training mode."""
         B, T = ids.shape
         x = self.word_embed(NDArray(ids)) + self.position_embed(NDArray(pos))
@@ -430,11 +437,11 @@ def _lm_head(model: GPTModel, x):
 def init_kv_cache(model: GPTModel, batch_size: int, max_len=None,
                   dtype=None):
     """Fresh (k, v) cache buffers for every layer."""
-    L, H, D = model.kv_geometry()
     Tmax = int(max_len or model.max_length)
     dt = jnp.dtype(dtype) if dtype else jnp.dtype(model._dtype)
-    mk = lambda: jnp.zeros((batch_size, Tmax, H, D), dt)
-    return [(mk(), mk()) for _ in range(L)]
+    mk = lambda lay: jnp.zeros(
+        (batch_size, Tmax, lay["kv_heads"], lay["head_dim"]), dt)
+    return [(mk(lay), mk(lay)) for lay in model.cache_layout()]
 
 
 def decode_forward(model: GPTModel, ids, caches, start_pos,

@@ -238,7 +238,7 @@ def _finalize(o_ref, m_ref, l_ref, acc_ref):
 
 
 def _walk_live_blocks(refs, page_at, live_keys, visible, *, scale,
-                      page_size, n_pages):
+                      page_size, n_pages, rep=1):
     """The body the three kernels share: one program walks ONE page-
     table row, and only as far as it is live. ``page_at(j)`` reads the
     row's j-th entry from SMEM, ``live_keys`` (traced scalar) is the
@@ -254,7 +254,16 @@ def _walk_live_blocks(refs, page_at, live_keys, visible, *, scale,
     visited; inside the last live block the entries past the last live
     page (null-page entries by the table's contract) are fetched with
     the rest and selected out like the tail of a partial page. Zero
-    live keys: zero turns and exact zeros out."""
+    live keys: zero turns and exact zeros out.
+
+    ``rep`` (static) is the count of query heads that read one
+    key-value head (grouped queries): the pool has H key-value heads
+    and the queries arrive with a head's ``rep`` query heads' rows one
+    under another, (H, rep * rows, lanes), so the stack of rows under
+    one key-value head's block is its query heads' rows and every dot
+    stays a head's. ``visible`` is asked by a row's index within its
+    own query head. At ``rep`` 1 nothing here differs from the plain
+    multi-head walk."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -266,9 +275,10 @@ def _walk_live_blocks(refs, page_at, live_keys, visible, *, scale,
     live_keys = jnp.minimum(live_keys, n_pages * page_size)
     n_blocks = pl.cdiv(live_keys, block)
     # the query row of each score row of a loop turn's stack of heads
-    row = jnp.concatenate(
-        [lax.broadcasted_iota(jnp.int32, (rows, 1), 0)] *
-        (heads // m_ref.shape[0]), axis=0)
+    row = lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    if rep > 1:
+        row = lax.rem(row, rows // rep)
+    row = jnp.concatenate([row] * (heads // m_ref.shape[0]), axis=0)
 
     def pages_of(b):
         # the table's last block may be short of ``per_block`` entries
@@ -359,15 +369,21 @@ def _paged_call(kernel, name, scale, prefetch, q4, kv_pool, interpret):
     """The one ``pallas_call`` shape of the three kernels: a program a
     page-table row (grid ``(q4.shape[0],)``: a slot, or the one slot of
     a chunk), ``prefetch`` int32 page table (first) / lengths (and f32
-    page scales) in SMEM, queries ``q4`` (S, H, rows, D) a row's block at a
-    time, the pool left in HBM as it lies for the program's own DMAs
+    page scales) in SMEM, queries ``q4`` (S, Hq, rows, D) a row's block at
+    a time, the pool left in HBM as it lies for the program's own DMAs
     (so the kernel never sees a gather, and no grid step is spent on a
     page nobody holds), online-softmax state and the two-block DMA
-    buffer in VMEM scratch. Returns (S, H, rows, D)."""
+    buffer in VMEM scratch. Against a pool of H < Hq key-value heads a
+    head's Hq / H query heads' rows go one under another, (S, H, rep *
+    rows, D), and come back apart. Returns (S, Hq, rows, D)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    q_shape = q4.shape
     D = q4.shape[-1]
+    rep = q4.shape[1] // kv_pool.shape[1]
+    if rep > 1:
+        q4 = q4.reshape(q_shape[0], kv_pool.shape[1], rep * q_shape[2], D)
     q4 = _pad_lanes(q4)
     S, H, rows, lanes = q4.shape
     page_size = kv_pool.shape[2]
@@ -396,7 +412,7 @@ def _paged_call(kernel, name, scale, prefetch, q4, kv_pool, interpret):
     )
     out = pl.pallas_call(
         functools.partial(kernel, scale=scale, page_size=page_size,
-                          n_pages=n_pages),
+                          n_pages=n_pages, rep=rep),
         name=name,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
@@ -406,7 +422,7 @@ def _paged_call(kernel, name, scale, prefetch, q4, kv_pool, interpret):
                 kv_pool.dtype.itemsize)),
         interpret=interpret,
     )(*prefetch, q4, kv_pool)
-    return out[..., D:]
+    return out[..., D:].reshape(q_shape)
 
 
 def _scale_prefetch(k_scale, v_scale):
@@ -464,6 +480,16 @@ def _gather_window(kv_pool, page_table, k_scale=None, v_scale=None):
     return window(k, k_scale), window(v, v_scale)
 
 
+def _per_query_head(k, v, heads, axis):
+    """Key and value windows repeated to one a QUERY head (grouped
+    queries: query head j reads key-value head j // rep); as they are
+    at equal head counts."""
+    rep = heads // k.shape[axis]
+    if rep == 1:
+        return k, v
+    return jnp.repeat(k, rep, axis=axis), jnp.repeat(v, rep, axis=axis)
+
+
 def _reference_core(q, k, v, lengths, sc):
     """Masked online-softmax attention over a pre-gathered window.
     q: (S, H, D); k/v: (S, H, K, D). Factored out so the verify
@@ -471,6 +497,7 @@ def _reference_core(q, k, v, lengths, sc):
     row runs bitwise the same computation as the decode reference."""
     S, H, D = q.shape
     K = k.shape[2]
+    k, v = _per_query_head(k, v, H, 1)
     s = jnp.einsum("shd,shkd->shk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * sc
     pos = lax.broadcasted_iota(jnp.int32, (S, K), 1)
@@ -604,11 +631,10 @@ def ragged_prefill_reference(q, kv_pool, page_row, q_start, scale=None,
             g = g.astype(jnp.float32) * \
                 pscale[page_row][:, None, None, None]
         g = jnp.moveaxis(g, 1, 0)               # (H, n_pages, ps, D)
-        return g.reshape(H, K, D)
+        return g.reshape(g.shape[0], K, D)
 
     k, v = _split(kv_pool[page_row])
-    k = window(k, k_scale)
-    v = window(v, v_scale)
+    k, v = _per_query_head(window(k, k_scale), window(v, v_scale), H, 0)
     s = jnp.einsum("chd,hkd->chk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * sc
     pos_k = lax.broadcasted_iota(jnp.int32, (C, K), 1)

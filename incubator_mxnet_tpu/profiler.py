@@ -47,7 +47,8 @@ from .base import getenv_bool
 __all__ = ["set_config", "start", "stop", "dump", "dumps", "pause", "resume",
            "scope", "scoped", "ProfileEvent", "Counter", "Marker", "mfu",
            "state_string", "session_live", "scope_table",
-           "compile_events", "compile_counts", "attention_dispatch"]
+           "compile_events", "compile_counts", "attention_dispatch",
+           "ssm_dispatch"]
 
 _lock = threading.Lock()
 _config = {
@@ -428,6 +429,16 @@ def attention_dispatch(reset: bool = False) -> Dict[str, int]:
     ``ops.pallas_attention``'s; read it after a first step, beside
     ``compile_events()``."""
     from .ops.pallas_attention import dispatch_tally
+    return dispatch_tally(reset=reset)
+
+
+def ssm_dispatch(reset: bool = False) -> Dict[str, int]:
+    """Which implementation each state-space call site got, counted as
+    the sites were traced: ``ssm_decode_pallas`` (the one-token state
+    update kernel ``mxtpu_ssm_decode``), ``ssm_decode_jnp`` (its twin,
+    off the TPU), ``ssm_chunk_jnp`` (the chunked scan of a prefill
+    chunk or a whole sequence). The tally is ``ops.ssm_scan``'s."""
+    from .ops.ssm_scan import dispatch_tally
     return dispatch_tally(reset=reset)
 
 

@@ -118,6 +118,7 @@ from .events import EventType, resolve_recorder, terminal_fields
 from .outcomes import Outcome
 from .paged_kv import (NULL_PAGE, KVTierStore, PageAllocator, PrefixIndex,
                        init_kv_pools, kv_quant_spec, page_scales,
+                       read_slot_rows, write_slot_rows, zero_slot_rows,
                        write_block_kv, write_block_kv_q,
                        write_prompt_kv, write_prompt_kv_q,
                        write_token_kv, write_token_kv_q)
@@ -288,10 +289,12 @@ def _next_pow2(n: int) -> int:
 
 class InferenceEngine:
     """Fixed-slot continuous-batching decode over a model that brings
-    ``kv_geometry()`` and ``cached_forward(ids, pos, attend, last_row)``
-    (models/gpt.py::GPTModel; docs/SERVING.md "What the engine asks of
-    a model"): the model owns its math, the engine's programs own
-    where keys and values are kept and how they are read.
+    ``cache_layout()`` and ``cached_forward(ids, pos, attend, last_row,
+    state, real)`` (models/gpt.py::GPTModel, models/granite_hybrid.py::
+    GraniteHybridModel; docs/SERVING.md "What the engine asks of a
+    model"): the model owns its math, the engine's programs own where
+    keys and values (a page pool a layer that keeps them) and state
+    rows (the state cache) are kept and how they are read.
 
     ``num_pages`` defaults to the worst case (every slot at max_len) so
     admission never stalls; shrink it to trade admission concurrency
@@ -444,8 +447,47 @@ class InferenceEngine:
                 f"({self.chunk_pages * self.page_size} tokens) — a long "
                 f"prompt could never make progress")
 
-        L, H, D = model.kv_geometry()
+        # what the model says a cache has to hold, a layer an entry:
+        # keys and values a position (a page pool) or state rows a
+        # sequence (the state cache; docs/SERVING.md "State cache")
+        layout = model.cache_layout()
+        kv_layers = [i for i, lay in enumerate(layout)
+                     if lay["kind"] == "kv"]
+        self._state_layers = [i for i, lay in enumerate(layout)
+                              if lay["kind"] == "state"]
+        geom = {(layout[i]["kv_heads"], layout[i]["head_dim"],
+                 layout[i]["scale"]) for i in kv_layers}
+        if len(geom) != 1:
+            raise MXNetError(
+                "the engine keeps one page geometry: the model's "
+                f"attention layers declare {sorted(geom)}")
+        (H, D, self._attn_scale), = geom
+        L = len(kv_layers)
         self._H, self._D = H, D
+        # model layer -> its pool / its state rows among their own kind
+        self._pool_of = {i: j for j, i in enumerate(kv_layers)}
+        self._state_of = {i: j for j, i in enumerate(self._state_layers)}
+        if self._state_layers:
+            # a position's keys and values can be shared, resent or
+            # rolled back; a sequence's state cannot (yet): every
+            # feature that leans on the first refuses the second
+            refused = [
+                (prefix_cache, "prefix_cache=True: a cached prefix's "
+                 "pages carry no state to resume from (state snapshots "
+                 "at page boundaries are not built)"),
+                (int(spec_k) > 0, "spec_k > 0: a rejected draft would "
+                 "have advanced the state and it cannot be rolled back"),
+                (kv_tiers is not None, "kv_tiers: tiers hold prefix "
+                 "pages, which a state layer cannot resume from"),
+                (kv_quant is not None, "kv_quant: not measured with "
+                 "state layers"),
+                (mesh is not None, "mesh: the state update kernel is "
+                 "per-chip and the state cache is not sharded")]
+            for hit, why in refused:
+                if hit:
+                    raise MXNetError(
+                        "the model has state layers (recurrent rows a "
+                        f"sequence, not pages a position); refusing {why}")
         # quantized KV pools (docs/SERVING.md "Quantized KV cache"):
         # int8/fp8 page payload + per-page symmetric scales. The amax
         # arrays are HOST-OWNED page metadata (np, one (P,) f32 per
@@ -471,6 +513,16 @@ class InferenceEngine:
                                 for _ in range(L))
         else:
             self._kamax = self._vamax = ()
+        # the state cache: one array a named row a state layer,
+        # (num_slots, *row shape), donated to and aliased through every
+        # program as the pools are. A slot's rows are zeroed at (every)
+        # admission and untouched while the slot is dead or prefilling.
+        self._state_spec = [layout[i]["rows"] for i in self._state_layers]
+        self._states = tuple(
+            {name: jnp.zeros((self.num_slots,) + tuple(shape), dtype)
+             for name, (shape, dtype) in rows.items()}
+            for rows in self._state_spec)
+        self.state_zero_trace_count = 0
 
         # model params are TRACED INPUTS of the decode/prefill programs
         # (not closure constants): warm-restarting new weights into a
@@ -652,7 +704,8 @@ class InferenceEngine:
         self.preempt_handoff = None
 
         self._decode_step = jax.jit(self._decode_step_fn,
-                                    donate_argnums=(1,))
+                                    donate_argnums=(1, 17))
+        self._zero_state_jit = None
         self._prefill_jits = {}          # bucket_pages -> jitted dense fn
         self._chunk_jits = {}            # bucket_pages -> jitted chunk fn
         # program name -> (jitted fn, abstract args of its first
@@ -750,6 +803,7 @@ class InferenceEngine:
         runs in."""
         kv = jnp.concatenate([k, v], axis=-1)
         spec = self._kv_spec
+        i = self._pool_of[i]
         if spec is None:
             pools[i] = write(pools[i], kv, *where)
             return None, None, pools[i].dtype
@@ -758,13 +812,57 @@ class InferenceEngine:
         return (page_scales(kamax[i], spec), page_scales(vamax[i], spec),
                 self._dtype)
 
+    def _state_closure(self, states, slot=None):
+        """(the program's list of state rows, the ``state(i, update)``
+        closure a model's ``cached_forward`` takes): layer ``i``'s rows
+        go to the model's ``update`` and what it hands back is kept, so
+        the model never learns where rows live. ``slot`` None: the rows
+        of every slot, as they lie (a decode step; the model's update
+        leaves a dead slot's rows as they were). A traced ``slot``: that
+        one slot's rows, cut out and put back (a prefill program).
+        read_slot_rows / write_slot_rows through this module's globals:
+        the dropped-carry test patches them here."""
+        new_states = [dict(rows) for rows in states]
+
+        def state(i, update):
+            j = self._state_of[i]
+            rows = new_states[j] if slot is None \
+                else read_slot_rows(new_states[j], slot)
+            y, new = update(rows, interpret=self._interpret)
+            new_states[j] = new if slot is None \
+                else write_slot_rows(new_states[j], new, slot)
+            return y
+
+        return new_states, state
+
+    def _zero_state_fn(self, states, slot):
+        """A slot's state rows back to zero, every state layer, in
+        place: what admission runs before a prompt's first position.
+        ``slot`` is traced — one compile, ever."""
+        self.state_zero_trace_count += 1     # trace-time only
+        return tuple(zero_slot_rows(rows, slot) for rows in states)
+
+    def _zero_state(self, slot_idx: int) -> bool:
+        """Zero ``slot_idx``'s state rows; False for a model that keeps
+        none."""
+        if not self._states:
+            return False
+        if self._zero_state_jit is None:
+            self._zero_state_jit = jax.jit(self._zero_state_fn,
+                                           donate_argnums=(0,))
+        self._states = self._zero_state_jit(self._states,
+                                            np.int32(slot_idx))
+        return True
+
     def _ragged_attn(self, q, pool, page_table, lengths, ks=None,
                      vs=None):
         if self._mesh is not None:
             return ragged_attention_reference(q, pool, page_table,
-                                              lengths, k_scale=ks,
-                                              v_scale=vs)
+                                              lengths,
+                                              scale=self._attn_scale,
+                                              k_scale=ks, v_scale=vs)
         return ragged_paged_attention(q, pool, page_table, lengths,
+                                      scale=self._attn_scale,
                                       interpret=self._interpret,
                                       k_scale=ks, v_scale=vs)
 
@@ -787,10 +885,12 @@ class InferenceEngine:
             return out[:, None]
         if self._mesh is not None:
             return ragged_verify_reference(q, pool, page_table,
-                                           lengths, k_scale=ks,
-                                           v_scale=vs)
+                                           lengths,
+                                           scale=self._attn_scale,
+                                           k_scale=ks, v_scale=vs)
         return ragged_verify_attention(q, pool, page_table, lengths,
                                        draft_len=draft_len,
+                                       scale=self._attn_scale,
                                        interpret=self._interpret,
                                        k_scale=ks, v_scale=vs)
 
@@ -798,10 +898,12 @@ class InferenceEngine:
                       ks=None, vs=None):
         if self._mesh is not None:
             return ragged_prefill_reference(q, pool, page_row, start,
+                                            scale=self._attn_scale,
                                             n_real=n_real, k_scale=ks,
                                             v_scale=vs)
         return ragged_prefill_attention(q, pool, page_row, start,
                                         n_real=n_real,
+                                        scale=self._attn_scale,
                                         interpret=self._interpret,
                                         k_scale=ks, v_scale=vs)
 
@@ -910,7 +1012,7 @@ class InferenceEngine:
     def _decode_step_fn(self, param_vals, pools, kamax, vamax,
                         tokens, draft_len, page_table, lengths, temps,
                         slot_keys, top_k, top_p, rep_pen, pres_pen,
-                        counts, bias, mask):
+                        counts, bias, mask, states=()):
         """ONE decode/verify step for every slot: W token positions per
         slot — the last accepted token plus up to W - 1 draft
         candidates — embedded, written into the tail pages, and scored
@@ -949,6 +1051,7 @@ class InferenceEngine:
         eff_len = jnp.where(act, lengths + 1, 0)
         new_pools = list(pools)
         new_ka, new_va = list(kamax), list(vamax)
+        new_states, state = self._state_closure(states)
 
         def attend(i, q, k, v):              # (S, W, H, D)
             # quantize-at-write: the page's scales grow with the
@@ -958,14 +1061,16 @@ class InferenceEngine:
             ks, vs, adt = self._write_kv(
                 write_block_kv, write_block_kv_q, new_pools, new_ka,
                 new_va, i, k, v, write_page, write_off)
-            out = self._verify_attn(q.astype(adt), new_pools[i],
+            out = self._verify_attn(q.astype(adt),
+                                    new_pools[self._pool_of[i]],
                                     page_table, eff_len, draft_len,
                                     ks, vs)
             return out.astype(q.dtype)
 
         with self._model_scope(param_vals):
-            logits = self.model.cached_forward(tokens, emb_pos,
-                                               attend)    # (S, W, V)
+            logits = self.model.cached_forward(
+                tokens, emb_pos, attend, state=state,
+                real=act[:, None] & used)                 # (S, W, V)
         emitted, n_emit = self._accept_emit(
             logits, tokens, draft_len, temps, slot_keys, pos, act,
             top_k=top_k, top_p=top_p, rep_pen=rep_pen,
@@ -985,11 +1090,12 @@ class InferenceEngine:
                           used, axis=-1) & act
             emitted = jnp.where(bad[:, None], -emitted - 1, emitted)
         return (tuple(new_pools), tuple(new_ka), tuple(new_va), emitted,
-                n_emit, new_lengths)
+                n_emit, new_lengths, tuple(new_states))
 
     def _prefill_fn(self, param_vals, pools, kamax, vamax,
                     ids, t0, pages, temp, key, top_k, top_p, rep_pen,
-                    pres_pen, counts, bias, vocab_mask):
+                    pres_pen, counts, bias, vocab_mask, states=(),
+                    slot=None):
         """Prompt forward for ONE request (ids (1, Tpad) padded): dense
         causal attention inside the prompt (the prompt attends only
         itself), K/V scattered into the slot's pages, and the FIRST
@@ -1007,6 +1113,7 @@ class InferenceEngine:
         mask = ((pos_k <= pos_q) & (pos_k < t0))[None, None]
         new_pools = list(pools)
         new_ka, new_va = list(kamax), list(vamax)
+        new_states, state = self._state_closure(states, slot)
 
         def attend(i, q, k, v):              # (1, Tpad, H, D)
             # quantized pools take the prompt's pages at FRESH per-page
@@ -1015,11 +1122,15 @@ class InferenceEngine:
             # quantization error)
             self._write_kv(write_prompt_kv, write_prompt_kv_q, new_pools,
                            new_ka, new_va, i, k[0], v[0], pages)
-            return _sdpa(q, k, v, mask=mask)
+            rep = q.shape[2] // k.shape[2]
+            if rep > 1:
+                k, v = (jnp.repeat(t, rep, axis=2) for t in (k, v))
+            return _sdpa(q, k, v, mask=mask, scale=self._attn_scale)
 
         with self._model_scope(param_vals):
             logits = self.model.cached_forward(
-                ids, pos, attend, last_row=t0 - 1)[:, 0]
+                ids, pos, attend, last_row=t0 - 1, state=state,
+                real=pos < t0)[:, 0]
         # the first generated token occupies position t0: its draw is
         # keyed by fold_in(request_key, t0), the engine-wide convention
         tok = self._sample_one(logits[0], temp,
@@ -1029,12 +1140,13 @@ class InferenceEngine:
         if self.guard_nonfinite:             # sign-encoded, see decode
             tok = jnp.where(jnp.any(~jnp.isfinite(logits)),
                             -tok - 1, tok)
-        return tuple(new_pools), tuple(new_ka), tuple(new_va), tok
+        return (tuple(new_pools), tuple(new_ka), tuple(new_va), tok,
+                tuple(new_states))
 
     def _chunk_prefill_fn(self, param_vals, pools, kamax,
                           vamax, ids, start, n_real, page_row, temp,
                           key, top_k, top_p, rep_pen, pres_pen, counts,
-                          bias, vocab_mask):
+                          bias, vocab_mask, states=(), slot=None):
         """ONE prefill chunk of ONE slot's prompt: ids (1, Cpad) holds
         ``n_real`` prompt tokens at absolute positions ``start + i``.
         Their K/V is scattered into the slot's pages (padded tokens land
@@ -1058,6 +1170,7 @@ class InferenceEngine:
         tok_off = pos[0] % ps
         new_pools = list(pools)
         new_ka, new_va = list(kamax), list(vamax)
+        new_states, state = self._state_closure(states, slot)
 
         def attend(i, q, k, v):              # (1, Cpad, H, D)
             # write_token_kv through this module's global: the
@@ -1065,13 +1178,15 @@ class InferenceEngine:
             ks, vs, adt = self._write_kv(
                 write_token_kv, write_token_kv_q, new_pools, new_ka,
                 new_va, i, k[0], v[0], tok_pages, tok_off)
-            out = self._prefill_attn(q[0].astype(adt), new_pools[i],
+            out = self._prefill_attn(q[0].astype(adt),
+                                     new_pools[self._pool_of[i]],
                                      page_row, start, n_real, ks, vs)
             return out.astype(q.dtype)[None]
 
         with self._model_scope(param_vals):
             logits = self.model.cached_forward(
-                ids, pos, attend, last_row=n_real - 1)[:, 0]
+                ids, pos, attend, last_row=n_real - 1, state=state,
+                real=live[None])[:, 0]
         # on the FINAL chunk start + n_real == t0, so the draw key
         # matches the dense prefill's exactly — chunked vs monolithic
         # prefill emit the identical first token even at temperature
@@ -1082,7 +1197,8 @@ class InferenceEngine:
         if self.guard_nonfinite:             # sign-encoded, see decode
             tok = jnp.where(jnp.any(~jnp.isfinite(logits)),
                             -tok - 1, tok)
-        return tuple(new_pools), tuple(new_ka), tuple(new_va), tok
+        return (tuple(new_pools), tuple(new_ka), tuple(new_va), tok,
+                tuple(new_states))
 
     def _copy_page_fn(self, pools, src, dst):
         """COW boundary copy: duplicate one page's K/V across every
@@ -1386,6 +1502,16 @@ class InferenceEngine:
                 sum(p.nbytes for p in self._kvpools) +
                 sum(a.nbytes for a in self._kamax) +
                 sum(a.nbytes for a in self._vamax)),
+            # the state cache (docs/SERVING.md "State cache"): how many
+            # layers keep rows a slot, one slot's rows of one layer
+            # ({name: (shape, dtype)}), and the bytes all of it pins
+            "state_layers": len(self._states),
+            "state_row_shapes": {
+                name: (tuple(shape), str(jnp.dtype(dtype)))
+                for name, (shape, dtype) in
+                (self._state_spec[0] if self._state_spec else {}).items()},
+            "state_cache_bytes": int(sum(
+                a.nbytes for rows in self._states for a in rows.values())),
             "kv_quantized_pages": (
                 self.num_pages - 1 - self._alloc.free_count
                 if self._kv_spec is not None else 0),
@@ -2064,6 +2190,9 @@ class InferenceEngine:
         self._lengths[slot_idx] = 0
         self._temps[slot_idx] = 0.0
         self._restore_stream_state(slot_idx, slot)
+        # a sequence's state starts from nothing, at every admission: a
+        # preempted request re-prefills from its first position
+        state_zeroed = self._zero_state(slot_idx)
         if partial is not None:
             # COW: the boundary page becomes a private copy; drop
             # the temporary pin on the cached source
@@ -2073,6 +2202,7 @@ class InferenceEngine:
             self._component, EventType.ADMIT,
             request_id=req.request_id, tier=req.tier.value,
             slot=slot_idx, t0=t0, cached_len=cached_len,
+            state_zeroed=state_zeroed,
             queue_delay_s=(slot.t_admit - req.submit_time
                            if req.submit_time is not None else None))
 
@@ -2144,6 +2274,16 @@ class InferenceEngine:
                 len(self._kvpools), (self._H, self.page_size, self._D),
                 str(self._kvpools[0].dtype))
 
+    def _refuse_capsule(self, what: str):
+        """A capsule carries pages; a model with state layers has rows a
+        sequence beside them that the wire format does not carry, and a
+        slot resumed without them would serve wrong tokens silently."""
+        if self._states:
+            raise MXNetError(
+                f"page transport ({what}): the model has state layers "
+                "(recurrent rows a sequence) and a capsule carries "
+                "pages only; migrate by replay, not by transfer")
+
     def decode_ready(self, request_id: int) -> bool:
         """True when ``request_id`` holds a slot past prefill — the
         only state a slot is page-capturable from (a prefilling slot's
@@ -2165,6 +2305,7 @@ class InferenceEngine:
         (source death mid-transfer) leaves the slot exactly as it was.
         None when the request holds no slot here or is still
         prefilling."""
+        self._refuse_capsule("capture")
         for i, slot in enumerate(self._slots):
             if slot is not None and \
                     slot.request.request_id == request_id:
@@ -2231,6 +2372,7 @@ class InferenceEngine:
         (``n_pos != len(attempt) - 1``). A mid-install abort (chaos:
         destination death) frees the allocated pages and refuses —
         ``audit_pages`` stays clean on the destination too."""
+        self._refuse_capsule("install")
         if request.outcome is not None:
             return False
         slot_idx = next((i for i in range(self.num_slots)
@@ -2358,14 +2500,15 @@ class InferenceEngine:
         pages_arr[:prompt_pages] = slot.row[:prompt_pages]
         fn = self._prefill_jits.get(bucket)
         if fn is None:
-            fn = jax.jit(self._prefill_fn, donate_argnums=(1,))
+            fn = jax.jit(self._prefill_fn, donate_argnums=(1, 16))
             self._prefill_jits[bucket] = fn
-        self._kvpools, ka, va, tok = self._dispatch(
+        self._kvpools, ka, va, tok, self._states = self._dispatch(
             ("dense", Tpad), fn,
             self._param_vals, self._kvpools, self._kamax,
             self._vamax, ids, np.int32(t0), pages_arr,
             np.float32(req.temperature), slot.key,
-            *self._slot_sampling_args(slot_idx))
+            *self._slot_sampling_args(slot_idx), self._states,
+            np.int32(slot_idx))
         self._pull_amax(ka, va)
         slot.prefill_pos = t0
         # mxlint: allow-host-sync(prefill-boundary readback, once per prompt: the sampled first token must reach token_ids)
@@ -2399,14 +2542,15 @@ class InferenceEngine:
         ids[0, :n] = slot.attempt_ids[start:start + n]
         fn = self._chunk_jits.get(bucket)
         if fn is None:
-            fn = jax.jit(self._chunk_prefill_fn, donate_argnums=(1,))
+            fn = jax.jit(self._chunk_prefill_fn, donate_argnums=(1, 17))
             self._chunk_jits[bucket] = fn
-        self._kvpools, ka, va, tok = self._dispatch(
+        self._kvpools, ka, va, tok, self._states = self._dispatch(
             ("chunk", Cpad), fn,
             self._param_vals, self._kvpools, self._kamax,
             self._vamax, ids, np.int32(start), np.int32(n),
             slot.row.copy(), np.float32(req.temperature), slot.key,
-            *self._slot_sampling_args(slot_idx))
+            *self._slot_sampling_args(slot_idx), self._states,
+            np.int32(slot_idx))
         self._pull_amax(ka, va)
         slot.prefill_pos = start + n
         # mxlint: allow-host-sync(chunk-boundary readback, once per chunk: the guard flag and tail token gate the next chunk)
@@ -2741,13 +2885,14 @@ class InferenceEngine:
         else:
             samp_ops = self._neutral_step_ops(W)
         t_start = time.perf_counter()
-        self._kvpools, ka, va, emitted, n_emit, lengths = \
+        self._kvpools, ka, va, emitted, n_emit, lengths, self._states = \
             self._dispatch("decode" if W == 1 else "verify",
                            self._decode_step, self._param_vals,
                            self._kvpools, self._kamax,
                            self._vamax, tokens, draft_len, table_dev,
                            lengths_dev, self._temps.copy(),
-                           self._slot_keys.copy(), *samp_ops)
+                           self._slot_keys.copy(), *samp_ops,
+                           self._states)
         self._pull_amax(ka, va)
         # THE designed per-step host sync: the scheduler needs the
         # emitted tokens/acceptance counts to advance slots; everything
@@ -2769,7 +2914,8 @@ class InferenceEngine:
                          ts=t_start, step=self.decode_steps, width=W,
                          live=len(live), dur_s=dt, pages=int(
                              (-(-new_lengths[live] // self.page_size))
-                             .sum()))
+                             .sum()),
+                         state_rows=len(live) * len(self._states))
         for s in live:
             if emitted[s, 0] < 0:            # sign-encoded guard flag
                 # poisoned verify: NOTHING from this step is recorded —

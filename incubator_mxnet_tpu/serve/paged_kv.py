@@ -48,6 +48,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..base import MXNetError
 from ..ops.quantization import (quantize_symmetric, requantize_symmetric,
@@ -1011,3 +1012,30 @@ def write_prompt_kv(pool, kv, pages):
     paged = kv.reshape(n_pages, ps, kv.shape[1], kv.shape[2]) \
         .transpose(0, 2, 1, 3)                  # (n_pages, H, ps, 2D)
     return pool.at[pages].set(paged.astype(pool.dtype))
+
+
+# --------------------------------------------------------------------- #
+# the state cache: rows that exist once a sequence, not once a position
+# --------------------------------------------------------------------- #
+
+def read_slot_rows(rows, slot):
+    """One slot's rows of a state layer, ``{name: (1, ...)}``, cut out of
+    ``{name: (num_slots, ...)}``: what a prefill program hands the model.
+    ``slot`` is traced."""
+    return {name: lax.dynamic_index_in_dim(a, slot, 0, keepdims=True)
+            for name, a in rows.items()}
+
+
+def write_slot_rows(rows, new, slot):
+    """``rows`` with ``slot``'s rows replaced by ``new`` (``{name:
+    (1, ...)}``), in place under donation."""
+    return {name: lax.dynamic_update_index_in_dim(
+        a, new[name].astype(a.dtype), slot, 0) for name, a in rows.items()}
+
+
+def zero_slot_rows(rows, slot):
+    """``rows`` with ``slot``'s rows zero: a sequence's state before its
+    first position."""
+    return {name: lax.dynamic_update_index_in_dim(
+        a, jnp.zeros((1,) + a.shape[1:], a.dtype), slot, 0)
+        for name, a in rows.items()}

@@ -243,6 +243,37 @@ def test_decode_step_counts_the_pages_its_attention_walked(model):
     assert got == want, (got, want)
 
 
+def test_state_cache_events_say_what_was_zeroed_and_updated(model):
+    """A model with state layers: ``ADMIT.state_zeroed`` is true at every
+    admission and ``DECODE_STEP.state_rows`` counts a row a live slot a
+    state layer; a model without state layers reads false and 0."""
+    from granite_hybrid_util import build_model, draw_weights, tiny_config
+    cfg = tiny_config()
+    hybrid = build_model(cfg, draw_weights(cfg, 3))
+    rng = np.random.RandomState(11)
+    eng = InferenceEngine(hybrid, num_slots=2, page_size=8, max_len=64,
+                          prefix_cache=False, chunk_pages=2)
+    reqs = [Request(rng.randint(0, 256, n).astype(np.int32),
+                    max_new_tokens=5) for n in (3, 20, 9)]
+    for r in reqs:
+        assert eng.submit(r)
+    _drain(eng, reqs)
+    admits = eng.flight.events(etype=EventType.ADMIT)
+    assert [e.data["state_zeroed"] for e in admits] == [True] * 3
+    steps = eng.flight.events(etype=EventType.DECODE_STEP)
+    assert steps and all(e.data["state_rows"] == 5 * e.data["live"]
+                         for e in steps)
+    assert eng.state_zero_trace_count == 1
+    plain = InferenceEngine(model, num_slots=2, page_size=8, max_len=64)
+    req = Request(_prompt(rng, 5), max_new_tokens=3)
+    assert plain.submit(req)
+    _drain(plain, [req])
+    assert [e.data["state_zeroed"] for e in
+            plain.flight.events(etype=EventType.ADMIT)] == [False]
+    assert all(e.data["state_rows"] == 0 for e in
+               plain.flight.events(etype=EventType.DECODE_STEP))
+
+
 def test_nonfinite_quarantine_emits_terminal():
     # PRIVATE model: NaNWeights poisons the weights in place via
     # warm_start — the shared module fixture must never see it

@@ -255,7 +255,8 @@ def test_gpt_decode_forward_logits_match_full_forward(caller):
     m.initialize()
     rng = np.random.RandomState(0)
     ids = nd.array(rng.randint(0, 64, (2, 16)), dtype="int32")
-    assert m.kv_geometry() == (2, 4, 32)
+    assert m.cache_layout() == [{"kind": "kv", "kv_heads": 4,
+                                 "head_dim": 32, "scale": 32 ** -0.5}] * 2
     with autograd.predict_mode():
         full = m(ids).asnumpy()                       # (2, 16, 64)
         caches = g.init_kv_cache(m, 2, max_len=16)

@@ -211,8 +211,69 @@ def test_trace_pass_follows_a_closure_into_the_model_it_is_handed_to(
     assert not [f for f in found if "captures" in f.message]
 
 
+def test_trace_pass_follows_a_seam_with_two_models_and_keyword_closures(
+        tmp_path):
+    """The seam's second user: two model classes define the method the
+    engine calls on the model it holds, and the closures are handed by
+    keyword. Both models' bodies and both closures run under the trace;
+    a method name that many classes share is still not followed."""
+    files = {
+        "incubator_mxnet_tpu/models/seam_a.py": """
+            class ModelA:
+                def cached_forward(self, ids, attend, state=None):
+                    return attend(0, ids) + float(ids)
+        """,
+        "incubator_mxnet_tpu/models/seam_b.py": """
+            class ModelB:
+                def cached_forward(self, ids, attend, state=None):
+                    return state(0, ids) + int(ids)
+        """,
+        "incubator_mxnet_tpu/models/many.py": """
+            class P:
+                def common(self, x):
+                    return float(x)
+
+            class Q:
+                def common(self, x):
+                    return float(x)
+
+            class R:
+                def common(self, x):
+                    return float(x)
+
+            class S:
+                def common(self, x):
+                    return float(x)
+        """,
+        "incubator_mxnet_tpu/serve/seam_engine2.py": """
+            import jax
+
+
+            class Engine:
+                def __init__(self, model):
+                    self.model = model
+                    self._step = jax.jit(self._step_fn)
+
+                def _step_fn(self, pool, ids):
+                    def attend(i, q):
+                        return pool + bool(q)
+
+                    def state(i, q):
+                        return pool + bool(q)
+
+                    self.model.common(ids)
+                    return self.model.cached_forward(ids, attend=attend,
+                                                     state=state)
+        """,
+    }
+    found = _findings(tmp_path, files, rule="trace-host-leak")
+    assert {f.symbol for f in _active(found)} == {
+        "ModelA.cached_forward", "ModelB.cached_forward",
+        "Engine._step_fn.attend", "Engine._step_fn.state"}
+
+
 def test_serve_imports_no_private_name_of_models():
-    """serve/ asks a model for ``kv_geometry`` and ``cached_forward``
+    """serve/ asks a model for ``cache_layout`` and ``cached_forward``
     (docs/SERVING.md "What the engine asks of a model") and reads none
     of its insides: no underscore name crosses from models/ into
     serve/, which is what keeps the scheduler from growing back into

@@ -1033,3 +1033,87 @@ def test_chunk_walk_takes_the_heads_a_group_a_loop_turn():
     got, ref, rows = _walk("prefill", rng, pool, pt, live, C=256)
     assert rows == live
     _assert_walk(got, ref, rows, live)
+
+
+# --------------------------------------------------------------------- #
+# grouped queries: Hq query heads over H key-value heads of the pool
+# --------------------------------------------------------------------- #
+
+def _repeat_heads(kp, vp, rep):
+    """The pool a plain multi-head model would hold if every query head
+    had its own copy of its key-value head: the grouped-query oracle."""
+    return _fuse(jnp.repeat(jnp.asarray(kp), rep, axis=1),
+                 jnp.repeat(jnp.asarray(vp), rep, axis=1))
+
+
+@pytest.mark.parametrize("Hq,H", [(4, 2), (8, 2), (32, 8), (3, 3)],
+                         ids=["4over2", "8over2", "32over8", "equal"])
+@pytest.mark.parametrize("kernel", ["decode", "verify", "prefill"])
+def test_grouped_query_kernels_match_their_references(kernel, Hq, H):
+    """Query head j reads key-value head j // (Hq / H), at a scale that is
+    not ``D ** -0.5``: the interpreted kernels against the jnp references
+    on the H-head pool, and the references against the equal-heads path on
+    a pool with every key-value head repeated (at equal heads that is the
+    same call: the old case). float32: 2e-5 covers the online softmax's
+    other order of sums."""
+    rng = np.random.RandomState(70 + Hq + H)
+    D, ps, max_pages, scale = 32, 8, 5, 1.0 / 64
+    rep = Hq // H
+    lengths = [0, 1, 8, 9, 37]
+    S = len(lengths)
+    _, kp, vp, pt, ln = _make_case(rng, S, H, D, ps, max_pages, lengths,
+                                   num_pages=24)
+    pool, wide = _fuse(kp, vp), _repeat_heads(kp, vp, rep)
+    tol = dict(rtol=2e-5, atol=2e-5)
+    if kernel == "decode":
+        q = jnp.asarray(rng.randn(S, Hq, D), jnp.float32)
+        got = _ragged_pallas(q, pool, pt, ln, scale, True)
+        ref = ragged_attention_reference(q, pool, pt, ln, scale)
+        old = ragged_attention_reference(q, wide, pt, ln, scale)
+        assert got.shape == (S, Hq, D)
+        np.testing.assert_array_equal(np.asarray(got)[0], 0.0)
+    elif kernel == "verify":
+        W = 3
+        q = jnp.asarray(rng.randn(S, W, Hq, D), jnp.float32)
+        lv = jnp.asarray([0, 1, 6, 9, 35], jnp.int32)
+        dl = jnp.full((S,), W - 1, jnp.int32)
+        got = _ragged_verify_pallas(q, pool, pt, lv, dl, scale, True)
+        ref = ragged_verify_reference(q, pool, pt, lv, scale)
+        old = ragged_verify_reference(q, wide, pt, lv, scale)
+        assert got.shape == (S, W, Hq, D)
+    else:
+        C, start, n_real = 16, 21, 13       # a chunk after 21 cached
+        q = jnp.asarray(rng.randn(C, Hq, D), jnp.float32)
+        row = pt[4]
+        got = _ragged_prefill_pallas(q, pool, row, jnp.asarray(
+            [start, n_real], jnp.int32), scale, True)[:n_real]
+        ref = ragged_prefill_reference(q, pool, row, start, scale,
+                                       n_real=n_real)[:n_real]
+        old = ragged_prefill_reference(q, wide, row, start, scale,
+                                       n_real=n_real)[:n_real]
+        assert got.shape == (n_real, Hq, D)
+    np.testing.assert_allclose(got, ref, **tol)
+    np.testing.assert_allclose(ref, old, **tol)
+    # the scale is read: at D ** -0.5 the answer is another
+    if kernel == "decode":
+        other = ragged_attention_reference(q, pool, pt, ln)
+        assert np.abs(np.asarray(other) - np.asarray(ref)).max() > 1e-3
+
+
+def test_grouped_query_dispatchers_take_the_pool_heads_from_the_pool():
+    """The public entries at 4 query heads over a 2-head pool: the jnp path
+    and the interpreted kernel agree, decode and chunk."""
+    rng = np.random.RandomState(77)
+    _, kp, vp, pt, ln = _make_case(rng, 3, 2, 32, 8, 4, [5, 0, 30],
+                                   num_pages=12)
+    pool = _fuse(kp, vp)
+    q = jnp.asarray(rng.randn(3, 4, 32), jnp.float32)
+    a = ragged_paged_attention(q, pool, pt, ln, scale=0.1, interpret=False)
+    b = ragged_paged_attention(q, pool, pt, ln, scale=0.1, interpret=True)
+    np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+    qc = jnp.asarray(rng.randn(8, 4, 32), jnp.float32)
+    a = ragged_prefill_attention(qc, pool, pt[2], 22, n_real=8, scale=0.1,
+                                 interpret=False)
+    b = ragged_prefill_attention(qc, pool, pt[2], 22, n_real=8, scale=0.1,
+                                 interpret=True)
+    np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)

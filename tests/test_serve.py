@@ -52,7 +52,7 @@ class _SeamOnly:
     """Of a model, what the engine's constructor and the seam name
     (docs/SERVING.md "What the engine asks of a model"); any other read
     fails the test."""
-    _ASKED = {"kv_geometry", "cached_forward", "collect_params",
+    _ASKED = {"cache_layout", "cached_forward", "collect_params",
               "vocab_size", "max_length", "_dtype"}
 
     def __init__(self, model):
@@ -734,3 +734,333 @@ def test_chunk_prefill_writes_through_the_engine_modules_global(
                      (8,)) for s in seen), seen
     np.testing.assert_array_equal(np.asarray(req.token_ids, np.int32),
                                   _solo_reference(model, prompt, 3))
+
+
+# --------------------------------------------------------------------- #
+# a model with state layers (models/granite_hybrid.py): recurrent rows a
+# sequence beside the page pool, grouped queries in the ragged kernels
+# --------------------------------------------------------------------- #
+
+@pytest.fixture(scope="module")
+def hybrid():
+    """(config, weights, model): 7 layers m m a m m m a at width 128, the
+    slow-decay draw, so that state hundreds of positions old reaches the
+    logits (tests/granite_hybrid_util.py)."""
+    from granite_hybrid_util import build_model, draw_weights, tiny_config
+    cfg = tiny_config()
+    w = draw_weights(cfg, 5)
+    return cfg, w, build_model(cfg, w)
+
+
+def _hybrid_engine(model, **kw):
+    args = dict(num_slots=3, page_size=8, max_len=128, prefix_cache=False,
+                chunk_pages=2, token_budget=16)
+    args.update(kw)
+    return InferenceEngine(model, **args)
+
+
+def _hybrid_requests(seed=1):
+    rng = np.random.default_rng(seed)
+    # prompt lengths that are no multiple of a page (8) or a chunk (16),
+    # one shorter than a page, one of exactly a chunk
+    return [Request(rng.integers(0, 256, n).astype(np.int32),
+                    max_new_tokens=k, temperature=0.0, eos_id=-1)
+            for n, k in ((37, 9), (5, 20), (16, 6), (23, 12), (50, 5))]
+
+
+def _drive_staggered(eng, reqs):
+    """Two at once, a third at step 3, two more at step 6: slots admitted
+    at different steps, chunks interleaved with decode steps, and slots
+    reused after a finished request."""
+    for r in reqs[:2]:
+        eng.submit(r)
+    step = 0
+    while eng._queue or eng.active_count:
+        eng.step()
+        step += 1
+        if step == 3:
+            eng.submit(reqs[2])
+        if step == 6:
+            eng.submit(reqs[3])
+            eng.submit(reqs[4])
+    eng.audit_pages()
+
+
+def _logit_error(cfg, w, model, eng, reqs, drive):
+    """Drive ``reqs`` through ``eng`` and return how far, at the widest,
+    the logits its programs computed lie from the plain reference's full
+    forward (the recurrence as a recurrence, no cache), over every position
+    a token was served from and every position a prefill chunk ended at,
+    with the count of positions compared. The programs' logits leave the
+    trace through an ordered debug callback on the model's
+    ``cached_forward``; which request and position a program's rows belong
+    to is read off its arguments at the dispatch."""
+    import jax
+    import jax.numpy as jnp
+    import granite_hybrid_reference as ref
+    from granite_hybrid_util import Ops
+    from incubator_mxnet_tpu.serve import engine as eng_mod
+    out, seen = [], []                  # program logits; (request, pos, row)
+    real_forward = model.cached_forward
+
+    def forward(*a, **k):
+        logits = real_forward(*a, **k)
+        jax.debug.callback(lambda x: out.append(np.asarray(x)), logits,
+                           ordered=True)
+        return logits
+
+    real_dispatch = eng_mod.InferenceEngine._dispatch
+
+    def dispatch(self, name, fn, *args):
+        slots = list(self._slots)
+        res = real_dispatch(self, name, fn, *args)
+        jax.effects_barrier()
+        logits = out.pop()
+        assert not out
+        if name == "decode":            # args[7]: lengths; the token fed
+            for s, slot in enumerate(slots):    # sits at position length
+                if slot is not None and args[7][s] > 0:
+                    seen.append((slot.request, int(args[7][s]),
+                                 logits[s, 0]))
+        else:                           # one slot's chunk or whole prompt
+            last = int(args[5]) - 1 if name[0] == "dense" \
+                else int(args[5]) + int(args[6]) - 1
+            seen.append((slots[int(args[-1])].request, last, logits[0, 0]))
+        return res
+
+    model.cached_forward = forward
+    eng_mod.InferenceEngine._dispatch = dispatch
+    try:
+        drive(eng, reqs)
+    finally:
+        del model.cached_forward
+        eng_mod.InferenceEngine._dispatch = real_dispatch
+    want = {}
+    for r in reqs:
+        ids = np.concatenate([np.asarray(r.prompt_ids, np.int32),
+                              np.asarray(r.token_ids[:-1], np.int32)])
+        want[r.request_id] = np.asarray(ref.logits_at(
+            w, jnp.asarray(ids), jnp.arange(ids.size), cfg, Ops))
+    assert {r.request_id for r, _, _ in seen} == set(want)
+    served = sum(len(r.token_ids) for r in reqs)
+    assert len(seen) >= served          # and the chunks that ended no prompt
+    return max(float(np.abs(row - want[r.request_id][pos]).max())
+               for r, pos, row in seen), len(seen)
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["jnp", "kernels-interpreted"])
+def test_hybrid_engine_agrees_with_the_reference_at_every_served_position(
+        hybrid, interpret):
+    """Chunked prefill (prompt lengths that are no multiple of a page or a
+    chunk), then decoding through the page pool and the state cache, several
+    slots admitted at different steps: the programs' LOGITS, not their
+    tokens, against the plain reference at every served position and every
+    chunk's end. float32 on both sides: 2e-7 covers sums taken in another
+    order (the chunked scan, the fused projections, the paged softmax) on
+    logits whose spread is 0.0024; read 7e-9. A slot reused after a
+    finished request serves what a fresh engine serves."""
+    cfg, w, model = hybrid
+    eng = _hybrid_engine(model, interpret=interpret)
+    reqs = _hybrid_requests()
+    err, n = _logit_error(cfg, w, model, eng, reqs, _drive_staggered)
+    assert err < 2e-7 and n >= 52 + 6, (err, n)
+    assert all(r.outcome.name == "MAX_TOKENS" for r in reqs)
+    assert eng.decode_trace_count == 1
+    assert eng.state_zero_trace_count == 1
+    assert set(eng.prefill_trace_counts.values()) == {1}
+    # five requests through three slots: two slots served two requests;
+    # each of the late ones alone on a fresh engine serves the same tokens
+    fresh = _hybrid_engine(model, interpret=interpret)
+    again = _hybrid_requests()[3:]
+    fresh.run(again)
+    assert [r.token_ids for r in again] == [r.token_ids for r in reqs[3:]]
+
+
+def test_hybrid_dense_prefill_serves_what_chunked_prefill_serves(hybrid):
+    _, _, model = hybrid
+    served = []
+    for kw in ({"chunk_pages": None, "token_budget": None}, {}):
+        eng = _hybrid_engine(model, **kw)
+        reqs = _hybrid_requests(seed=2)
+        eng.run(reqs)
+        served.append([r.token_ids for r in reqs])
+    assert served[0] == served[1]
+
+
+def test_hybrid_dead_and_prefilling_slots_keep_their_state_bit_for_bit(
+        hybrid):
+    """A decode step runs every slot; the rows of a slot that is dead, or
+    still prefilling, come back as they went in. The kernels interpreted:
+    the state update skips what is not live."""
+    _, _, model = hybrid
+    eng = _hybrid_engine(model, interpret=True)
+    rng = np.random.default_rng(3)
+    eng.submit(Request(rng.integers(0, 256, 9).astype(np.int32),
+                       max_new_tokens=8, temperature=0.0, eos_id=-1))
+    eng.step()                            # admitted, prefilled: slot 0 live
+    # poison slot 2 (dead) with a pattern; admit a long prompt into slot 1
+    eng._states = tuple({k: a.at[2].set(7.25) for k, a in rows.items()}
+                        for rows in eng._states)
+    eng.submit(Request(rng.integers(0, 256, 60).astype(np.int32),
+                       max_new_tokens=4, temperature=0.0, eos_id=-1))
+    eng.step()                            # slot 1 mid-prefill, slot 0 decodes
+    assert eng._slots[1] is not None and eng._slots[1].prefilling
+    mid = [{k: np.asarray(a[1]) for k, a in rows.items()}
+           for rows in eng._states]
+    before0 = np.asarray(eng._states[0]["ssm"][0])
+    tok = len(eng._slots[0].request.token_ids)
+    # a step with no chunk budget left for slot 1: decode only
+    eng._advance_prefill = lambda: 0
+    eng.step()
+    assert len(eng._slots[0].request.token_ids) == tok + 1
+    assert not np.array_equal(np.asarray(eng._states[0]["ssm"][0]), before0)
+    for rows, was in zip(eng._states, mid):
+        for k, a in rows.items():
+            np.testing.assert_array_equal(np.asarray(a[2]), 7.25)
+            np.testing.assert_array_equal(np.asarray(a[1]), was[k])
+
+
+@pytest.mark.parametrize("fault", ["never-zeroed", "carry-dropped"])
+def test_hybrid_state_faults_show_in_the_logits(hybrid, monkeypatch, fault):
+    """What the agreement test above can see: state left from a slot's last
+    occupant, or a chunk that starts from nothing, moves a logit by more
+    than ten thousand times that test's tolerance (read 6.4e-3 and 8.2e-3,
+    more than the logits' spread)."""
+    from incubator_mxnet_tpu.serve import engine as eng_mod
+    cfg, w, model = hybrid
+    if fault == "never-zeroed":
+        monkeypatch.setattr(eng_mod.InferenceEngine, "_zero_state",
+                            lambda self, slot_idx: False)
+    else:
+        import jax.numpy as jnp
+        real = eng_mod.read_slot_rows
+        monkeypatch.setattr(
+            eng_mod, "read_slot_rows", lambda rows, slot: {
+                k: jnp.zeros_like(a) for k, a in real(rows, slot).items()})
+    eng = _hybrid_engine(model, num_slots=1)
+    err, _ = _logit_error(cfg, w, model, eng, _hybrid_requests(),
+                          lambda e, reqs: e.run(reqs))
+    assert err > 1e-3, err
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["jnp", "kernels-interpreted"])
+def test_hybrid_state_kept_in_bfloat16_shows_in_the_logits(hybrid,
+                                                           interpret):
+    """``state_dtype`` is what the cache keeps the recurrent state in, and
+    float32 is what the agreement above rests on: with the rows in bfloat16
+    (the benchmark's bf16-state control, benchmark/tests/state_faults.py) the
+    engine serves every request and its logits lie twenty times that test's
+    tolerance from the reference: read 4.2e-6 on logits of spread 0.0024. That
+    is a thousandth of what a lost state moves them by, and a hundredth of
+    what bfloat16 matrices do: a check of served tokens under bfloat16
+    matrices cannot tell this state from float32 (PERF.md, Findings, PR 36)."""
+    from granite_hybrid_util import build_model
+    cfg, w, _ = hybrid
+    cfg = dict(cfg, state_dtype="bfloat16")
+    model = build_model(cfg, w)
+    eng = _hybrid_engine(model, interpret=interpret)
+    assert eng.health_snapshot()["state_row_shapes"]["ssm"][1] == "bfloat16"
+    reqs = _hybrid_requests()
+    err, _ = _logit_error(cfg, w, model, eng, reqs, _drive_staggered)
+    assert all(r.outcome.name == "MAX_TOKENS" for r in reqs)
+    assert 1e-6 < err < 1e-4, err
+
+
+@pytest.mark.parametrize("kw,why", [
+    ({"prefix_cache": True}, "prefix_cache"),
+    ({"spec_k": 2}, "spec_k"),
+    ({"prefix_cache": True, "kv_tiers": {"dram_bytes": 1 << 20}},
+     "prefix_cache"),
+    ({"kv_tiers": {"dram_bytes": 1 << 20}}, "kv_tiers"),
+    ({"kv_quant": "int8"}, "kv_quant"),
+    ({"mesh": "tp2"}, "mesh"),
+], ids=["prefix_cache", "spec_k", "prefix+tiers", "kv_tiers", "kv_quant",
+        "mesh"])
+def test_hybrid_engine_refuses_what_assumes_a_position_can_be_shared(
+        hybrid, kw, why):
+    _, _, model = hybrid
+    if kw.get("mesh") == "tp2":
+        import jax
+        from incubator_mxnet_tpu.parallel import mesh as pmesh
+        kw = {"mesh": pmesh.build_mesh(devices=jax.devices()[:2],
+                                       axis_sizes={"tp": 2})}
+    with pytest.raises(MXNetError, match="state layers.*" + why):
+        _hybrid_engine(model, **kw)
+
+
+def test_hybrid_engine_refuses_page_capsules(hybrid):
+    """A capsule carries pages; a slot resumed without its state rows would
+    serve wrong tokens silently, so capture and install raise."""
+    from incubator_mxnet_tpu.serve.transport import PageTransport
+    _, _, model = hybrid
+    src, dst = _hybrid_engine(model), _hybrid_engine(model)
+    req = Request(np.arange(9, dtype=np.int32), max_new_tokens=8,
+                  temperature=0.0, eos_id=-1)
+    src.submit(req)
+    src.step()
+    src.step()
+    with pytest.raises(MXNetError, match="state layers"):
+        PageTransport().capture(src, req.request_id)
+    with pytest.raises(MXNetError, match="state layers"):
+        dst.install_slot(req, [], 9, np.zeros(2, np.uint32))
+    src.run([])
+    src.audit_pages()
+
+
+def test_hybrid_engine_reads_the_model_through_its_seam_only(hybrid):
+    """The second user of the seam: ``cache_layout`` says which layers keep
+    what, ``cached_forward`` takes ``attend``, ``state`` and ``real``."""
+    _, _, model = hybrid
+    served = []
+    for m in (model, _SeamOnly(model)):
+        eng = _hybrid_engine(m)
+        reqs = _hybrid_requests(seed=4)[:3]
+        eng.run(reqs)
+        eng.audit_pages()
+        served.append([list(r.token_ids) for r in reqs])
+    assert served[0] == served[1]
+    snap = eng.health_snapshot()
+    assert snap["state_layers"] == 5 and snap["kv_page_shape"] == (2, 8, 64)
+    assert snap["state_row_shapes"] == {"ssm": ((1, 16, 128), "float32"),
+                                        "conv": ((3, 160), "float32")}
+    assert snap["state_cache_bytes"] == 5 * 3 * (16 * 128 + 3 * 160) * 4
+
+
+def test_gpt_engine_keeps_no_state_cache(model):
+    eng = InferenceEngine(model, num_slots=2, page_size=8, max_len=64)
+    snap = eng.health_snapshot()
+    assert (snap["state_layers"], snap["state_row_shapes"],
+            snap["state_cache_bytes"]) == (0, {}, 0)
+    assert model.cache_layout() == [
+        {"kind": "kv", "kv_heads": 4, "head_dim": 32,
+         "scale": 32 ** -0.5}] * model.num_layers
+
+
+def test_hybrid_preempted_request_resumes_from_a_zeroed_state(hybrid):
+    """A preempted request comes back by re-prefilling its prompt and the
+    tokens it had, into a slot whose state is zeroed again: it ends with the
+    tokens of a run that was never preempted."""
+    from incubator_mxnet_tpu.serve import EventType
+    _, _, model = hybrid
+    rng = np.random.default_rng(6)
+    prompt = rng.integers(0, 256, 21).astype(np.int32)
+    served = []
+    for preempt in (False, True):
+        eng = _hybrid_engine(model, num_slots=1)
+        req = Request(prompt, max_new_tokens=12, temperature=0.0, eos_id=-1)
+        eng.submit(req)
+        for _ in range(6):
+            eng.step()
+        assert 2 <= len(req.token_ids) < 12
+        if preempt:
+            eng._preempt(0, "test")
+            assert eng.active_count == 0
+        eng.run([])
+        eng.audit_pages()
+        served.append(list(req.token_ids))
+        admits = eng.flight.events(etype=EventType.ADMIT)
+        assert [e.data["state_zeroed"] for e in admits] == \
+            [True] * (2 if preempt else 1)
+    assert served[0] == served[1] and len(served[0]) == 12

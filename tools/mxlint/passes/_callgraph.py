@@ -5,11 +5,13 @@ fidelity as a reviewer reading the code: a call to a bare name binds to
 the nested/module function of that name (or the function it was
 imported from, project-wide); ``self.m(...)`` binds to method ``m`` of
 the enclosing class; ``self.held.m(...)`` binds to method ``m`` of the
-one project class that defines it (an engine calling the model it
-holds). A function NAMED as an argument of a call that resolved is
+project classes that define it, up to ``_MAX_IMPLEMENTERS`` of them (an
+engine calling the model it holds: a seam has a few implementers, a
+name that half the project defines says nothing). A function NAMED as
+an argument of a call that resolved, by position or by keyword, is
 reached with it (project code handed a closure runs it: the serving
-programs' ``attend``); what is handed to a library call is not
-followed. Anything more dynamic (getattr, dict-of-functions) is out of
+programs' ``attend`` and ``state``); what is handed to a library call
+is not followed. Anything more dynamic (getattr, dict-of-functions) is out of
 scope; the passes that ride on this are designed so a missed edge means
 a missed finding, never a false one.
 """
@@ -22,6 +24,9 @@ from typing import Dict, List, Optional, Set, Tuple
 from ..core import Project, SourceUnit, dotted, enclosing_scopes, parent
 
 FuncKey = int       # id(FunctionDef node)
+
+# classes that may share a held object's method name and still be followed
+_MAX_IMPLEMENTERS = 3
 
 
 class FuncInfo:
@@ -122,7 +127,8 @@ class CallGraph:
             if head == "self" and rest.count(".") == 1:
                 # a method of an object the instance holds
                 named = self.methods_named.get(func.attr, [])
-                return list(named) if len(named) == 1 else []
+                return list(named) \
+                    if 1 <= len(named) <= _MAX_IMPLEMENTERS else []
             # module-alias call: `import x.y as z; z.f(...)` or
             # `from . import sub; sub.f(...)`
             mod = unit.import_modules.get(head)
@@ -153,7 +159,8 @@ class CallGraph:
                 if isinstance(sub, ast.Call):
                     callees = self.resolve_call(sub, unit, node)
                     work.extend(callees)
-                    for arg in sub.args if callees else ():
+                    handed = list(sub.args) + [k.value for k in sub.keywords]
+                    for arg in handed if callees else ():
                         if isinstance(arg, ast.Name):
                             work.extend(self.resolve_name(arg.id, unit,
                                                           node))
