@@ -640,6 +640,10 @@ class InferenceEngine:
         self.spec_steps = 0                  # steps run K+1 wide
         self.spec_gated_steps = 0            # steps adaptive gating
                                              # suppressed all drafting
+        self.truncate_steps = 0              # steps whose program sorted
+                                             # (a live top-k / top-p slot)
+        self.draw_steps = 0                  # steps whose program drew
+                                             # (a live temperature slot)
 
         self.stop_hits = 0                   # stop-sequence terminals
         self.constrained_requests = 0        # admissions with a grammar
@@ -943,20 +947,18 @@ class InferenceEngine:
         exactly the history a sequential decode would have seen —
         computed in-program from the (known) draft block. The
         degenerate single-allowed-token case (empty residual) force-
-        accepts: p̃ is that point mass."""
+        accepts: p̃ is that point mass.
+
+        The menu's truncations and the whole temperature path each sit
+        under a ``lax.cond`` of this one program, taken when a LIVE
+        slot asks (top-k / top-p on; temperature > 0): a step of
+        greedy, menu-free slots pays for neither, a mixed step pays
+        for every row as before, and either way each row reads what
+        the unbranched code gave it (``truncate_steps`` /
+        ``draw_steps`` count the steps that took them)."""
         S, W = tokens.shape
         V = logits.shape[-1]
         jj = lax.broadcasted_iota(jnp.int32, (S, W), 1)
-        jpos = pos + 1                   # position of column j's token
-        pos_keys = jax.vmap(
-            lambda key, row: jax.vmap(
-                lambda p: jax.random.fold_in(key, p))(row)
-        )(slot_keys, jpos)                               # (S, W, 2)
-        cat_keys = jax.vmap(jax.vmap(
-            lambda k: jax.random.fold_in(k, 0)))(pos_keys)
-        acc_keys = jax.vmap(jax.vmap(
-            lambda k: jax.random.fold_in(k, 1)))(pos_keys)
-        u = jax.vmap(jax.vmap(jax.random.uniform))(acc_keys)   # (S, W)
 
         if top_k is not None:
             # in-window history: column j scores the token AFTER
@@ -966,45 +968,69 @@ class InferenceEngine:
             oh = jax.nn.one_hot(tokens, V, dtype=jnp.int32)
             win_counts = counts[:, None, :] + \
                 jnp.cumsum(oh, axis=1) - oh[:, :1]
+            # a dead row (free, prefilling or stalled: its emission is
+            # never read) asks for nothing: the truncation branch is
+            # taken for what a LIVE slot asks
             logits = constrain_logits(
                 logits, temps[:, None], win_counts, bias[:, None, :],
-                mask, top_k[:, None], top_p[:, None],
+                mask, jnp.where(act, top_k, 0)[:, None],
+                jnp.where(act, top_p, 1.0)[:, None],
                 rep_pen[:, None], pres_pen[:, None])
         greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        scaled = logits.astype(jnp.float32) / \
-            jnp.maximum(temps, 1e-6)[:, None, None]
-        logp = jax.nn.log_softmax(scaled, axis=-1)       # (S, W, V)
         # column j tests/replaces the token at position jpos[:, j] —
         # the draft in tokens column j + 1 (the wrapped last column is
         # never valid: draft_len <= W - 1)
         d_next = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
-        p_next = jnp.take_along_axis(logp, d_next[..., None],
-                                     axis=-1)[..., 0]    # log p_j(d)
-        # residual for a REJECTED draft at column j: q was a point mass
-        # at d, so max(p - q, 0) is p with d's mass removed — mask d's
-        # logit out and renormalize via the categorical itself. Columns
-        # with no draft (j >= draft_len) sample plain p — the bonus
-        # token when every draft was accepted.
         valid = jj < draft_len[:, None]
-        res_logits = scaled + jax.nn.one_hot(
-            d_next, V, dtype=jnp.float32) * \
-            jnp.where(valid, _NEG_BIG, 0.0)[..., None]
-        # an empty residual (every unit of mass sits on the draft —
-        # e.g. a grammar state with ONE legal token) means p̃(d) = 1:
-        # force acceptance instead of resampling from nothing. Tested
-        # on the UNSCALED constrained logits: a temperature divide
-        # could float a masked -1e30 back over the threshold
-        res_empty = ~jnp.any(
-            (logits + jax.nn.one_hot(d_next, V, dtype=jnp.float32) *
-             _NEG_BIG) > _NEG_BIG / 2, axis=-1)
-        accept = jnp.where((temps > 0)[:, None],
-                           (jnp.log(u) < p_next) | res_empty,
-                           d_next == greedy_tok)
+
+        def draw(logits):
+            """The temperature path, every row: taken when a live slot
+            has temperature > 0 (greedy rows select their argmax chain
+            out of it, as they always did)."""
+            jpos = pos + 1               # position of column j's token
+            pos_keys = jax.vmap(
+                lambda key, row: jax.vmap(
+                    lambda p: jax.random.fold_in(key, p))(row)
+            )(slot_keys, jpos)                           # (S, W, 2)
+            cat_keys = jax.vmap(jax.vmap(
+                lambda k: jax.random.fold_in(k, 0)))(pos_keys)
+            acc_keys = jax.vmap(jax.vmap(
+                lambda k: jax.random.fold_in(k, 1)))(pos_keys)
+            u = jax.vmap(jax.vmap(jax.random.uniform))(acc_keys)
+            scaled = logits.astype(jnp.float32) / \
+                jnp.maximum(temps, 1e-6)[:, None, None]
+            logp = jax.nn.log_softmax(scaled, axis=-1)   # (S, W, V)
+            p_next = jnp.take_along_axis(logp, d_next[..., None],
+                                         axis=-1)[..., 0]  # log p_j(d)
+            # residual for a REJECTED draft at column j: q was a point
+            # mass at d, so max(p - q, 0) is p with d's mass removed —
+            # mask d's logit out and renormalize via the categorical
+            # itself. Columns with no draft (j >= draft_len) sample
+            # plain p — the bonus token when every draft was accepted.
+            res_logits = scaled + jax.nn.one_hot(
+                d_next, V, dtype=jnp.float32) * \
+                jnp.where(valid, _NEG_BIG, 0.0)[..., None]
+            # an empty residual (every unit of mass sits on the draft —
+            # e.g. a grammar state with ONE legal token) means p̃(d) = 1:
+            # force acceptance instead of resampling from nothing.
+            # Tested on the UNSCALED constrained logits: a temperature
+            # divide could float a masked -1e30 back over the threshold
+            res_empty = ~jnp.any(
+                (logits + jax.nn.one_hot(d_next, V, dtype=jnp.float32) *
+                 _NEG_BIG) > _NEG_BIG / 2, axis=-1)
+            accept = jnp.where((temps > 0)[:, None],
+                               (jnp.log(u) < p_next) | res_empty,
+                               d_next == greedy_tok)
+            samp = jax.vmap(jax.vmap(jax.random.categorical))(
+                cat_keys, res_logits).astype(jnp.int32)
+            return accept, jnp.where((temps > 0)[:, None], samp,
+                                     greedy_tok)
+
+        accept, final = lax.cond(
+            jnp.any((temps > 0) & act), draw,
+            lambda _: (d_next == greedy_tok, greedy_tok), logits)
         chain = jnp.cumprod((accept & valid).astype(jnp.int32), axis=1)
         n_acc = jnp.sum(chain, axis=1).astype(jnp.int32)
-        samp = jax.vmap(jax.vmap(jax.random.categorical))(
-            cat_keys, res_logits).astype(jnp.int32)
-        final = jnp.where((temps > 0)[:, None], samp, greedy_tok)
         emitted = jnp.where(jj < n_acc[:, None], d_next, final)
         n_emit = jnp.where(act, n_acc + 1, 0).astype(jnp.int32)
         return emitted, n_emit
@@ -2884,6 +2910,15 @@ class InferenceEngine:
                         self._mask_block(drafts, W, live))
         else:
             samp_ops = self._neutral_step_ops(W)
+        # which branches of the sampling tail the step's program will
+        # take, from the host's own copies of what it ships (no
+        # read-back): the sort under a live slot's top-k / top-p, the
+        # draw under a live slot's temperature
+        k, p = self._top_k[live], self._top_p[live]
+        truncating = int((((k > 0) & (k < self._vocab)) | (p < 1.0)).any())
+        drawing = int((self._temps[live] > 0).any())
+        self.truncate_steps += truncating
+        self.draw_steps += drawing
         t_start = time.perf_counter()
         self._kvpools, ka, va, emitted, n_emit, lengths, self._states = \
             self._dispatch("decode" if W == 1 else "verify",
@@ -2915,7 +2950,8 @@ class InferenceEngine:
                          live=len(live), dur_s=dt, pages=int(
                              (-(-new_lengths[live] // self.page_size))
                              .sum()),
-                         state_rows=len(live) * len(self._states))
+                         state_rows=len(live) * len(self._states),
+                         truncating=truncating, drawing=drawing)
         for s in live:
             if emitted[s, 0] < 0:            # sign-encoded guard flag
                 # poisoned verify: NOTHING from this step is recorded —

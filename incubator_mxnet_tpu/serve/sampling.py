@@ -350,7 +350,15 @@ def constrain_logits(logits, temps, counts, bias, mask, top_k, top_p,
     token) and the combination can never leave zero tokens above the
     floor (masked-then-truncated-to-nothing would sample uniform
     garbage). Masked tokens sit at -1e30 where the rejection sampler
-    sees probability 0."""
+    sees probability 0.
+
+    The two truncations, and with them the one (..., V) sort, run
+    under a ``lax.cond`` on ``any(top-k on | top-p on)`` over every
+    leading dim: one scalar a call, one program either way. A call of
+    neutral rows does the elementwise bias/penalty/mask pass and
+    returns; a call in which ONE row asks sorts every row (what every
+    call cost before the branch) and each row reads exactly what the
+    unbranched stages gave it."""
     import jax
     import jax.numpy as jnp
 
@@ -365,35 +373,41 @@ def constrain_logits(logits, temps, counts, bias, mask, top_k, top_p,
     # the vocabulary mask (grammar / constrained decoding) — applied
     # BEFORE top-k/top-p so both truncate within the legal set
     l = jnp.where(mask, l, _NEG_BIG)
-    # top-k: keep the k largest logits (ties at the k-th value kept)
+    # the two truncations need a sorted copy, the dearest thing in a
+    # decode program: one branch, taken when any row asks for either
+    # (inside it the selects hand a neutral row back untouched)
     k_on = (top_k > 0) & (top_k < V)
-    srt = jnp.sort(l, axis=-1)              # ascending
-    kidx = jnp.clip(V - top_k, 0, V - 1)[..., None]
-    kidx = jnp.broadcast_to(kidx, l.shape[:-1] + (1,))
-    kth = jnp.take_along_axis(srt, kidx, axis=-1)
-    l = jnp.where(k_on[..., None] & (l < kth), _NEG_BIG, l)
-    # top-p: smallest prefix of the descending-prob order with
-    # cumulative mass >= p (ties at the threshold prob kept)
     p_on = top_p < 1.0
-    safe_t = jnp.where(temps > 0, jnp.maximum(temps, 1e-6),
-                       1.0)[..., None]
-    # the sorted probs come from the top-k sort already in hand:
-    # flooring below the k-th value commutes with sorting, and exp is
-    # monotone + elementwise — no second O(V log V) sort on the
-    # constrained hot path. One shared max/normalizer keeps sp
-    # BIT-IDENTICAL to a sort of probs (softmax'ing the sorted copy
-    # separately would round its denominator differently, and the
-    # ties-at-the-threshold-kept contract compares probs < thr with
-    # exact equality at the boundary).
-    srt2 = jnp.where(k_on[..., None] & (srt < kth), _NEG_BIG, srt)
-    m = jnp.max(l, axis=-1, keepdims=True)
-    e = jnp.exp(l / safe_t - m / safe_t)
-    z = jnp.sum(e, axis=-1, keepdims=True)
-    probs = e / z
-    sp = (jnp.exp(srt2 / safe_t - m / safe_t) / z)[..., ::-1]
-    csum = jnp.cumsum(sp, axis=-1)
-    keep_sorted = (csum - sp) < top_p[..., None]
-    thr = jnp.min(jnp.where(keep_sorted, sp, jnp.inf), axis=-1,
-                  keepdims=True)
-    l = jnp.where(p_on[..., None] & (probs < thr), _NEG_BIG, l)
-    return l
+
+    def truncate(l):
+        # top-k: keep the k largest logits (ties at the k-th value kept)
+        srt = jnp.sort(l, axis=-1)              # ascending
+        kidx = jnp.clip(V - top_k, 0, V - 1)[..., None]
+        kidx = jnp.broadcast_to(kidx, l.shape[:-1] + (1,))
+        kth = jnp.take_along_axis(srt, kidx, axis=-1)
+        l = jnp.where(k_on[..., None] & (l < kth), _NEG_BIG, l)
+        # top-p: smallest prefix of the descending-prob order with
+        # cumulative mass >= p (ties at the threshold prob kept)
+        safe_t = jnp.where(temps > 0, jnp.maximum(temps, 1e-6),
+                           1.0)[..., None]
+        # the sorted probs come from the top-k sort already in hand:
+        # flooring below the k-th value commutes with sorting, and exp
+        # is monotone + elementwise — no second O(V log V) sort on the
+        # constrained hot path. One shared max/normalizer keeps sp
+        # BIT-IDENTICAL to a sort of probs (softmax'ing the sorted copy
+        # separately would round its denominator differently, and the
+        # ties-at-the-threshold-kept contract compares probs < thr with
+        # exact equality at the boundary).
+        srt2 = jnp.where(k_on[..., None] & (srt < kth), _NEG_BIG, srt)
+        m = jnp.max(l, axis=-1, keepdims=True)
+        e = jnp.exp(l / safe_t - m / safe_t)
+        z = jnp.sum(e, axis=-1, keepdims=True)
+        probs = e / z
+        sp = (jnp.exp(srt2 / safe_t - m / safe_t) / z)[..., ::-1]
+        csum = jnp.cumsum(sp, axis=-1)
+        keep_sorted = (csum - sp) < top_p[..., None]
+        thr = jnp.min(jnp.where(keep_sorted, sp, jnp.inf), axis=-1,
+                      keepdims=True)
+        return jnp.where(p_on[..., None] & (probs < thr), _NEG_BIG, l)
+
+    return jax.lax.cond(jnp.any(k_on | p_on), truncate, lambda l: l, l)

@@ -688,6 +688,12 @@ def test_serving_programs_compile_for_v5e_with_no_pool_relayout(
             r"= \w+\[([\d,]+)\]\S* (copy|transpose)\(", text)
             if math.prod(map(int, shape.split(","))) >= pool_elems // 2]
         assert not moved, (name, moved)
+        # the sampling menu's sort sits in a branch no greedy step takes
+        # (PERF.md, PR 37): the entry computation holds the branches,
+        # not the sort
+        entry = text[text.index("\nENTRY "):]
+        assert " conditional(" in entry and " sort(" not in entry, name
+        assert " sort(" in text, name
         mem = compiled.memory_analysis()
         assert mem.alias_size_in_bytes >= L * pool_elems * 2   # donated
         assert mem.temp_size_in_bytes < pool_elems * 2 // 4, \

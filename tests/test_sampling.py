@@ -515,6 +515,202 @@ def test_preemption_resume_bit_identical_with_sampling(model):
 
 
 # --------------------------------------------------------------------- #
+# the sampling tail's two branches (PR 37): the sort under top-k /
+# top-p and the draw under a temperature run only in a step that
+# holds a live slot asking for them
+# --------------------------------------------------------------------- #
+
+def _unbranched_constrain_logits(logits, temps, counts, bias, mask,
+                                 top_k, top_p, rep_pen, pres_pen):
+    """``constrain_logits`` as it stood before PR 37, every stage in
+    line: the oracle the branched function has to equal bit for bit."""
+    import jax.numpy as jnp
+    neg = -1e30
+    V = logits.shape[-1]
+    l = logits.astype(jnp.float32) + bias
+    pen_on = (rep_pen != 1.0) | (pres_pen != 0.0)
+    penalized = jnp.where(l > 0, l / rep_pen[..., None],
+                          l * rep_pen[..., None]) - pres_pen[..., None]
+    l = jnp.where(pen_on[..., None] & (counts > 0), penalized, l)
+    l = jnp.where(mask, l, neg)
+    k_on = (top_k > 0) & (top_k < V)
+    srt = jnp.sort(l, axis=-1)
+    kidx = jnp.clip(V - top_k, 0, V - 1)[..., None]
+    kidx = jnp.broadcast_to(kidx, l.shape[:-1] + (1,))
+    kth = jnp.take_along_axis(srt, kidx, axis=-1)
+    l = jnp.where(k_on[..., None] & (l < kth), neg, l)
+    p_on = top_p < 1.0
+    safe_t = jnp.where(temps > 0, jnp.maximum(temps, 1e-6),
+                       1.0)[..., None]
+    srt2 = jnp.where(k_on[..., None] & (srt < kth), neg, srt)
+    m = jnp.max(l, axis=-1, keepdims=True)
+    e = jnp.exp(l / safe_t - m / safe_t)
+    z = jnp.sum(e, axis=-1, keepdims=True)
+    probs = e / z
+    sp = (jnp.exp(srt2 / safe_t - m / safe_t) / z)[..., ::-1]
+    csum = jnp.cumsum(sp, axis=-1)
+    keep_sorted = (csum - sp) < top_p[..., None]
+    thr = jnp.min(jnp.where(keep_sorted, sp, jnp.inf), axis=-1,
+                  keepdims=True)
+    return jnp.where(p_on[..., None] & (probs < thr), neg, l)
+
+
+def _primitives(jaxpr, in_branch=False):
+    """``(name, in_branch)`` of every primitive of ``jaxpr`` and of the
+    jaxprs nested in its equations (jit, vmap and custom-call bodies):
+    ``in_branch`` says whether it sits under a ``cond``'s branch, or on
+    the path every call takes."""
+    import jax
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, in_branch
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(
+                sub, in_branch or eqn.primitive.name == "cond")
+
+
+def test_constrain_logits_sorts_only_inside_a_branch():
+    import jax
+    a = _neutral_args((2, 4), 16)
+    logits = np.zeros((2, 4, 16), np.float32)
+    prims = list(_primitives(jax.make_jaxpr(
+        lambda l, kw: constrain_logits(l, **kw))(logits, a).jaxpr))
+    always = {n for n, branch in prims if not branch}
+    assert "cond" in always
+    assert not {"sort", "cumsum", "exp"} & always, sorted(always)
+    assert ("sort", True) in prims
+
+
+def test_decode_step_sorts_and_draws_only_inside_a_branch(model):
+    """The W=1 decode program: no sort and no random draw on the path
+    every step takes (a structural guard against either coming back:
+    on the chip the sort alone was a fifth of a greedy step)."""
+    import jax
+    eng = _eng(model, num_slots=2)
+    _run(eng, [np.array([1, 2, 3], np.int32)], max_new=3)
+    _, args = eng._programs["decode"]
+    prims = list(_primitives(
+        jax.make_jaxpr(eng._decode_step_fn)(*args).jaxpr))
+    always = [n for n, branch in prims if not branch]
+    assert always.count("cond") == 2, always.count("cond")
+    bad = {"sort", "random_bits", "threefry2x32", "random_fold_in"}
+    assert not bad & set(always), sorted(bad & set(always))
+    assert {("sort", True), ("random_bits", True)} <= set(prims)
+
+
+@pytest.mark.parametrize("asks", ["top_k", "top_p", "both"])
+@pytest.mark.parametrize("shape", [(), (3,), (2, 4)],
+                         ids=["scalar", "rows3", "rows2x4"])
+def test_one_asking_row_gets_the_unbranched_values(shape, asks):
+    """Exactly one row asks for a truncation: the branch is taken for
+    the whole batch, every row reads bit for bit what the unbranched
+    function gives, and the rows that asked for nothing come back
+    value-identical."""
+    import jax
+    V = 32
+    rng = np.random.RandomState(len(shape) * 7 + len(asks))
+    logits = rng.randn(*(shape + (V,))).astype(np.float32)
+    a = _neutral_args(shape, V)
+    a["counts"] = rng.randint(0, 2, size=shape + (V,)).astype(np.int32)
+    one = tuple(d - 1 for d in shape)        # the last row asks
+    if asks in ("top_k", "both"):
+        a["top_k"] = np.array(a["top_k"])
+        a["top_k"][one] = 5
+    if asks in ("top_p", "both"):
+        a["top_p"] = np.array(a["top_p"])
+        a["top_p"][one] = 0.6
+    got = np.asarray(jax.jit(constrain_logits)(logits, **a))
+    want = np.asarray(jax.jit(_unbranched_constrain_logits)(logits, **a))
+    assert np.array_equal(got, want)
+    floored = int((got[one] < -1e29).sum())
+    assert floored >= V - 5 if asks != "top_p" else floored > 0
+    neutral = np.ones(shape, bool)
+    neutral[one] = False
+    assert np.array_equal(got[neutral], logits[neutral])
+
+
+# a fixed-seed sampled request beside a greedy one, both engine widths:
+# the tokens the PARENT of PR 37 served (recorded from its tree with
+# these prompts, seeds and the module's model before the branches went in)
+_P0 = [15, 43, 12, 59, 28, 53, 22]
+_P1 = [58, 35, 3, 42, 21, 63, 24, 53, 23]
+_GREEDY_ALONE = [17] * 12
+_SAMPLED = {
+    "top_p": (dict(temperature=0.9, seed=7,
+                   sampling=SamplingParams(top_p=0.7)),
+              {0: [40, 53, 33, 13, 4, 12, 48, 55, 62, 42, 25, 18],
+               3: [40, 53, 33, 13, 4, 12, 48, 55, 62, 42, 25, 18]}),
+    "top_k": (dict(temperature=0.8, seed=8,
+                   sampling=SamplingParams(top_k=5)),
+              {0: [11, 39, 56, 0, 17, 6, 54, 54, 54, 16, 17, 8],
+               3: [11, 39, 56, 0, 17, 6, 54, 54, 15, 16, 17, 8]}),
+    "both": (dict(temperature=1.1, seed=9,
+                  sampling=SamplingParams(top_k=12, top_p=0.8,
+                                          repetition_penalty=1.2)),
+             {0: [17, 16, 33, 56, 4, 8, 13, 18, 17, 30, 48, 20],
+              3: [17, 16, 33, 56, 4, 8, 13, 18, 17, 16, 16, 20]}),
+    "temperature_only": (dict(temperature=0.9, seed=10),
+                         {0: [46, 37, 30, 57, 8, 26, 5, 21, 39, 12, 59,
+                              48],
+                          3: [46, 37, 30, 57, 8, 26, 5, 21, 39, 12, 59,
+                              48]}),
+}
+
+
+@pytest.mark.parametrize("spec_k", [0, 3])
+@pytest.mark.parametrize("case", sorted(_SAMPLED))
+def test_greedy_beside_a_sampled_request_and_the_parents_tokens(
+        model, case, spec_k):
+    """A greedy request that shares its steps with a sampled one (the
+    steps take the branches) emits what it emits alone (where no step
+    does); the sampled request emits the parent's tokens; one decode
+    program and one verify program serve both kinds of step."""
+    kw, want = _SAMPLED[case]
+    alone = _eng(model, spec_k=spec_k)
+    (ga,) = _run(alone, [np.array(_P0, np.int32)], max_new=12)
+    assert list(ga.token_ids) == _GREEDY_ALONE
+    assert alone.truncate_steps == 0 and alone.draw_steps == 0
+    eng = _eng(model, spec_k=spec_k)
+    greedy = Request(np.array(_P0, np.int32), max_new_tokens=12)
+    sampled = Request(np.array(_P1, np.int32), max_new_tokens=12, **kw)
+    eng.run([greedy, sampled])
+    assert list(greedy.token_ids) == list(ga.token_ids)
+    assert list(sampled.token_ids) == want[spec_k]
+    assert eng.decode_trace_count <= 1 and eng.verify_trace_count <= 1
+    assert eng.draw_steps > 0
+    assert (eng.truncate_steps > 0) == ("sampling" in kw)
+    eng.audit_pages()
+
+
+def test_branch_counters_count_the_steps_that_held_such_a_slot(model):
+    """``truncate_steps`` / ``draw_steps`` and ``DECODE_STEP``'s
+    ``truncating`` / ``drawing``: 1 in exactly the steps in which a LIVE
+    slot asked for top-k / top-p, or had a temperature. All three
+    prompts prefill in the first ``step()``, the first token comes from
+    the prefill, so a request of n tokens is live in decode steps
+    1..n-1."""
+    from incubator_mxnet_tpu.events import EventType
+    eng = InferenceEngine(model, num_slots=3, page_size=8, max_len=64)
+    rng = np.random.RandomState(14)
+    prompts = [rng.randint(0, 64, size=(n,)).astype(np.int32)
+               for n in (5, 6, 7)]
+    reqs = [
+        # the menu's path without a truncation: top_k == V is off
+        Request(prompts[0], max_new_tokens=10,
+                sampling=SamplingParams(top_k=64)),
+        Request(prompts[1], max_new_tokens=4, temperature=0.9, seed=1,
+                sampling=SamplingParams(top_p=0.7)),
+        Request(prompts[2], max_new_tokens=6, temperature=0.8, seed=2),
+    ]
+    eng.run(reqs)
+    steps = eng.flight.events(etype=EventType.DECODE_STEP)
+    assert [e.data["live"] for e in steps] == [3] * 3 + [2] * 2 + [1] * 4
+    assert [e.data["truncating"] for e in steps] == [1] * 3 + [0] * 6
+    assert [e.data["drawing"] for e in steps] == [1] * 5 + [0] * 4
+    assert (eng.truncate_steps, eng.draw_steps) == (3, 5)
+    assert eng.decode_steps == 9 and eng.decode_trace_count == 1
+
+
+# --------------------------------------------------------------------- #
 # distribution correctness under truncated proposals
 # --------------------------------------------------------------------- #
 
