@@ -238,20 +238,30 @@ class GPTModel(HybridBlock):
         row before the head. ``state`` and ``real`` are the seam's
         other half, for a model with state layers; this one has none
         and reads neither. Returns logits (B, T or 1, vocab), f32.
-        No dropout: call it outside training mode."""
+        No dropout: call it outside training mode. The parts carry the
+        ``mx.`` scopes of the training forward (docs/SERVING.md "What
+        the engine asks of a model"); ``attend`` runs inside ``mx.attn``."""
         B, T = ids.shape
-        x = self.word_embed(NDArray(ids)) + self.position_embed(NDArray(pos))
-        if self._dtype != "float32":
-            x = x.astype(self._dtype)
+        with scope("mx.embed"):
+            x = self.word_embed(NDArray(ids)) + \
+                self.position_embed(NDArray(pos))
+            if self._dtype != "float32":
+                x = x.astype(self._dtype)
         for i in range(self.num_layers):
             blk = getattr(self, f"block{i}")
-            q, k, v = _qkv_heads(blk.attn, blk.ln1(x))
-            out = attend(i, q, k, v)
-            x = x + blk.attn.proj(NDArray(out.reshape(B, T, self._units)))
-            x = x + _mlp(blk, x)
+            with scope("mx.norm"):
+                n = blk.ln1(x)
+            with scope("mx.attn"):
+                q, k, v = _qkv_heads(blk.attn, n)
+                out = attend(i, q, k, v)
+                x = x + blk.attn.proj(NDArray(out.reshape(B, T,
+                                                          self._units)))
+            with scope("mx.ffn"):
+                x = x + _mlp(blk, x)
         if last_row is not None:
-            x = NDArray(lax.dynamic_slice(
-                x._data, (0, last_row, 0), (B, 1, self._units)))
+            with scope("mx.head"):
+                x = NDArray(lax.dynamic_slice(
+                    x._data, (0, last_row, 0), (B, 1, self._units)))
         return _lm_head(self, x)._data
 
 
@@ -419,8 +429,11 @@ def _qkv_heads(attn: CausalSelfAttention, x):
 def _mlp(blk: GPTBlock, x):
     """The decode-path FFN half of a block: ln2 → ffn_in → exact gelu →
     ffn_out (no dropout — inference only)."""
-    return blk.ffn_out(NDArray(jax.nn.gelu(
-        blk.ffn_in(blk.ln2(x))._data, approximate=False)))
+    with scope("mx.norm"):
+        n = blk.ln2(x)
+    with scope("mx.ffn"):
+        return blk.ffn_out(NDArray(jax.nn.gelu(
+            blk.ffn_in(n)._data, approximate=False)))
 
 
 def _lm_head(model: GPTModel, x):
@@ -429,9 +442,11 @@ def _lm_head(model: GPTModel, x):
     bf16-rounded activations into the vocab projection and break token
     parity with the training/greedy path). x: (B, T, units) NDArray →
     (B, T, vocab) NDArray."""
-    x = model.ln_f(x.astype("float32"))
-    embed_w = model.word_embed.weight.data()
-    return x._op("dot", embed_w, transpose_b=True)
+    with scope("mx.norm"):
+        x = model.ln_f(x.astype("float32"))
+    with scope("mx.head"):
+        embed_w = model.word_embed.weight.data()
+        return x._op("dot", embed_w, transpose_b=True)
 
 
 def init_kv_cache(model: GPTModel, batch_size: int, max_len=None,

@@ -225,18 +225,19 @@ class GraniteHybridModel(HybridBlock):
                 xs = conv[..., :inner].reshape(B, T, H, P)
                 Bm = conv[..., inner:inner + N]
                 Cm = conv[..., inner + N:]
-                if T == 1:
-                    live = jnp.ones((B,), bool) if real is None \
-                        else real[:, 0]
-                    y, new = ssm_scan.ssm_decode(
-                        rows["ssm"], xs[:, 0], dt[:, 0], A, Bm[:, 0],
-                        Cm[:, 0], live, interpret=interpret)
-                    y = y[:, None]
-                else:
-                    y, new = ssm_scan.ssm_chunk_scan(
-                        ssm_scan.unpack_state(rows["ssm"], P), xs, dt, A,
-                        Bm, Cm, real)
-                    new = ssm_scan.pack_state(new)
+                with scope("mx.ssm_state"):
+                    if T == 1:
+                        live = jnp.ones((B,), bool) if real is None \
+                            else real[:, 0]
+                        y, new = ssm_scan.ssm_decode(
+                            rows["ssm"], xs[:, 0], dt[:, 0], A, Bm[:, 0],
+                            Cm[:, 0], live, interpret=interpret)
+                        y = y[:, None]
+                    else:
+                        y, new = ssm_scan.ssm_chunk_scan(
+                            ssm_scan.unpack_state(rows["ssm"], P), xs, dt,
+                            A, Bm, Cm, real)
+                        new = ssm_scan.pack_state(new)
                 y = y + _val(lay.D)[:, None] * xs
                 return y, {"ssm": new.astype(rows["ssm"].dtype),
                            "conv": tail}

@@ -148,12 +148,17 @@ def _record(name: str, cat: str, t_start_us: float, dur_us: float) -> None:
 @contextmanager
 def scope(name: str, cat: str = "operator"):
     """Scoped profiling region. Host-side timing is recorded when the
-    profiler runs; the region is ALWAYS forwarded to ``jax.named_scope`` so
-    XLA device traces attribute HLO to it (SURVEY.md §5.1 TPU equivalent)."""
+    profiler runs and no JAX trace is live; the region is ALWAYS forwarded
+    to ``jax.named_scope`` so XLA device traces attribute HLO to it
+    (SURVEY.md §5.1 TPU equivalent). Under a trace (``jax.jit``,
+    ``make_jaxpr``, a transformation) the body runs Python that builds a
+    program rather than the region's work, so no host span is recorded:
+    the name reaches the device trace through the program's metadata."""
     import jax
+    from jax._src import core as _jax_core
 
     with jax.named_scope(name):
-        if not is_running():
+        if not is_running() or not _jax_core.trace_state_clean():
             yield
             return
         t = _now_us()
@@ -176,10 +181,14 @@ def scoped(name: str, fn):
 
 _HLO_NAME = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_REF = re.compile(r"%([\w.\-]+)")
+_HLO_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s")
 _MX_SCOPE = re.compile(r"mx\.[a-z_]+")
 
 
-def scope_table(hlo_text: str) -> Dict[str, tuple]:
+def scope_table(hlo_text: str, fill_unnamed: bool = False
+                ) -> Dict[str, tuple]:
     """``{HLO instruction name: (scope, direction)}`` from a compiled
     program's text. ``scope`` is the innermost ``mx.`` name of the
     instruction's ``op_name`` metadata (what ``scope`` left on it at trace
@@ -188,9 +197,27 @@ def scope_table(hlo_text: str) -> Dict[str, tuple]:
     rematerialized forward included), ``"fwd"`` where only through
     ``jvp(``, else ``""`` (optimizer, guard). A fusion carries its root's
     op_name. Instruction names are what a device trace calls its events,
-    so the table attributes trace time to model parts."""
-    table = {}
+    so the table attributes trace time to model parts.
+
+    ``fill_unnamed``: an instruction with no ``mx.`` scope of its own
+    takes, first, the one scope of the instructions inside the
+    computation it calls, where all of those that have one agree (a
+    fusion whose root the compiler rebuilt without a name: the page
+    write's scatter, flattened to two dimensions); else that of the
+    instructions of its own computation that read what it makes, where
+    all of those that have one agree (the asynchronous copy or slice of
+    a weight into the chip's fast memory, ``copy-start`` /
+    ``copy-done``, ``slice-start`` / ``slice-done``, ahead of the
+    product that reads it; a relayout ``copy`` of an operand; a
+    concatenation of slices). An instruction for which neither agrees
+    stays ``""``."""
+    table, lines, members, inside = {}, [], {}, None
     for line in hlo_text.splitlines():
+        if not line.startswith(" "):
+            lines.append(None)          # a computation's bounds
+            head = _HLO_COMPUTATION.match(line)
+            inside = members.setdefault(head.group(1), []) \
+                if head else None
         m = _HLO_NAME.match(line)
         if m is None:
             continue
@@ -201,6 +228,29 @@ def scope_table(hlo_text: str) -> Dict[str, tuple]:
             "fwd" if "jvp(" in op_name else ""
         table[m.group(1)] = (scopes[-1] if scopes else "",
                              direction if scopes else "")
+        lines.append((m.group(1), line[m.end():]))
+        if inside is not None:
+            inside.append(m.group(1))
+    if fill_unnamed:
+        for item in lines:          # callees are printed before callers
+            if item is not None and not table[item[0]][0]:
+                found = {table[n] for c in _HLO_CALLS.findall(item[1])
+                         for n in members.get(c, ()) if table[n][0]}
+                if len(found) == 1:
+                    table[item[0]] = found.pop()
+        read_by = {}                # instruction -> scopes of its readers
+        for item in reversed(lines):
+            if item is None:
+                read_by = {}
+                continue
+            name, rest = item
+            if not table[name][0]:
+                found = set(read_by.get(name, ()))
+                if len(found) == 1:
+                    table[name] = found.pop()
+            if table[name][0]:
+                for ref in set(_HLO_REF.findall(rest)):
+                    read_by.setdefault(ref, set()).add(table[name])
     return table
 
 

@@ -102,7 +102,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .. import autograd
+from .. import autograd, profiler
 from ..base import MXNetError
 from ..gluon.block import _hybrid_trace_scope
 from ..ndarray import NDArray
@@ -750,6 +750,28 @@ class InferenceEngine:
             (self.decode_trace_count, self.verify_trace_count,
              self.prefill_trace_count, self.prefill_trace_counts) = counts
 
+    def scope_table(self, name) -> Dict[str, tuple]:
+        """``{HLO instruction name: (scope, "")}`` of a serving program
+        this engine has dispatched (``profiler.scope_table`` over
+        ``compiled_program_text(name)``): which part of the step each
+        instruction belongs to, by the ``mx.`` scopes the program was
+        traced under (the model's ``mx.embed`` / ``mx.norm`` /
+        ``mx.attn`` / ``mx.ffn`` / ``mx.ssm`` / ``mx.ssm_state`` /
+        ``mx.head``, the engine's ``mx.attend`` / ``mx.kv_write`` /
+        ``mx.sample``, docs/OBSERVABILITY.md "Named scopes"). An
+        instruction the compiler made with no name of its own is booked
+        to the one part inside it (a page write's scatter it rebuilt),
+        else to the part that reads it (a weight brought into the chip's
+        fast memory ahead of its product): ``fill_unnamed``. A device
+        trace names its events by instruction, so joined with one this
+        says where the program's device time goes. Where the persistent
+        compile cache holds the program the compile is a load of the
+        executable that ran. An executable the cache kept from before
+        the scopes existed comes back without them (JAX leaves metadata
+        out of the cache key): every scope then reads ``""``."""
+        return profiler.scope_table(self.compiled_program_text(name),
+                                    fill_unnamed=True)
+
     # ------------------------------------------------------------- #
     # traced programs
     # ------------------------------------------------------------- #
@@ -1065,16 +1087,19 @@ class InferenceEngine:
         # host pre-mapped the whole draft window); padded columns and
         # dead slots write to the null page, harmless and never read
         # unmasked
-        page_idx = jnp.clip(pos // ps, 0, self.max_pages - 1)
-        write_page = jnp.where(act[:, None] & used,
-                               jnp.take_along_axis(page_table, page_idx,
-                                                   axis=1),
-                               NULL_PAGE)
-        write_off = pos % ps
+        with profiler.scope("mx.kv_write"):
+            page_idx = jnp.clip(pos // ps, 0, self.max_pages - 1)
+            write_page = jnp.where(act[:, None] & used,
+                                   jnp.take_along_axis(page_table,
+                                                       page_idx, axis=1),
+                                   NULL_PAGE)
+            write_off = pos % ps
         # padded columns of a nearly-finished slot can index past the
         # table — clamp for the (masked, discarded) embedding lookup
-        emb_pos = jnp.minimum(pos, self.model.max_length - 1)
-        eff_len = jnp.where(act, lengths + 1, 0)
+        with profiler.scope("mx.embed"):
+            emb_pos = jnp.minimum(pos, self.model.max_length - 1)
+        with profiler.scope("mx.attend"):
+            eff_len = jnp.where(act, lengths + 1, 0)
         new_pools = list(pools)
         new_ka, new_va = list(kamax), list(vamax)
         new_states, state = self._state_closure(states)
@@ -1084,37 +1109,41 @@ class InferenceEngine:
             # window's amax and existing codes requantize in the same
             # scatter — pure traced data, no new programs (trace counts
             # stay asserted at 1)
-            ks, vs, adt = self._write_kv(
-                write_block_kv, write_block_kv_q, new_pools, new_ka,
-                new_va, i, k, v, write_page, write_off)
-            out = self._verify_attn(q.astype(adt),
-                                    new_pools[self._pool_of[i]],
-                                    page_table, eff_len, draft_len,
-                                    ks, vs)
-            return out.astype(q.dtype)
+            with profiler.scope("mx.kv_write"):
+                ks, vs, adt = self._write_kv(
+                    write_block_kv, write_block_kv_q, new_pools, new_ka,
+                    new_va, i, k, v, write_page, write_off)
+            with profiler.scope("mx.attend"):
+                out = self._verify_attn(q.astype(adt),
+                                        new_pools[self._pool_of[i]],
+                                        page_table, eff_len, draft_len,
+                                        ks, vs)
+                return out.astype(q.dtype)
 
         with self._model_scope(param_vals):
             logits = self.model.cached_forward(
                 tokens, emb_pos, attend, state=state,
                 real=act[:, None] & used)                 # (S, W, V)
-        emitted, n_emit = self._accept_emit(
-            logits, tokens, draft_len, temps, slot_keys, pos, act,
-            top_k=top_k, top_p=top_p, rep_pen=rep_pen,
-            pres_pen=pres_pen, counts=counts, bias=bias, mask=mask)
-        new_lengths = jnp.where(act, lengths + n_emit, 0)
-        # per-slot non-finite guard: one logits reduction over the USED
-        # verify columns (later columns may legitimately read stale
-        # draft K/V — their logits are dead data), SIGN-ENCODED into
-        # the emitted tokens (column 0 reads -t - 1 on a poisoned slot)
-        # — pure data riding the existing token transfer, so the
-        # jit-once contract is untouched (asserted), a poisoned slot is
-        # visible the step it poisons, and NOTHING from a poisoned
-        # verify step is ever recorded (accepted drafts included; see
-        # step()). Cost banked in BENCH_SERVE.json guard_overhead.
-        if self.guard_nonfinite:
-            bad = jnp.any(jnp.any(~jnp.isfinite(logits), axis=-1) &
-                          used, axis=-1) & act
-            emitted = jnp.where(bad[:, None], -emitted - 1, emitted)
+        with profiler.scope("mx.sample"):
+            emitted, n_emit = self._accept_emit(
+                logits, tokens, draft_len, temps, slot_keys, pos, act,
+                top_k=top_k, top_p=top_p, rep_pen=rep_pen,
+                pres_pen=pres_pen, counts=counts, bias=bias, mask=mask)
+            new_lengths = jnp.where(act, lengths + n_emit, 0)
+            # per-slot non-finite guard: one logits reduction over the
+            # USED verify columns (later columns may legitimately read
+            # stale draft K/V — their logits are dead data),
+            # SIGN-ENCODED into the emitted tokens (column 0 reads
+            # -t - 1 on a poisoned slot) — pure data riding the existing
+            # token transfer, so the jit-once contract is untouched
+            # (asserted), a poisoned slot is visible the step it
+            # poisons, and NOTHING from a poisoned verify step is ever
+            # recorded (accepted drafts included; see step()). Cost
+            # banked in BENCH_SERVE.json guard_overhead.
+            if self.guard_nonfinite:
+                bad = jnp.any(jnp.any(~jnp.isfinite(logits), axis=-1) &
+                              used, axis=-1) & act
+                emitted = jnp.where(bad[:, None], -emitted - 1, emitted)
         return (tuple(new_pools), tuple(new_ka), tuple(new_va), emitted,
                 n_emit, new_lengths, tuple(new_states))
 
@@ -1146,12 +1175,15 @@ class InferenceEngine:
             # scales; the prompt's own attention runs on the exact
             # pre-quantization K/V (only future paged reads pay the
             # quantization error)
-            self._write_kv(write_prompt_kv, write_prompt_kv_q, new_pools,
-                           new_ka, new_va, i, k[0], v[0], pages)
-            rep = q.shape[2] // k.shape[2]
-            if rep > 1:
-                k, v = (jnp.repeat(t, rep, axis=2) for t in (k, v))
-            return _sdpa(q, k, v, mask=mask, scale=self._attn_scale)
+            with profiler.scope("mx.kv_write"):
+                self._write_kv(write_prompt_kv, write_prompt_kv_q,
+                               new_pools, new_ka, new_va, i, k[0], v[0],
+                               pages)
+            with profiler.scope("mx.attend"):
+                rep = q.shape[2] // k.shape[2]
+                if rep > 1:
+                    k, v = (jnp.repeat(t, rep, axis=2) for t in (k, v))
+                return _sdpa(q, k, v, mask=mask, scale=self._attn_scale)
 
         with self._model_scope(param_vals):
             logits = self.model.cached_forward(
@@ -1159,13 +1191,14 @@ class InferenceEngine:
                 real=pos < t0)[:, 0]
         # the first generated token occupies position t0: its draw is
         # keyed by fold_in(request_key, t0), the engine-wide convention
-        tok = self._sample_one(logits[0], temp,
-                               jax.random.fold_in(key, t0),
-                               top_k, top_p, rep_pen, pres_pen,
-                               counts, bias, vocab_mask)
-        if self.guard_nonfinite:             # sign-encoded, see decode
-            tok = jnp.where(jnp.any(~jnp.isfinite(logits)),
-                            -tok - 1, tok)
+        with profiler.scope("mx.sample"):
+            tok = self._sample_one(logits[0], temp,
+                                   jax.random.fold_in(key, t0),
+                                   top_k, top_p, rep_pen, pres_pen,
+                                   counts, bias, vocab_mask)
+            if self.guard_nonfinite:         # sign-encoded, see decode
+                tok = jnp.where(jnp.any(~jnp.isfinite(logits)),
+                                -tok - 1, tok)
         return (tuple(new_pools), tuple(new_ka), tuple(new_va), tok,
                 tuple(new_states))
 
@@ -1201,13 +1234,15 @@ class InferenceEngine:
         def attend(i, q, k, v):              # (1, Cpad, H, D)
             # write_token_kv through this module's global: the
             # benchmark's never-written-page control patches it here
-            ks, vs, adt = self._write_kv(
-                write_token_kv, write_token_kv_q, new_pools, new_ka,
-                new_va, i, k[0], v[0], tok_pages, tok_off)
-            out = self._prefill_attn(q[0].astype(adt),
-                                     new_pools[self._pool_of[i]],
-                                     page_row, start, n_real, ks, vs)
-            return out.astype(q.dtype)[None]
+            with profiler.scope("mx.kv_write"):
+                ks, vs, adt = self._write_kv(
+                    write_token_kv, write_token_kv_q, new_pools, new_ka,
+                    new_va, i, k[0], v[0], tok_pages, tok_off)
+            with profiler.scope("mx.attend"):
+                out = self._prefill_attn(q[0].astype(adt),
+                                         new_pools[self._pool_of[i]],
+                                         page_row, start, n_real, ks, vs)
+                return out.astype(q.dtype)[None]
 
         with self._model_scope(param_vals):
             logits = self.model.cached_forward(
@@ -1216,13 +1251,14 @@ class InferenceEngine:
         # on the FINAL chunk start + n_real == t0, so the draw key
         # matches the dense prefill's exactly — chunked vs monolithic
         # prefill emit the identical first token even at temperature
-        tok = self._sample_one(logits[0], temp,
-                               jax.random.fold_in(key, start + n_real),
-                               top_k, top_p, rep_pen, pres_pen,
-                               counts, bias, vocab_mask)
-        if self.guard_nonfinite:             # sign-encoded, see decode
-            tok = jnp.where(jnp.any(~jnp.isfinite(logits)),
-                            -tok - 1, tok)
+        with profiler.scope("mx.sample"):
+            tok = self._sample_one(logits[0], temp,
+                                   jax.random.fold_in(key, start + n_real),
+                                   top_k, top_p, rep_pen, pres_pen,
+                                   counts, bias, vocab_mask)
+            if self.guard_nonfinite:         # sign-encoded, see decode
+                tok = jnp.where(jnp.any(~jnp.isfinite(logits)),
+                                -tok - 1, tok)
         return (tuple(new_pools), tuple(new_ka), tuple(new_va), tok,
                 tuple(new_states))
 

@@ -75,6 +75,33 @@ def test_profiler_events_and_dump(tmp_path):
     assert "fwd" in table and "Calls" in table
 
 
+def test_profiler_scope_records_no_span_under_a_trace():
+    """A region traced under ``jax.jit`` builds a program, not the region's
+    work: it records no host span (the name reaches the device through the
+    program's metadata); the same region run eagerly records one."""
+    import jax
+    import jax.numpy as jnp
+    prof = mx.profiler
+    prof.dumps(reset=True)
+    prof.start()
+    try:
+        def body(x):
+            with prof.scope("mx.traced_region"):
+                return x * 2.0
+
+        jax.jit(body)(jnp.ones((4,)))
+        jax.make_jaxpr(body)(jnp.ones((4,)))
+        with prof.scope("eager_region"):
+            nd.ones((4, 4)).sum().asnumpy()
+    finally:
+        prof.stop()
+    table = prof.dumps(reset=True)
+    assert "eager_region" in table
+    assert "mx.traced_region" not in table
+    compiled = jax.jit(body).lower(jnp.ones((4,))).compile().as_text()
+    assert ("mx.traced_region", "") in prof.scope_table(compiled).values()
+
+
 def test_profiler_mfu():
     val = mx.profiler.mfu(1e12, 1.0, n_chips=1, peak_flops_per_chip=2e12)
     assert val == pytest.approx(0.5)

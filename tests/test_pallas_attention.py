@@ -698,3 +698,113 @@ def test_serving_programs_compile_for_v5e_with_no_pool_relayout(
         assert mem.alias_size_in_bytes >= L * pool_elems * 2   # donated
         assert mem.temp_size_in_bytes < pool_elems * 2 // 4, \
             (name, mem.temp_size_in_bytes)
+
+
+def _executed(text):
+    """``{computation: [instruction lines]}`` of every computation of a
+    compiled program's text, and the same of those that run as such (the
+    entry and the branches of its conditionals), not those a fusion, a
+    reduction or a sort calls."""
+    import re
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and line.startswith(" "):
+            cur.append(line)
+    todo = [re.search(r"^ENTRY %?([\w.\-]+)", text, re.M).group(1)]
+    live = {}
+    while todo:
+        c = todo.pop()
+        if c in live or c not in comps:
+            continue
+        live[c] = comps[c]
+        for line in comps[c]:
+            for grp in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                todo += [x.strip().lstrip("%") for x in grp.split(",")]
+            todo += re.findall(r"(?:true_computation|false_computation|"
+                               r"body|condition)=%([\w.\-]+)", line)
+    return comps, live
+
+
+@pytest.fixture(scope="module")
+def served_engines():
+    from test_serve_scopes import FAMILIES, served_engine
+    return {family: served_engine(family) for family in FAMILIES}
+
+
+@pytest.mark.parametrize("family,program", [
+    ("gpt", "decode"), ("gpt", "chunk32"), ("hybrid", "decode"),
+    ("hybrid", "chunk32")])
+def test_serving_programs_book_products_copies_and_kernels_to_parts(
+        served_engines, family, program, v5e_chip, no_compile_cache,
+        monkeypatch):
+    """The engine's programs of both served families at a small size,
+    compiled for the v5e, with the scope table ``InferenceEngine.
+    scope_table`` reads (docs/OBSERVABILITY.md "Named scopes"): every
+    instruction that runs and holds a product is booked to a part that
+    owns weights (``mx.attn``, ``mx.ffn``, ``mx.ssm``, ``mx.head``; in a
+    prefill chunk the chunked scan's own products under ``mx.ssm_state``
+    besides), every page write under ``mx.kv_write`` (at GPT-2-XL's 25
+    heads and 64 slots the compiler rebuilds its scatter, flattened and
+    without a name, and the kernel that reads the pool is under
+    ``mx.attend``), the ragged kernel runs under ``mx.attend`` and the
+    state kernel under ``mx.ssm_state``, and the weights the compiler
+    brings into the fast memory ahead of their products are booked to a
+    part too."""
+    import re
+    from incubator_mxnet_tpu import profiler
+    eng, donate, scopes = served_engines[family]
+    name = "decode" if program == "decode" else ("chunk", 32)
+    body = eng._decode_step_fn if name == "decode" \
+        else eng._chunk_prefill_fn
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
+        eng._programs[name][1])
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    # a jit of its own: the engine's was traced on the CPU, where the
+    # dispatchers took the jnp references
+    text = jax.jit(lambda *a: body(*a), donate_argnums=donate) \
+        .lower(*args).compile().as_text()
+    table = profiler.scope_table(text, fill_unnamed=True)
+    comps, live = _executed(text)
+    with_product = {c for c, lines in comps.items()
+                    if any(re.search(r" (dot|convolution)\(", ln)
+                           for ln in lines)}
+    with_scatter = {c for c, lines in comps.items()
+                    if any(" scatter(" in ln for ln in lines)}
+    allowed = {"mx.attn", "mx.ffn", "mx.ssm", "mx.head"} | (
+        {"mx.ssm_state"} if name != "decode" else set())
+    kernels, copies, products, writes = {}, [], 0, 0
+    for lines in live.values():
+        for line in lines:
+            m = profiler._HLO_NAME.match(line)
+            if m is None:
+                continue
+            inst, (scope, _) = m.group(1), table[m.group(1)]
+            op = re.search(r" ([\w\-]+)\(", line[m.end():])
+            op = op.group(1) if op else ""
+            callee = re.findall(r"calls=%([\w.\-]+)", line)
+            if op in ("dot", "convolution") or \
+                    any(c in with_product for c in callee):
+                products += 1
+                assert scope in allowed, (inst, scope, line[:160])
+            if op == "scatter" or any(c in with_scatter for c in callee):
+                writes += 1         # the page writes, named or rebuilt
+                assert scope == "mx.kv_write", (inst, scope, line[:160])
+            if "tpu_custom_call" in line:
+                kernels[re.sub(r"[.\d]+$", "", inst)] = scope
+            if op == "copy-done" and "S(1)" in line.split(" copy-done(")[0]:
+                copies.append((inst, scope))
+    assert products >= 4 and writes >= 1
+    want = {"decode": {"mxtpu_ragged_decode": "mx.attend"},
+            "chunk32": {"mxtpu_ragged_prefill": "mx.attend"}}[program]
+    if family == "hybrid" and name == "decode":
+        want["mxtpu_ssm_decode"] = "mx.ssm_state"
+    assert kernels == want
+    # a copy read by parts that disagree (the page table's, read by the
+    # page writes and the kernel) stays out; the weights' are booked
+    booked = [c for c in copies if c[1]]
+    assert copies and len(booked) >= 0.75 * len(copies), copies
+    assert {sc for _, sc in booked} <= scopes
